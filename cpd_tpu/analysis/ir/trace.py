@@ -65,7 +65,7 @@ class TracedProgram:
 
 
 def _iter_jaxprs(v):
-    import jax.core as jc
+    import jax.extend.core as jc
     if isinstance(v, jc.ClosedJaxpr):
         yield v.jaxpr
     elif isinstance(v, jc.Jaxpr):

@@ -1,26 +1,10 @@
-"""Version-bridging shims over the installed JAX.
+"""The one home of ``jax.experimental`` imports, for the installed jax 0.9.
 
-The codebase targets the modern public surface (``jax.shard_map`` with
-``check_vma=``, promoted in jax 0.6); older jaxlibs (>= 0.4.30) ship the
-same primitive as ``jax.experimental.shard_map.shard_map`` with the flag
-spelled ``check_rep=``.  Everything in cpd_tpu (and its tests/tools)
-imports ``shard_map`` from here so the whole tree tracks one shim instead
-of sprinkling try/except at every call site.
-
-This file is the ONE sanctioned home of ``jax.experimental`` imports:
-the ``compat-drift`` lint rule (docs/ANALYSIS.md) flags every use
-outside it, which is the machine-checked precondition for the jax
-un-pin (ROADMAP item 5) — when upstream renames or promotes an API,
-exactly one file changes.  Besides ``shard_map`` that covers:
-
-* ``pallas`` / ``pallas_tpu`` — still under jax.experimental on every
-  supported jax; re-exported so the Pallas kernels (ops/) survive the
-  eventual promotion to a stable namespace with a one-line edit here.
-* ``multihost_utils`` — host-coordination helpers (checkpoint.py's
-  preemption-flag agreement); experimental on 0.4.x.
-* ``flash_attention_import()`` — the stock Pallas TPU flash kernel,
-  imported LAZILY because the module pulls in TPU-kernel machinery that
-  CPU-only processes (and old jaxlibs) may not have.
+Everything in cpd_tpu (and its tests/tools) imports ``shard_map``, the
+Pallas namespaces, ``multihost_utils`` and the stock flash kernel from
+here, so the ``compat-drift`` lint rule (docs/ANALYSIS.md) can flag every
+``jax.experimental`` use outside this file — when upstream promotes or
+renames one of them, exactly one file changes.
 
 Stdlib-cheap rule: this module DOES import jax, so it must never be
 imported from ``cpd_tpu/__init__.py`` eagerly (see the lazy-export note
@@ -29,51 +13,12 @@ there) — only from the L1/L2 modules that already depend on jax.
 
 from __future__ import annotations
 
+from jax import shard_map
+from jax.experimental import multihost_utils, pallas
+from jax.experimental.pallas import tpu as pallas_tpu
+
 __all__ = ["shard_map", "pallas", "pallas_tpu", "multihost_utils",
            "flash_attention_import"]
-
-try:  # jax >= 0.6: public
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.x/0.5.x: experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-class _MissingModule:
-    """Placeholder for an optional surface the installed jax lacks.
-    Import-time soft (every compat importer — trainers, checkpointing,
-    shard_map users — must not hard-fail because Pallas moved), use-time
-    loud: touching any attribute raises with the real story."""
-
-    def __init__(self, name: str, err: Exception):
-        self._name = name
-        self._err = err
-
-    def __getattr__(self, attr):
-        raise ImportError(
-            f"{self._name} is unavailable in the installed jax "
-            f"({self._err}); cpd_tpu.compat could not locate it under "
-            f"jax.experimental or a promoted spelling") from self._err
-
-
-# Pallas: experimental namespace on every jax this tree currently
-# supports; try the promoted spelling first so the eventual move is
-# absorbed here, and degrade to a use-time error (never an import-time
-# one) when neither exists — compat is imported by far more modules
-# than the three Pallas kernels.
-try:
-    try:
-        from jax import pallas  # promoted (future jax)
-        from jax.pallas import tpu as pallas_tpu
-    except ImportError:
-        from jax.experimental import pallas
-        from jax.experimental.pallas import tpu as pallas_tpu
-except ImportError as _e:
-    pallas = _MissingModule("pallas", _e)
-    pallas_tpu = _MissingModule("pallas.tpu", _e)
-
-try:
-    from jax.experimental import multihost_utils
-except ImportError as _e:
-    multihost_utils = _MissingModule("multihost_utils", _e)
 
 
 def flash_attention_import():
@@ -86,32 +31,3 @@ def flash_attention_import():
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         flash_attention)
     return flash_attention
-
-
-def _check_kw() -> str:
-    """The replication-check flag's spelling in the installed JAX.
-
-    Probed from the function's signature, not from which import
-    succeeded — the public promotion of shard_map and the
-    check_rep -> check_vma rename landed in different jax releases."""
-    import inspect
-    try:
-        params = inspect.signature(_shard_map).parameters
-    except (TypeError, ValueError):
-        return "check_rep"  # unsignaturable wrapper: assume the old name
-    return "check_vma" if "check_vma" in params else "check_rep"
-
-
-_CHECK_KW = _check_kw()
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """``jax.shard_map`` with the replication-check flag translated.
-
-    Accepts the modern ``check_vma=`` spelling and forwards it under
-    whatever name the installed JAX uses.  All other keywords pass
-    through untouched."""
-    if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)

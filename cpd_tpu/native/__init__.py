@@ -5,8 +5,11 @@ The reference compiles its native layer at import time with
 CPDtorch/quant/quant_function.py:10-17) and degrades to None on CPU-only
 environments (:18-19).  Same contract here, minus the torch dependency:
 `g++ -O2 -shared -fPIC` into a cached .so beside the source, ctypes
-bindings, and graceful degradation (`available() == False`) when no
-compiler exists.
+bindings, and degradation to the numpy paths (`available() == False`)
+when no compiler exists — `load()` says on stderr, once, which of the
+two is in use.  The .so is git-ignored and named by a hash of its two
+sources, so a binary copied along with a checkout is only ever loaded
+by the sources it was built from.
 
 Public surface (numpy in/out, pure — no in-place mutation):
   * `float_quantize_np(x, exp, man)`   — elementwise eXmY cast
@@ -21,8 +24,11 @@ independent oracles.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
+import sys
 import tempfile
 from typing import Optional
 
@@ -39,34 +45,39 @@ _TRIED = False
 
 
 def _so_path() -> str:
-    return os.path.join(_HERE, "_cpd_native.so")
+    h = hashlib.sha256()
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_HERE, f"_cpd_native.{h.hexdigest()[:12]}.so")
 
 
 def build(force: bool = False) -> Optional[str]:
-    """Compile the shared library if absent/stale; return its path or None
-    when no toolchain is available."""
+    """Compile the shared library for the current sources if absent;
+    return its path or None when no toolchain is available."""
     so = _so_path()
-    if (not force and os.path.exists(so)
-            and os.path.getmtime(so) >= max(os.path.getmtime(s)
-                                            for s in _SRCS)):
+    if not force and os.path.exists(so):
         return so
     for cxx in (os.environ.get("CXX"), "g++", "c++", "clang++"):
         if not cxx:
             continue
         # build into a temp file then rename: atomic under concurrent
         # imports (e.g. pytest-xdist workers racing).
-        fd, tmp = tempfile.mkstemp(suffix=".so", dir=_HERE)
+        fd, tmp = tempfile.mkstemp(suffix=".so.tmp", dir=_HERE)
         os.close(fd)
         cmd = [cxx, "-O2", "-shared", "-fPIC", "-pthread", "-o", tmp,
                *_SRCS]
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
             os.replace(tmp, so)
-            return so
         except (OSError, subprocess.SubprocessError):
             if os.path.exists(tmp):
                 os.unlink(tmp)
             continue
+        for stale in glob.glob(os.path.join(_HERE, "_cpd_native*.so")):
+            if stale != so:
+                os.unlink(stale)
+        return so
     return None
 
 
@@ -78,7 +89,11 @@ def load() -> Optional[ctypes.CDLL]:
     _TRIED = True
     so = build()
     if so is None:
+        print("cpd_tpu.native: no C++ compiler found — numpy paths in use",
+              file=sys.stderr)
         return None
+    print(f"cpd_tpu.native: C++ library {os.path.basename(so)} in use",
+          file=sys.stderr)
     lib = ctypes.CDLL(so)
     i64, i32 = ctypes.c_int64, ctypes.c_int
     fptr = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")

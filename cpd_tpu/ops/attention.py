@@ -353,8 +353,12 @@ def grouped_query_attention(q: jnp.ndarray, k: jnp.ndarray,
 
     impl="flash" routes MHA (H == H_kv) to the stock TPU flash-attention
     kernel and GQA to the in-repo GQA-native Pallas kernel
-    (`ops/flash_gqa.py`, round 5) which consumes the unexpanded K/V
-    directly; both hardware-validated by tools/pallas_check.py.
+    (`ops/flash_gqa.py`) which consumes the unexpanded K/V directly.
+    tools/pallas_check.py on a v5e chip (2026-09-26, libtpu 0.0.34): both
+    forwards compile and agree with the XLA reference to 2e-2 (fp32
+    matmuls run as bf16 passes there), flash_gqa also at short Tq
+    (q block 8 and 40); its Pallas backward compiles since its dk/dv
+    contraction was merged to one dimension.  No speed was measured.
     impl="chunked" runs the grouped contraction through the
     online-softmax K/V-block scan (`_chunked_attention`) — GQA-native,
     O(Tq·block) score memory, any backend.

@@ -34,9 +34,9 @@ Design notes:
 Backward: `jax.custom_vjp` with two selectable paths (``bwd=``).  The
 default "chunked" recomputes through `_chunked_attention`'s
 checkpointed scan (same recurrence, O(Tq·block) score memory in
-reverse) and takes ITS gradient — pure XLA, the conservative choice
-while the Mosaic lowering has only interpret-mode evidence.  "pallas"
-(round 5) runs the flash-backward recipe on the MXU: the forward also
+reverse) and takes ITS gradient — pure XLA.  "pallas" runs the
+flash-backward recipe on the MXU (compiles on v5e and agrees with the
+XLA gradient to 5e-2, tools/pallas_check.py; neither is timed yet): the forward also
 emits the per-row LSE, and two kernels — dq (K innermost) and fused
 dk/dv (Q innermost, the GQA group-sums folded into (rep, bq)
 contractions) — re-exponentiate p = exp(s − lse) per block.  Both are
@@ -54,6 +54,7 @@ from jax import lax
 from ..compat import pallas as pl, pallas_tpu as pltpu
 
 from .attention import _NEG_INF, _gqa_rep  # attention imports us lazily
+from .backend import interpret_mode
 
 __all__ = ["flash_gqa"]
 
@@ -276,16 +277,21 @@ def _flash_gqa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         p, ds = _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref,
                           delta_ref, i, j, causal=causal, scale=scale,
                           tk=tk, bq=bq, bk=bk)
-        q = q_ref[0, 0]
-        do = do_ref[0, 0]
-        # dv += Σ_rep p^T do ; dk += Σ_rep ds^T q  (one contraction each
-        # over the (rep, bq) axes — the GQA group sums fall out of the
-        # dot_general, nothing rep-sized is materialized)
+        # dv += Σ_rep p^T do ; dk += Σ_rep ds^T q.  Mosaic's matmul takes
+        # ONE contracting dimension, so (rep, bq) merge into rows first (a
+        # layout no-op: bq is a sublane multiple) — the GQA group sums
+        # still fall out of the contraction, nothing rep-sized is
+        # materialized
+        rows = p.shape[0] * p.shape[1]
+        q = q_ref[0, 0].reshape(rows, -1)
+        do = do_ref[0, 0].reshape(rows, -1)
         dv_acc[...] += lax.dot_general(
-            p.astype(do.dtype), do, (((0, 1), (0, 1)), ((), ())),
+            p.reshape(rows, bk).astype(do.dtype), do,
+            (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # (bk, D)
         dk_acc[...] += lax.dot_general(
-            ds.astype(q.dtype), q, (((0, 1), (0, 1)), ((), ())),
+            ds.reshape(rows, bk).astype(q.dtype), q,
+            (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # (bk, D)
 
     @pl.when(i == n_q - 1)
@@ -375,25 +381,23 @@ def flash_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     (B, Tq, H, D) in q.dtype; fp32 softmax.
 
     Matches `_chunked_attention` / `grouped_query_attention` to fp32
-    round-off (different contraction order — not bitwise).  Runs in
-    interpret mode automatically off-TPU so tests and CPU smoke runs
-    exercise the same code path; `tools/pallas_check.py` proves the real
-    Mosaic lowering on hardware.
+    round-off (different contraction order — not bitwise).  Compiled by
+    Mosaic on TPU, interpreted on the CPU test backend
+    (`ops.backend.interpret_mode`); `tools/pallas_check.py` checks the
+    compiled kernels on the chip.
 
     ``bwd`` selects the gradient path: "chunked" (default) recomputes
-    through `_chunked_attention`'s checkpointed scan — pure XLA, the
-    conservative choice while the Pallas kernels' Mosaic lowering has
-    only interpret-mode evidence; "pallas" runs the flash-backward
+    through `_chunked_attention`'s checkpointed scan — pure XLA;
+    "pallas" runs the flash-backward
     recipe as two Pallas kernels (dq with K innermost; fused dk/dv with
     Q innermost, the GQA group-sums folded into the (rep, bq)
     contractions) against the forward's saved LSE — O(1) extra memory,
     the full fwd+bwd on the MXU.  Both are valid gradients of softmax
     attention to fp32 round-off and are tested against each other and
-    the XLA AD oracle; pallas_check stages the "pallas" path for
-    hardware validation.
+    the XLA AD oracle; pallas_check compiles both on the chip.
     """
     _validate_call(q, k, bwd)
-    interpret = jax.devices()[0].platform != "tpu"
+    interpret = interpret_mode()
     out, _ = _flash_gqa_fwd_call(q, k, v, causal, interpret)
     return out
 
@@ -409,7 +413,7 @@ def _validate_call(q, k, bwd):
 
 def _fwd(q, k, v, causal, bwd):
     _validate_call(q, k, bwd)
-    interpret = jax.devices()[0].platform != "tpu"
+    interpret = interpret_mode()
     out, lse = _flash_gqa_fwd_call(q, k, v, causal, interpret)
     res = (q, k, v, out, lse) if bwd == "pallas" else (q, k, v)
     return out, res
@@ -418,7 +422,7 @@ def _fwd(q, k, v, causal, bwd):
 def _bwd(causal, bwd, res, g):
     if bwd == "pallas":
         q, k, v, out, lse = res
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = interpret_mode()
         return _flash_gqa_bwd_call(q, k, v, out, lse, g, causal,
                                    interpret)
     q, k, v = res

@@ -245,16 +245,26 @@ def fletcher_mod65521(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(x >= m, x - m, x)
 
 
+def _usum(x: jnp.ndarray, axis=None, keepdims: bool = False) -> jnp.ndarray:
+    """Sum of uint32 values whose total stays below 2^31 (every caller's
+    overflow audit), taken as int32: Mosaic on libtpu 0.0.34 refuses
+    "Reductions over unsigned integers", and below 2^31 the two sums are
+    the same number.  Same-width casts, not bitcasts: the full reduction
+    is a scalar, which `tpu.bitcast` does not take."""
+    return jnp.sum(x.astype(jnp.int32), axis=axis,
+                   keepdims=keepdims).astype(jnp.uint32)
+
+
 def _tile_fletcher(bytes_u32: jnp.ndarray, byte_pos: jnp.ndarray) -> tuple:
     """Partial Fletcher sums (s1, s2) of one (R, 128) tile of byte
     values at absolute byte positions `byte_pos` (uint32).  Zero pad
     bytes contribute nothing, so no masking is needed.  Overflow-safe:
     Σ bytes <= 65536·255 < 2^24; per-lane products < 2^8·2^16 = 2^24,
     row sums of 128 < 2^31, mod'd row partials sum < 512·2^16."""
-    s1 = fletcher_mod65521(jnp.sum(bytes_u32))
+    s1 = fletcher_mod65521(_usum(bytes_u32))
     posm = fletcher_mod65521(byte_pos) + jnp.uint32(1)
-    rows = fletcher_mod65521(jnp.sum(bytes_u32 * posm, axis=1))
-    s2 = fletcher_mod65521(jnp.sum(rows))
+    rows = fletcher_mod65521(_usum(bytes_u32 * posm, axis=1, keepdims=True))
+    s2 = fletcher_mod65521(_usum(rows))
     return s1, s2
 
 
@@ -553,16 +563,20 @@ def _digest_rows_kernel(b_ref, o_ref, *, w: int, sub_per_row: int):
     j = pl.program_id(0)
     bytes_u32 = b_ref[:].astype(jnp.uint32)
     rows, lanes = b_ref.shape                  # rows = w * sub_per_row
+    # sub_per_row is a power of two (digest_rows_pallas), so the sublane
+    # within its row and the row id are a mask and a shift — Mosaic has
+    # no vector integer division
+    shift = sub_per_row.bit_length() - 1
     idx0 = lax.broadcasted_iota(jnp.uint32, (rows, lanes), 0)
-    sub = idx0 % jnp.uint32(sub_per_row)       # sublane within the row
+    sub = idx0 & jnp.uint32(sub_per_row - 1)
     pos = (j.astype(jnp.uint32)
            * jnp.uint32(sub_per_row * lanes)
            + sub * jnp.uint32(lanes)
            + lax.broadcasted_iota(jnp.uint32, (rows, lanes), 1))
     posm = fletcher_mod65521(pos) + jnp.uint32(1)
-    c1 = jnp.sum(bytes_u32, axis=1)                        # (rows,)
-    c2 = fletcher_mod65521(jnp.sum(bytes_u32 * posm, axis=1))
-    row_id = idx0[:, 0] // jnp.uint32(sub_per_row)         # (rows,)
+    c1 = _usum(bytes_u32, axis=1, keepdims=True)           # (rows, 1)
+    c2 = fletcher_mod65521(_usum(bytes_u32 * posm, axis=1, keepdims=True))
+    row_id = lax.broadcasted_iota(jnp.uint32, (rows, 1), 0) >> shift
 
     @pl.when(j == 0)
     def _():
@@ -570,10 +584,11 @@ def _digest_rows_kernel(b_ref, o_ref, *, w: int, sub_per_row: int):
             o_ref[r, 0] = jnp.uint32(0)
             o_ref[r, 1] = jnp.uint32(0)
 
+    zero = jnp.uint32(0)
     for r in range(w):
         m = row_id == jnp.uint32(r)
-        p1 = fletcher_mod65521(jnp.sum(jnp.where(m, c1, 0)))
-        p2 = fletcher_mod65521(jnp.sum(jnp.where(m, c2, 0)))
+        p1 = fletcher_mod65521(_usum(jnp.where(m, c1, zero)))
+        p2 = fletcher_mod65521(_usum(jnp.where(m, c2, zero)))
         o_ref[r, 0] = fletcher_mod65521(o_ref[r, 0] + p1)
         o_ref[r, 1] = fletcher_mod65521(o_ref[r, 1] + p2)
 
@@ -602,8 +617,9 @@ def digest_rows_pallas(rows: jnp.ndarray,
         return jnp.zeros((w,), jnp.uint32)
     # sublanes of one row per grid step: cap the whole block (all W
     # rows' tiles) near 2 MiB of VMEM, and cap per-row sublanes at 2048
-    # (the masked-sum overflow bound above)
-    sub_per_row = max(1, min(2048, 16384 // max(w, 1)))
+    # (the masked-sum overflow bound above), rounded down to a power of
+    # two so the kernel finds a sublane's row with a shift
+    sub_per_row = 1 << (max(1, min(2048, 16384 // w)).bit_length() - 1)
     tile = sub_per_row * _LANES
     t = -(-nb // tile)
     padded = jnp.pad(rows, ((0, 0), (0, t * tile - nb)))

@@ -17,8 +17,16 @@ functions the XLA composition uses — they arrive as closures
 implementation, not a copy that can drift — and the digest is
 `parallel.integrity.wire_digest` itself.  tests/test_serve_tp.py gates
 kernel == XLA bitwise in interpret mode over GQA page shapes including
-odd tail pages × odd blocks; `tools/pallas_check.py` check 8 re-runs
-the gate compiled on real chips.
+odd tail pages × odd blocks.
+
+**Does not compile on TPU** (v5e, libtpu 0.0.34; `tools/pallas_check.py`
+check 8): Mosaic has no lowering for the `dynamic_slice` that
+`dynamic_index_in_dim` over the loaded pool becomes.  A kernel that
+does — a grid over (slot, page) with the page table as a scalar-prefetch
+operand and per-page BlockSpecs, instead of a whole-pool VMEM load —
+is a rebuild, not a repair (ROADMAP S5/D3).  `make_decode_step(fused=
+True)` therefore raises on TPU; the kernel runs only in the CPU
+interpreter.
 
 Composition with tensor parallelism: the caller hands in a SHARD-LOCAL
 pool slice (legacy tp=1 layout) with the shard-view config's unpack

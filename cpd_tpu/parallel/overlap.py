@@ -51,6 +51,7 @@ import dataclasses
 from typing import Any, Callable, Optional, Sequence
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
@@ -443,15 +444,15 @@ def _walk_eqns(jaxpr, out: list):
                            if aval.shape else 1)
         out.append((eqn.primitive.name, size))
         for v in eqn.params.values():
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, jex_core.ClosedJaxpr):
                 _walk_eqns(v.jaxpr, out)
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, jex_core.Jaxpr):
                 _walk_eqns(v, out)
             elif isinstance(v, (tuple, list)):
                 for w in v:
-                    if isinstance(w, jax.core.ClosedJaxpr):
+                    if isinstance(w, jex_core.ClosedJaxpr):
                         _walk_eqns(w.jaxpr, out)
-                    elif isinstance(w, jax.core.Jaxpr):
+                    elif isinstance(w, jex_core.Jaxpr):
                         _walk_eqns(w, out)
     return out
 

@@ -112,6 +112,16 @@ def _pow2(e: jnp.ndarray) -> jnp.ndarray:
         ((e + 127) << 23).astype(jnp.uint32), jnp.float32)
 
 
+def _with_sign(mag: jnp.ndarray, negative: jnp.ndarray) -> jnp.ndarray:
+    """`mag` (>= +0) with the sign bit set where `negative` — by bit
+    assembly, not ``-mag``: Mosaic on libtpu 0.0.34 lowers a float
+    negation as ``0 - x``, which turns ``-(+0.0)`` into +0.0 where XLA
+    and the reference give -0.0 (found on the chip, PR 21)."""
+    bits = jax.lax.bitcast_convert_type(mag, jnp.uint32)
+    sign = jnp.where(negative, jnp.uint32(0x80000000), jnp.uint32(0))
+    return jax.lax.bitcast_convert_type(bits | sign, jnp.float32)
+
+
 def _cast_core(x: jnp.ndarray, exp_bits: int, man_bits: int,
                round_fn) -> jnp.ndarray:
     """Shared cast skeleton: everything except the significand rounding step.
@@ -167,7 +177,7 @@ def _cast_core(x: jnp.ndarray, exp_bits: int, man_bits: int,
     a = jnp.clip(e, -126, 127)
     b = e - a  # 0 in the normal range; [-23, 0) deep in the subnormal range
     mag = man_out.astype(jnp.float32) * _pow2(a) * _pow2(b)
-    val = jnp.where(negative, -mag, mag)
+    val = _with_sign(mag, negative)
 
     inf = jnp.where(negative, -jnp.inf, jnp.inf).astype(jnp.float32)
     val = jnp.where(overflow, inf, val)
@@ -256,8 +266,7 @@ def sr_bits_at(key: jax.Array, offsets: jnp.ndarray) -> jnp.ndarray:
     7.8–12.3x the RTNE faithful reduction on the world=8 CPU mesh
     (0.2M–3.2M params; docs/PERF.md "SR faithful-path overhead").  The
     TPU ratio is expected lower (vectorized threefry vs the scan's ICI
-    gather) but has not been measured — staged in the recapture
-    pipeline.  Deployments that need cheap SR should use mode="fast"
+    gather) but has not been measured (ROADMAP S7).  Deployments that need cheap SR should use mode="fast"
     (one pre-/post-cast pair) or the Pallas SR kernel's hardware PRNG.
 
     `offsets` may be any shape; values must fit uint32 (documented limit:
@@ -601,7 +610,7 @@ def unpack_code(code: jnp.ndarray, exp_bits: int,
     mag = mantissa.astype(jnp.float32) * _pow2(a) * _pow2(b)
     inf = jnp.float32(jnp.inf)
     mag = jnp.where(is_special & (man_field == 0), inf, mag)
-    val = jnp.where(sign, -mag, mag)
+    val = _with_sign(mag, sign)
     return jnp.where(is_special & (man_field >= 2), jnp.float32(jnp.nan),
                      val)
 

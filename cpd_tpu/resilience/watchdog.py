@@ -2,7 +2,7 @@
 
 Pod-scale reality: a step that normally takes 300ms occasionally never
 returns — a wedged collective, a straggler host, a dead interconnect
-tunnel.  The blocking call cannot time itself out, so a background timer
+link.  The blocking call cannot time itself out, so a background timer
 thread does: on expiry it (1) dumps the last-known context and every
 thread's stack to stderr, (2) marks itself ``tripped``, and (3) sends
 the process a real SIGINT (``os.kill`` — an actual OS signal, which

@@ -42,6 +42,7 @@ from jax.sharding import PartitionSpec as P
 from . import kvcache
 from .kvcache import KVCacheConfig
 from ..compat import shard_map
+from ..ops.backend import interpret_mode
 from ..ops.serve_attn import fused_gather_attention
 from ..parallel.mesh import AXIS_TENSOR, make_mesh
 from ..parallel.ring import gather_transport_bytes
@@ -282,7 +283,7 @@ def _block(blk: dict, x: jnp.ndarray, positions: jnp.ndarray,
             page_size=cfg.page_size,
             unpack_fn=lambda kv_pages: kvcache.unpack_kv(kv_pages, cfg),
             attend_fn=_paged_attention,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret_mode())
         bad = bad + jnp.sum(
             (read_dig != digests[layer][page_rows]).astype(jnp.int32))
     else:
@@ -385,12 +386,23 @@ def make_decode_step(spec: ModelSpec, cfg: KVCacheConfig,
     (8, 23)).  ``fused`` routes the pool read through the one-pass
     Pallas kernel; it is a retrace coordinate (`ladder_step_key`
     carries it) and composes with tp.  The fp32 oracle cache keeps the
-    XLA read path — ``fused`` with ``raw=True`` is rejected."""
+    XLA read path — ``fused`` with ``raw=True`` is rejected.  The kernel
+    does not compile on TPU (Mosaic has no `dynamic_slice`), so there
+    ``fused`` raises with the compiler's message: it runs only in the
+    CPU interpreter, as the bitwise gate of a design to be rebuilt."""
     if fused and cfg.raw:
         raise ValueError(
             "fused_attn with raw=True: the fp32 oracle cache is the "
             "reference the fused kernel is gated against — it keeps "
             "the XLA read path")
+    if fused and not interpret_mode():
+        raise NotImplementedError(
+            "fused_attn=True does not compile on TPU (found on v5e, "
+            "libtpu 0.0.34, tools/pallas_check.py): Mosaic reports "
+            "'Unimplemented primitive in Pallas TPU lowering for "
+            "KernelType.TC: dynamic_slice' — ops/serve_attn.py gathers "
+            "pages by dynamic index out of a whole-pool VMEM load.  Use "
+            "the default XLA read path")
     _check_tp(spec, cfg)
 
     def build():

@@ -1,7 +1,6 @@
 """Shared utilities: config loading, loggers, profiling."""
 
-from .cache import (LRUCache, clear_cache, default_cache_dir,
-                    enable_compile_cache)
+from .cache import LRUCache, default_cache_dir, enable_compile_cache
 from .config import load_yaml_config, merge_config_into_args
 from .logging import (ProgressPrinter, ScalarWriter, TableLogger, TSVLogger,
                       format_validation_line)
@@ -16,7 +15,7 @@ _GRAPH_NAMES = ("GraphModule", "GraphClassifier", "build_graph", "rel_path",
 __all__ = ["load_yaml_config", "merge_config_into_args", "TableLogger",
            "TSVLogger", "ScalarWriter", "ProgressPrinter",
            "format_validation_line", "enable_compile_cache",
-           "default_cache_dir", "clear_cache", "LRUCache", "StepProfiler",
+           "default_cache_dir", "LRUCache", "StepProfiler",
            *_GRAPH_NAMES]
 
 

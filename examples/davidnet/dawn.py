@@ -85,6 +85,9 @@ def main(argv=None) -> dict:
     from cpd_tpu.utils import StepProfiler, TableLogger, TSVLogger
 
     rank, world = dist_init() if args.dist else (0, 1)
+    # after dist_init: it consults the resolved backend
+    from cpd_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     mesh = data_parallel_mesh()
     n_dev = mesh.devices.size
 

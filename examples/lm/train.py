@@ -184,6 +184,9 @@ def main(argv=None) -> dict:
     from cpd_tpu.utils import ProgressPrinter, ScalarWriter, StepProfiler
 
     rank, world = dist_init() if args.dist else (0, 1)
+    # after dist_init: it consults the resolved backend
+    from cpd_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     # sampling-flag validation BEFORE training: a bad combination must not
     # surface as a crash after the whole run completed
     if args.sample_temperature == 0 and (args.sample_top_k is not None

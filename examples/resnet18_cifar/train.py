@@ -170,6 +170,9 @@ def main(argv=None) -> dict:
                                merge_config_into_args)
 
     rank, world = dist_init() if args.dist else (0, 1)
+    # after dist_init: it consults the resolved backend
+    from cpd_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     explicit = {k: v for k, v in vars(args).items() if v is not None}
     merge_config_into_args(args, load_yaml_config(args.config),
                            cli_overrides=explicit)

@@ -141,8 +141,17 @@ def main(argv=None) -> dict:
                                format_validation_line)
 
     rank, world = dist_init() if args.dist else (0, 1)
+    # after dist_init: it consults the resolved backend
+    from cpd_tpu.utils import enable_compile_cache
+    enable_compile_cache()
     mesh = data_parallel_mesh()
     n_dev = mesh.devices.size
+    if rank == 0:
+        # an unset JAX_PLATFORMS on a machine whose chip did not come up
+        # resolves to cpu without a word — say what the mesh spans
+        dev0 = mesh.devices.flat[0]
+        print(f"=> devices: {n_dev} x {dev0.device_kind} "
+              f"({dev0.platform})")
 
     train_ds, val_ds = load_imagenet(args.train_dir, size=args.image_size,
                                      num_classes=args.num_classes)

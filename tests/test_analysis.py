@@ -646,8 +646,9 @@ def test_live_suppression_count_is_pinned():
     # StepTable._cache (static level vocabulary), MetricsRegistry
     # ._metrics (declared-name cardinality), ServeEngine.logits_log
     # (tests-only oracle tap), TSVLogger.log (one line per epoch — the
-    # DAWNBench artifact itself)
-    assert len(sites) == 14, (
+    # DAWNBench artifact itself); minus the 2 `swallow` claims that left
+    # utils/cache.py with its CPUID tag and its catch-all (PR 21)
+    assert len(sites) == 12, (
         "live-tree suppression count changed — review the new/removed "
         "site's justification and re-pin:\n" + "\n".join(
             f"{p}:{ln}: {pl}" for p, ln, pl in sites))

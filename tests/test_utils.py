@@ -268,15 +268,6 @@ def test_sum_gradients_fn_jit_cache_bounded():
                                   np.asarray(results[0]["k0"]))
 
 
-def test_machine_tag_stable_and_hex():
-    from cpd_tpu.utils.cache import _machine_tag
-
-    a, b = _machine_tag(), _machine_tag()
-    assert a == b                    # deterministic (APIC-ID byte masked)
-    int(a, 16)
-    assert len(a) == 10
-
-
 def test_enable_compile_cache_noop_on_cpu():
     import jax
 
@@ -289,16 +280,68 @@ def test_enable_compile_cache_noop_on_cpu():
     assert jax.config.jax_compilation_cache_dir == before
 
 
-def test_clear_cache_removes_only_current_tag(tmp_path, monkeypatch):
+@pytest.fixture()
+def tpu_backend(monkeypatch):
+    """Pretend the resolved backend is a TPU so the enabling branch of
+    enable_compile_cache runs; every jax.config value it writes is put
+    back afterwards."""
+    import jax
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {n: getattr(jax.config, n) for n in names}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+
+
+def test_compile_cache_dir_from_env_is_left_alone(tpu_backend, monkeypatch,
+                                                  tmp_path):
+    import jax
+
+    from cpd_tpu.utils import enable_compile_cache
+
+    # jax reads JAX_COMPILATION_CACHE_DIR itself at import; model that
+    # state, then check the function sets no directory of its own
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    enable_compile_cache()
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+
+def test_compile_cache_dir_defaults_to_checkout(tpu_backend, monkeypatch):
+    import jax
+
+    from cpd_tpu.utils import default_cache_dir, enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    enable_compile_cache()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert default_cache_dir() == os.path.join(repo, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == default_cache_dir()
+
+
+def test_default_cache_dir_identical_across_processes():
+    # the directory is part of jax's cache key: a path that differed
+    # between two processes on one checkout could never hit
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("from cpd_tpu.utils.cache import default_cache_dir; "
+            "print(default_cache_dir())")
+    outs = [subprocess.run([sys.executable, "-c", code], cwd=repo,
+                           capture_output=True, text=True, check=True,
+                           timeout=60).stdout.strip() for _ in range(2)]
+    assert outs[0] == outs[1] == os.path.join(repo, ".jax_cache")
+
+
+def test_cache_module_runs_no_machine_code():
+    import inspect
+
     from cpd_tpu.utils import cache
 
-    root = tmp_path / ".jax_cache"
-    mine = root / cache._machine_tag()
-    other = root / "otherhosttag"
-    mine.mkdir(parents=True)
-    other.mkdir(parents=True)
-    (mine / "entry").write_text("x")
-    monkeypatch.setattr(cache, "_cache_root", lambda: str(root))
-    cache.clear_cache()
-    assert not mine.exists()
-    assert other.exists()            # other machines' entries survive
+    src = inspect.getsource(cache)
+    assert "mmap" not in src and "PROT_EXEC" not in src

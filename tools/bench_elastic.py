@@ -59,6 +59,10 @@ def _ensure_multidevice():
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_"
                                      "count=8").strip()
+    # a CI gate that defaults to the CPU mesh says so on its first line
+    print(f"# {os.path.basename(__file__)}: JAX_PLATFORMS="
+          f"{os.environ.get('JAX_PLATFORMS') or '(unset: jax picks)'}",
+          flush=True)
 
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(

@@ -7,7 +7,7 @@ never hits.  This tool measures it and gates it:
 
     python tools/bench_linalg.py              # measure: timings per
         transport + the per-format accuracy-vs-wire-bytes frontier,
-        ONE JSON line out (bench.py embeds the same block)
+        ONE JSON line out
     python tools/bench_linalg.py --smoke      # the `linalg-smoke` CI
         gate: (1) sharded matmul / QR / power / Lanczos BITWISE ==
         their single-device quantized oracles on representative
@@ -45,6 +45,10 @@ def _ensure_multidevice():
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_"
                                      "count=8").strip()
+    # a CI gate that defaults to the CPU mesh says so on its first line
+    print(f"# {os.path.basename(__file__)}: JAX_PLATFORMS="
+          f"{os.environ.get('JAX_PLATFORMS') or '(unset: jax picks)'}",
+          flush=True)
 
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
@@ -376,8 +380,7 @@ def measure(iters: int = 3) -> dict:
 
 
 def main():
-    # scoped to main() like bench_reduce's: importers (bench.py's
-    # _tool_mod) must not have their process env mutated at import
+    # scoped to main() like bench_reduce's: never mutate env at import
     _ensure_multidevice()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",

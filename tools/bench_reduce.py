@@ -21,7 +21,7 @@ TPU the timing is real too).
         per-tensor APS vs block-scaled accuracy (vs the exact fp32 ring
         oracle) against analytic wire bytes incl. the scale sidecar
 
-Prints ONE JSON line; `bench.py` embeds the same analytic byte accounting
+Prints ONE JSON line; `bench.py` reports the same analytic byte accounting
 as its `reduction` block.
 """
 
@@ -45,6 +45,10 @@ def _ensure_multidevice():
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_"
                                      "count=8").strip()
+    # a CI gate that defaults to the CPU mesh says so on its first line
+    print(f"# {os.path.basename(__file__)}: JAX_PLATFORMS="
+          f"{os.environ.get('JAX_PLATFORMS') or '(unset: jax picks)'}",
+          flush=True)
 
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
@@ -452,8 +456,7 @@ def overlap_step_bench(iters: int = 8, batch_per_dev: int = 8,
                        bucket_elems: int = 65536) -> dict:
     """Full-train-step throughput of the overlapped transport vs the
     monoliths on the current backend — the ISSUE 8 acceptance
-    measurement (docs/PERF.md "Overlapped reduce"; bench.py embeds this
-    as ``reduction.overlap``).
+    measurement (docs/PERF.md "Overlapped reduce").
 
     Arms: fp32 step (grad (8,23) — the plain-psum shortcut), faithful
     e5m2 APS (monolith), faithful+overlap, ring, ring+overlap.  The
@@ -922,8 +925,8 @@ def smoke() -> dict:
     # CPU mesh, where every hash op serializes against the reduce
     # itself and the in-kernel digests run interpreted.  The <= 1.2x
     # target is the COMPILED-kernel claim (digest = ~6 VPU ops riding a
-    # memory-bound pack kernel + O(W) scalar tag algebra; rides the
-    # recapture pipeline) — this gate pins the measured CPU bounds so a
+    # memory-bound pack kernel + O(W) scalar tag algebra; not measured
+    # on the chip) — this gate pins the measured CPU bounds so a
     # regression back toward separate-pass digesting fails loudly.
     # 1M elements PER RANK: small vectors measure interpret-mode
     # per-op dispatch (fixed cost per kernel op), not the digest
@@ -979,8 +982,8 @@ def smoke() -> dict:
     # every rank pays a fixed ~2 ms pallas-call dispatch for its row
     # pass where the old XLA hash vectorized to ~1 ms total.  Measured
     # 2.1-2.6x here vs 1.9-2.0x before — pure interpret-emulation tax
-    # (one fewer pass on compiled kernels, where <= 1.2x remains the
-    # claim riding the recapture pipeline); the bound still fails a
+    # (one fewer pass on compiled kernels, where <= 1.2x remains an
+    # unmeasured claim); the bound still fails a
     # regression toward the PR-4 separate-pass digesting (+449-566%)
     if fused_ratio > 3.0:
         raise AssertionError(
@@ -1041,9 +1044,7 @@ def smoke() -> dict:
 
 
 def main():
-    # env mutation ONLY on CLI entry: bench.py imports this module from an
-    # already-initialized (possibly TPU) process, which must see no
-    # platform side effects
+    # env mutation ONLY on CLI entry, never at import
     _ensure_multidevice()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",

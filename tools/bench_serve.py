@@ -1073,6 +1073,9 @@ def main() -> int:
     add_obs_flags(p)
     args = p.parse_args()
 
+    from cpd_tpu.utils import enable_compile_cache
+    enable_compile_cache()
+
     if args.smoke:
         out = run_smoke(args)
     elif args.soak_smoke:

@@ -74,6 +74,10 @@ def _ensure_multidevice():
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_"
                                      "count=8").strip()
+    # a CI gate that defaults to the CPU mesh says so on its first line
+    print(f"# {os.path.basename(__file__)}: JAX_PLATFORMS="
+          f"{os.environ.get('JAX_PLATFORMS') or '(unset: jax picks)'}",
+          flush=True)
 
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
@@ -142,7 +146,9 @@ def _probe_total_ops(surface: str) -> int:
 
 
 def crash_matrix() -> bool:
-    """The kill-at-every-write-boundary sweep (module docstring #1)."""
+    """The kill-at-every-write-boundary sweep (module docstring #1).
+    A CPU-tier gate: every child runs with JAX_PLATFORMS=cpu whatever the
+    parent resolved, so it can never contend for a chip the parent holds."""
     from cpd_tpu.store import CRASH_EXIT, DurableStore
 
     ok = True
@@ -166,6 +172,7 @@ def crash_matrix() -> bool:
                     rc = subprocess.run(
                         [sys.executable, os.path.abspath(__file__),
                          "--crash-child", root, surface, str(n)],
+                        env=dict(os.environ, JAX_PLATFORMS="cpu"),
                         capture_output=True).returncode
                     want_rc = CRASH_EXIT if n < total else 0
                     rec = DurableStore(root)   # the restarted process
