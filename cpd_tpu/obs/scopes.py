@@ -1,0 +1,83 @@
+"""The names of a step's layers inside the compiled program.
+
+A train step is one compiled program; the host cannot see inside it
+(docs/OBSERVABILITY.md, the `step` span).  The device trace can: every
+operation carries jax's name stack as metadata (`tf_op` in a TPU trace,
+`op_name=` in the compiled HLO's text), so a `jax.named_scope` entered
+where a layer's work is traced names that work in every profile, at no
+cost to the program: a scope adds no equation and changes no value.
+
+This module is the one home of the scope strings.  Call sites write
+`with jax.named_scope(scopes.REDUCE):` (or use it as a decorator); the
+benchmark's reader (`benchmark/trace_scopes.py`) matches the same strings.
+Stdlib-only, like the rest of `obs`.
+
+| scope | entered in | covers |
+| --- | --- | --- |
+| `cpd.loss_grad` | `train/step.py`, `train/lm.py`, `parallel/overlap.py` | forward and backward; jax's own `jvp(` / `transpose(` markers deeper in the stack split the two |
+| `cpd.emulate_node` | `parallel/emulate.py` | the in-chip emulated-node reduction |
+| `cpd.reduce` | `parallel/dist.py:sum_gradients` | the whole gradient reduction, for every caller |
+| `aps.max_exp` / `aps.scale` / `aps.unscale` | `parallel/aps.py` | the APS passes (the maximum includes its `pmax`) |
+| `wire.cast` | `parallel/dist.py` | the eXmY cast before (and, in `fast` mode, after) the wire |
+| `wire.pack` / `wire.unpack` | `quant/numerics.py` | eXmY values to and from wire bytes |
+| `wire.collective` | `parallel/dist.py`, `parallel/ring.py` | the collectives themselves, whatever XLA renames or combines them into |
+| `reduce.scan` | `parallel/reduction.py` | the ordered requantised sum over ranks |
+| `cpd.optimizer` | `train/step.py`, `train/lm.py` | `tx.update` and `apply_updates` (or the custom `update_fn`) |
+| `cpd.metrics` | `train/step.py`, `train/lm.py` | the step's own telemetry `psum`s, and the batch statistics' `pmean` |
+| `kernel.<name>` | `ops/*.py`, around each `pl.pallas_call` | one Pallas kernel; the call's `name=` is the same `<name>` |
+
+Ownership, as the reader applies it: an operation belongs to the LAST
+`cpd.*` or `kernel.*` component of its name stack; `aps.*`, `wire.*` and
+`reduce.*` refine it.  jax wraps a scope entered under a transformation
+in that transformation's marker (`jvp(cpd.reduce)` when `overlap_reduce`
+runs the reduction inside the backward pass); the reader unwraps it.
+"""
+
+from __future__ import annotations
+
+__all__ = ["LOSS_GRAD", "EMULATE_NODE", "REDUCE", "OPTIMIZER", "METRICS",
+           "APS_MAX_EXP", "APS_SCALE", "APS_UNSCALE", "WIRE_CAST",
+           "WIRE_PACK", "WIRE_UNPACK", "WIRE_COLLECTIVE", "REDUCE_SCAN",
+           "KERNEL_PREFIX", "KERNELS", "kernel_name"]
+
+LOSS_GRAD = "cpd.loss_grad"
+EMULATE_NODE = "cpd.emulate_node"
+REDUCE = "cpd.reduce"
+OPTIMIZER = "cpd.optimizer"
+METRICS = "cpd.metrics"
+
+APS_MAX_EXP = "aps.max_exp"
+APS_SCALE = "aps.scale"
+APS_UNSCALE = "aps.unscale"
+WIRE_CAST = "wire.cast"
+WIRE_PACK = "wire.pack"
+WIRE_UNPACK = "wire.unpack"
+WIRE_COLLECTIVE = "wire.collective"
+REDUCE_SCAN = "reduce.scan"
+
+KERNEL_PREFIX = "kernel."
+KERNEL_QUANTIZE = "kernel.quantize"
+KERNEL_QUANTIZE_ADD = "kernel.quantize_add"
+KERNEL_QUANTIZE_ADD_SR = "kernel.quantize_add_sr"
+KERNEL_QUANTIZE_SR = "kernel.quantize_sr"
+KERNEL_WIRE_HOP = "kernel.wire_hop"
+KERNEL_DIGEST_ROWS = "kernel.digest_rows"
+KERNEL_QGEMM = "kernel.qgemm"
+KERNEL_FUSED_GATHER_ATTENTION = "kernel.fused_gather_attention"
+KERNEL_FLASH_GQA_FWD = "kernel.flash_gqa_fwd"
+KERNEL_FLASH_GQA_BWD_DQ = "kernel.flash_gqa_bwd_dq"
+KERNEL_FLASH_GQA_BWD_DKV = "kernel.flash_gqa_bwd_dkv"
+
+KERNELS = (KERNEL_QUANTIZE, KERNEL_QUANTIZE_ADD, KERNEL_QUANTIZE_ADD_SR,
+           KERNEL_QUANTIZE_SR, KERNEL_WIRE_HOP, KERNEL_DIGEST_ROWS,
+           KERNEL_QGEMM, KERNEL_FUSED_GATHER_ATTENTION,
+           KERNEL_FLASH_GQA_FWD, KERNEL_FLASH_GQA_BWD_DQ,
+           KERNEL_FLASH_GQA_BWD_DKV)
+
+
+def kernel_name(scope: str) -> str:
+    """`kernel.flash_gqa_fwd` -> `flash_gqa_fwd`: the `name=` of the
+    `pl.pallas_call` that the scope surrounds."""
+    if not scope.startswith(KERNEL_PREFIX):
+        raise ValueError(f"not a kernel scope: {scope!r}")
+    return scope[len(KERNEL_PREFIX):]

@@ -46,7 +46,7 @@ __all__ = ["Tracer", "Span", "NULL_TRACER", "NULL_SPAN"]
 class Span:
     """Context manager handed out by `Tracer.span` — records on exit."""
 
-    __slots__ = ("_tracer", "name", "cat", "step", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "step", "args", "_t0", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  step: Optional[int], args: dict):
@@ -56,14 +56,21 @@ class Span:
         self.step = step
         self.args = args
         self._t0 = 0.0
+        self._note = None
 
     def __enter__(self) -> "Span":
         self._tracer._depth += 1
+        if self._tracer._annotation is not None:
+            # the same span on the profiler's clock (Tracer(annotate=True))
+            self._note = self._tracer._annotation(self.name)
+            self._note.__enter__()
         self._t0 = now()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = now()
+        if self._note is not None:
+            self._note.__exit__(*exc)
         tr = self._tracer
         tr._depth -= 1
         tr._push(tr.spans, (tr._next_seq(), self.name, self.cat,
@@ -100,10 +107,16 @@ class Tracer:
         out past it, counted in ``spans_dropped``/``events_dropped``.
     meta : free-form run metadata carried into the export headers
         (model shape, flags, world size) — keep it JSON-serializable.
+    annotate : also enter a `jax.profiler.TraceAnnotation` of the same
+        name around every span, so that a `jax.profiler` trace taken
+        meanwhile holds the host's `data` / `step` / `checkpoint` / serve
+        phases on the device's clock.  jax is imported here, on request
+        only: ``import cpd_tpu.obs`` stays stdlib-only, and with it off a
+        span pays one ``is not None`` test.
     """
 
     def __init__(self, run: str = "run", *, max_records: int = 65536,
-                 meta: Optional[dict] = None):
+                 meta: Optional[dict] = None, annotate: bool = False):
         if max_records < 1:
             raise ValueError(f"max_records must be >= 1, got "
                              f"{max_records}")
@@ -116,6 +129,10 @@ class Tracer:
         self.events_dropped = 0
         self._seq = 0
         self._depth = 0
+        self._annotation = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
 
     # -- recording --------------------------------------------------------
 

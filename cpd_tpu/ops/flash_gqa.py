@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from ..compat import pallas as pl, pallas_tpu as pltpu
+from ..obs import scopes
 
 from .attention import _NEG_INF, _gqa_rep  # attention imports us lazily
 from .backend import interpret_mode
@@ -161,7 +162,7 @@ def _flash_gqa_fwd_call(q, k, v, causal: bool, interpret: bool):
     vt = _kv_layout(v, tk_p, d_p)
 
     n_q, n_k = tq_p // bq, tk_p // bk
-    out, lse = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_flash_gqa_kernel, causal=causal, scale=scale,
                           tq=tq, tk=tk, bq=bq, bk=bk, n_k=n_k),
         out_shape=(
@@ -194,7 +195,10 @@ def _flash_gqa_fwd_call(q, k, v, causal: bool, interpret: bool):
             pltpu.VMEM((rep, bq, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(qt, kt, vt)
+        name=scopes.kernel_name(scopes.KERNEL_FLASH_GQA_FWD),
+    )
+    with jax.named_scope(scopes.KERNEL_FLASH_GQA_FWD):
+        out, lse = call(qt, kt, vt)
     # (B, H_kv, rep, Tq_p, D_p) -> (B, Tq, H, D)
     out = out[:, :, :, :tq, :d].transpose(0, 3, 1, 2, 4).reshape(
         b, tq, h, d)
@@ -325,7 +329,7 @@ def _flash_gqa_bwd_call(q, k, v, out, lse, do, causal: bool,
     kvspec = pl.BlockSpec((1, 1, bk, d_p),
                           lambda bi, g, i, j: (bi, g, j, 0),
                           memory_space=pltpu.VMEM)
-    dq = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_flash_gqa_bwd_dq_kernel, causal=causal,
                           scale=scale, tk=tk, bq=bq, bk=bk, n_k=n_k),
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, tq_p, d_p), q.dtype),
@@ -334,7 +338,10 @@ def _flash_gqa_bwd_call(q, k, v, out, lse, do, causal: bool,
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((rep, bq, d_p), jnp.float32)],
         interpret=interpret,
-    )(qt, kt, vt, dot, lse, delta)
+        name=scopes.kernel_name(scopes.KERNEL_FLASH_GQA_BWD_DQ),
+    )
+    with jax.named_scope(scopes.KERNEL_FLASH_GQA_BWD_DQ):
+        dq = call(qt, kt, vt, dot, lse, delta)
 
     # k-major grid: the q-block index is innermost for the accumulators
     qspec_kmaj = pl.BlockSpec((1, 1, rep, bq, d_p),
@@ -346,7 +353,7 @@ def _flash_gqa_bwd_call(q, k, v, out, lse, do, causal: bool,
     kvspec_kmaj = pl.BlockSpec((1, 1, bk, d_p),
                                lambda bi, g, j, i: (bi, g, j, 0),
                                memory_space=pltpu.VMEM)
-    dk, dv = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_flash_gqa_bwd_dkv_kernel, causal=causal,
                           scale=scale, tk=tk, bq=bq, bk=bk, n_q=n_q),
         out_shape=(
@@ -360,7 +367,10 @@ def _flash_gqa_bwd_call(q, k, v, out, lse, do, causal: bool,
         scratch_shapes=[pltpu.VMEM((bk, d_p), jnp.float32),
                         pltpu.VMEM((bk, d_p), jnp.float32)],
         interpret=interpret,
-    )(qt, kt, vt, dot, lse, delta)
+        name=scopes.kernel_name(scopes.KERNEL_FLASH_GQA_BWD_DKV),
+    )
+    with jax.named_scope(scopes.KERNEL_FLASH_GQA_BWD_DKV):
+        dk, dv = call(qt, kt, vt, dot, lse, delta)
 
     dq = dq[:, :, :, :tq, :d].transpose(0, 3, 1, 2, 4).reshape(
         b, tq, h, d)

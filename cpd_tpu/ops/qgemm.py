@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from ..compat import pallas as pl, pallas_tpu as pltpu
+from ..obs import scopes
 
 from ..quant.numerics import _validate, cast_body
 
@@ -80,7 +81,7 @@ def qgemm_pallas(a: jnp.ndarray, b: jnp.ndarray, exp_bits: int,
     at = jnp.pad(a.T, ((0, 0), (0, mp - m)))          # (K, Mp)
     bp = jnp.pad(b, ((0, 0), (0, np_ - n)))           # (K, Np)
 
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_qgemm_kernel, exp_bits=exp_bits,
                           man_bits=man_bits, k_steps=k),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
@@ -98,5 +99,8 @@ def qgemm_pallas(a: jnp.ndarray, b: jnp.ndarray, exp_bits: int,
             pltpu.VMEM((_TILE, _TILE), jnp.float32),
         ],
         interpret=interpret,
-    )(at, bp)
+        name=scopes.kernel_name(scopes.KERNEL_QGEMM),
+    )
+    with jax.named_scope(scopes.KERNEL_QGEMM):
+        out = call(at, bp)
     return out[:m, :n]

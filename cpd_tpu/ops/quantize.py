@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from ..compat import pallas as pl, pallas_tpu as pltpu
+from ..obs import scopes
 
 from ..quant.numerics import (_scale_pow2, _validate, _validate_wire,
                               cast_body, cast_body_sr,
@@ -80,7 +81,7 @@ def quantize_pallas(x: jnp.ndarray, exp_bits: int, man_bits: int,
         return x
     flat, grid, padded_rows = _to_blocks(x)
 
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_quantize_kernel, exp_bits=exp_bits,
                           man_bits=man_bits),
         out_shape=jax.ShapeDtypeStruct((padded_rows, _LANES), jnp.float32),
@@ -88,7 +89,10 @@ def quantize_pallas(x: jnp.ndarray, exp_bits: int, man_bits: int,
         in_specs=[_block_spec()],
         out_specs=_block_spec(),
         interpret=interpret,
-    )(flat)
+        name=scopes.kernel_name(scopes.KERNEL_QUANTIZE),
+    )
+    with jax.named_scope(scopes.KERNEL_QUANTIZE):
+        out = call(flat)
     return out.reshape(-1)[:n].reshape(shape)
 
 
@@ -121,7 +125,7 @@ def quantize_add_pallas(x: jnp.ndarray, y: jnp.ndarray, exp_bits: int,
         return x
     xf, grid, padded_rows = _to_blocks(x)
     yf, _, _ = _to_blocks(y)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_quantize_add_kernel, exp_bits=exp_bits,
                           man_bits=man_bits),
         out_shape=jax.ShapeDtypeStruct((padded_rows, _LANES), jnp.float32),
@@ -129,7 +133,10 @@ def quantize_add_pallas(x: jnp.ndarray, y: jnp.ndarray, exp_bits: int,
         in_specs=[_block_spec(), _block_spec()],
         out_specs=_block_spec(),
         interpret=interpret,
-    )(xf, yf)
+        name=scopes.kernel_name(scopes.KERNEL_QUANTIZE_ADD),
+    )
+    with jax.named_scope(scopes.KERNEL_QUANTIZE_ADD):
+        out = call(xf, yf)
     return out.reshape(-1)[:n].reshape(shape)
 
 
@@ -154,7 +161,7 @@ def quantize_add_pallas_bits(x: jnp.ndarray, y: jnp.ndarray, exp_bits: int,
     xf, grid, padded_rows = _to_blocks(x)
     yf, _, _ = _to_blocks(y)
     rf, _, _ = _to_blocks(rbits)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_quantize_add_sr_kernel, exp_bits=exp_bits,
                           man_bits=man_bits),
         out_shape=jax.ShapeDtypeStruct((padded_rows, _LANES), jnp.float32),
@@ -162,7 +169,10 @@ def quantize_add_pallas_bits(x: jnp.ndarray, y: jnp.ndarray, exp_bits: int,
         in_specs=[_block_spec(), _block_spec(), _block_spec()],
         out_specs=_block_spec(),
         interpret=interpret,
-    )(xf, yf, rf)
+        name=scopes.kernel_name(scopes.KERNEL_QUANTIZE_ADD_SR),
+    )
+    with jax.named_scope(scopes.KERNEL_QUANTIZE_ADD_SR):
+        out = call(xf, yf, rf)
     return out.reshape(-1)[:n].reshape(shape)
 
 
@@ -186,7 +196,7 @@ def quantize_pallas_sr(x: jnp.ndarray, exp_bits: int, man_bits: int,
     flat, grid, padded_rows = _to_blocks(x)
     rflat, _, _ = _to_blocks(rbits)
 
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_quantize_sr_kernel, exp_bits=exp_bits,
                           man_bits=man_bits),
         out_shape=jax.ShapeDtypeStruct((padded_rows, _LANES), jnp.float32),
@@ -194,7 +204,10 @@ def quantize_pallas_sr(x: jnp.ndarray, exp_bits: int, man_bits: int,
         in_specs=[_block_spec(), _block_spec()],
         out_specs=_block_spec(),
         interpret=interpret,
-    )(flat, rflat)
+        name=scopes.kernel_name(scopes.KERNEL_QUANTIZE_SR),
+    )
+    with jax.named_scope(scopes.KERNEL_QUANTIZE_SR):
+        out = call(flat, rflat)
     return out.reshape(-1)[:n].reshape(shape)
 
 
@@ -475,14 +488,17 @@ def _wire_call(codes_in, k_in, sidecar_in, g, exp_bits, man_bits, rbits,
     kernel = _make_wire_kernel(exp_bits, man_bits, wb, first=first,
                                sr=sr, blocked=block_size if blocked
                                else None, want_digest=want_digest)
-    outs = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         out_shape=tuple(out_shape),
         grid=(grid,),
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         interpret=interpret,
-    )(*operands)
+        name=scopes.kernel_name(scopes.KERNEL_WIRE_HOP),
+    )
+    with jax.named_scope(scopes.KERNEL_WIRE_HOP):
+        outs = call(*operands)
 
     res = outs[0].reshape(-1)[:n]
     planes = outs[1:1 + wb]
@@ -633,7 +649,7 @@ def digest_rows_pallas(rows: jnp.ndarray,
     # tiling rule is about VMEM vector blocks; SMEM is word-addressed
     dig_spec = pl.BlockSpec(  # cpd: disable=pallas-hygiene
         (w, 2), lambda j: (0, 0), memory_space=pltpu.SMEM)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_digest_rows_kernel, w=w,
                           sub_per_row=sub_per_row),
         out_shape=jax.ShapeDtypeStruct((w, 2), jnp.uint32),
@@ -643,7 +659,10 @@ def digest_rows_pallas(rows: jnp.ndarray,
                                memory_space=pltpu.VMEM)],
         out_specs=dig_spec,
         interpret=interpret,
-    )(stacked)
+        name=scopes.kernel_name(scopes.KERNEL_DIGEST_ROWS),
+    )
+    with jax.named_scope(scopes.KERNEL_DIGEST_ROWS):
+        out = call(stacked)
     return (out[:, 1] << 16) | out[:, 0]
 
 

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..compat import pallas as pl  # noqa: F401  (kernel home: ..compat)
+from ..obs import scopes
 from ..parallel.integrity import wire_digest
 
 __all__ = ["fused_gather_attention"]
@@ -85,14 +86,17 @@ def fused_gather_attention(pool_layer: jnp.ndarray,
     kernel = functools.partial(
         _fused_kernel, s_count=s_count, max_pages=max_pages,
         page_size=page_size, unpack_fn=unpack_fn, attend_fn=attend_fn)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         out_shape=(
             jax.ShapeDtypeStruct((s_count, t, h, d), jnp.float32),
             jax.ShapeDtypeStruct((s_count, max_pages), jnp.uint32),
         ),
         interpret=interpret,
-    )(pool_layer, page_rows, q, positions, last_pos)
+        name=scopes.kernel_name(scopes.KERNEL_FUSED_GATHER_ATTENTION),
+    )
+    with jax.named_scope(scopes.KERNEL_FUSED_GATHER_ATTENTION):
+        return call(pool_layer, page_rows, q, positions, last_pos)
 
 
 def _fused_kernel(pool_ref, rows_ref, q_ref, pos_ref, last_ref,

@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs import scopes
+
 __all__ = ["aps_max_exponents", "aps_shift_factors",
            "aps_shift_factors_checked", "aps_scale", "aps_unscale",
            "exp2_exact"]
@@ -88,6 +90,7 @@ def _ceil_log2_exact(m: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(jnp.isnan(m), jnp.nan, ex)
 
 
+@jax.named_scope(scopes.APS_MAX_EXP)
 def aps_max_exponents(grads: Any, world_size) -> jnp.ndarray:
     """ceil(log2(max|g * W|)) per leaf, stacked into one (n_leaves,) vector
     (computed EXACTLY from the max's bit pattern — `_ceil_log2_exact` —
@@ -137,6 +140,7 @@ def aps_shift_factors(max_exp: jnp.ndarray, grad_exp: int) -> jnp.ndarray:
     return aps_shift_factors_checked(max_exp, grad_exp)[0]
 
 
+@jax.named_scope(scopes.APS_SCALE)
 def aps_scale(grads: Any, shifts: jnp.ndarray) -> Any:
     """g * 2^shift per leaf (lossless power-of-two scaling — the scale
     is the EXACT `exp2_exact` power of two, program-independent)."""
@@ -145,6 +149,7 @@ def aps_scale(grads: Any, shifts: jnp.ndarray) -> Any:
     return jax.tree_util.tree_unflatten(treedef, scaled)
 
 
+@jax.named_scope(scopes.APS_UNSCALE)
 def aps_unscale(grads: Any, shifts: jnp.ndarray) -> Any:
     """g / 2^shift per leaf — a true fp32 divide like the reference
     (dist_util.py:45), NOT multiply-by-2^-shift: for shifts > 127 the
@@ -157,6 +162,7 @@ def aps_unscale(grads: Any, shifts: jnp.ndarray) -> Any:
     return jax.tree_util.tree_unflatten(treedef, scaled)
 
 
+@jax.named_scope(scopes.APS_MAX_EXP)
 def pmax_scalar_vector(vec: jnp.ndarray, axis_name: str | Sequence[str]) -> jnp.ndarray:
     """One MAX collective over the stacked per-leaf exponent vector —
     the TPU replacement for dist.all_reduce(max_exp, MAX)

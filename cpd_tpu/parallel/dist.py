@@ -52,6 +52,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..obs import scopes
 from ..quant.numerics import (cast_to_format, cast_to_format_sr_at,
                               pack_exmy, unpack_exmy, wire_bytes)
 from ..quant.quant_function import tree_quant_health
@@ -221,9 +222,11 @@ def _gather_leaf(g: jnp.ndarray, axis_name, wire=None) -> jnp.ndarray:
     payload (values must already be in that format's value set)."""
     if wire is not None:
         packed = pack_exmy(g, *wire)
-        out = lax.all_gather(packed, axis_name, axis=0, tiled=False)
+        with jax.named_scope(scopes.WIRE_COLLECTIVE):
+            out = lax.all_gather(packed, axis_name, axis=0, tiled=False)
         return unpack_exmy(out, *wire)
-    return lax.all_gather(g, axis_name, axis=0, tiled=False)
+    with jax.named_scope(scopes.WIRE_COLLECTIVE):
+        return lax.all_gather(g, axis_name, axis=0, tiled=False)
 
 
 # Per-bucket element cap for the faithful path (one home for the number:
@@ -292,6 +295,7 @@ def _bucketed_quantized_sum(grads: Any, axis_name, grad_exp: int,
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+@jax.named_scope(scopes.REDUCE)
 def sum_gradients(grads: Any, axis_name: str | Sequence[str],
                   use_aps: bool = False, grad_exp: int = 5, grad_man: int = 2,
                   use_kahan: bool = False, mode: str = "faithful",
@@ -449,8 +453,9 @@ def sum_gradients(grads: Any, axis_name: str | Sequence[str],
         k_pre = jax.random.fold_in(k_pre, _flat_axis_index(axis_name))
 
     def q_tree(t, k):
-        return quantize_tree_sr(t, grad_exp, grad_man, k,
-                                starts=offset_starts)
+        with jax.named_scope(scopes.WIRE_CAST):
+            return quantize_tree_sr(t, grad_exp, grad_man, k,
+                                    starts=offset_starts)
 
     shifts = None
     prec = None
@@ -492,8 +497,9 @@ def sum_gradients(grads: Any, axis_name: str | Sequence[str],
         # fast mode IS the XLA-order psum by definition: same wire
         # precision, no order emulation (module docstring) — the one
         # place the unordered reduction is the documented intent.
-        reduced = jax.tree.map(  # cpd: disable=kahan-ordering
-            lambda g: lax.psum(g, axis_name), grads)
+        with jax.named_scope(scopes.WIRE_COLLECTIVE):
+            reduced = jax.tree.map(  # cpd: disable=kahan-ordering
+                lambda g: lax.psum(g, axis_name), grads)
         if not (grad_exp == 8 and grad_man == 23):
             reduced = q_tree(reduced, k_post)
     elif mode == "ring":
@@ -571,8 +577,9 @@ def sum_gradients(grads: Any, axis_name: str | Sequence[str],
             # fp32 fast path == plain all-reduce: the reference takes the
             # same shortcut at the identity format (dist_util.py:55-59),
             # so XLA-order psum here is reference parity, not a loss.
-            reduced = jax.tree.map(  # cpd: disable=kahan-ordering
-                lambda g: lax.psum(g, axis_name), grads)
+            with jax.named_scope(scopes.WIRE_COLLECTIVE):
+                reduced = jax.tree.map(  # cpd: disable=kahan-ordering
+                    lambda g: lax.psum(g, axis_name), grads)
         elif bucket:
             reduced = _bucketed_quantized_sum(
                 grads, axis_name, grad_exp, grad_man, use_kahan,

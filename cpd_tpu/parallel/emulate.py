@@ -30,6 +30,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ..obs import scopes
 from ..quant.numerics import cast_to_format, cast_to_format_sr
 from .aps import aps_max_exponents, aps_shift_factors, exp2_exact
 from .reduction import ordered_quantized_sum
@@ -89,6 +90,7 @@ def make_overlap_emulate_fn(n: int, use_aps: bool, grad_exp: int,
     gradients (`extra`, (N-1, *leaf)) and runs the rank-local
     emulate-node ordered reduce on the (N, *leaf) result."""
 
+    @jax.named_scope(scopes.EMULATE_NODE)
     def emulate_fn(g, extra, i, ekey):
         stacked_leaf = jnp.concatenate([extra, g[None]], 0)
         return reduce_stacked_leaf(
@@ -98,6 +100,7 @@ def make_overlap_emulate_fn(n: int, use_aps: bool, grad_exp: int,
     return emulate_fn
 
 
+@jax.named_scope(scopes.EMULATE_NODE)
 def emulate_node_reduce(stacked_grads: Any, emulate_node: int,
                         use_aps: bool = False, grad_exp: int = 5,
                         grad_man: int = 2, key=None,

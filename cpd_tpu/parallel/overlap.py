@@ -55,6 +55,8 @@ import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import scopes
+
 __all__ = ["bucket_layout", "BucketPlan", "overlapped_grads",
            "overlap_evidence", "evidence_from_prims",
            "extract_bucket_shards", "REPORT_FIELDS",
@@ -367,8 +369,9 @@ def overlapped_grads(loss_fn: Callable, params: Any, *,
         return loss_fn(jax.tree_util.tree_unflatten(treedef, leaves))
 
     z0 = jnp.zeros((plan.n_buckets, n_rep + 1), jnp.float32)
-    (loss, aux_out), (g_params, g_z) = jax.value_and_grad(
-        inner, argnums=(0, 1), has_aux=True)(params, z0)
+    with jax.named_scope(scopes.LOSS_GRAD):
+        (loss, aux_out), (g_params, g_z) = jax.value_and_grad(
+            inner, argnums=(0, 1), has_aux=True)(params, z0)
 
     report = None
     if want_report and plan.n_buckets == 0:

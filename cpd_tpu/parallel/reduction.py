@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs import scopes
 from ..quant.numerics import (cast_body_blocked, cast_to_format,
                               cast_to_format_sr_at, sr_bits_at)
 
@@ -75,6 +76,7 @@ def _make_q(exp: int, man: int, key, offsets=None, block=None):
     return q
 
 
+@jax.named_scope(scopes.REDUCE_SCAN)
 def ordered_quantized_sum(stacked: jnp.ndarray, exp: int, man: int,
                           key=None, offsets=None,
                           block_size=None) -> jnp.ndarray:
@@ -99,6 +101,7 @@ def ordered_quantized_sum(stacked: jnp.ndarray, exp: int, man: int,
     return res
 
 
+@jax.named_scope(scopes.REDUCE_SCAN)
 def kahan_quantized_sum(stacked: jnp.ndarray, exp: int, man: int,
                         key=None, offsets=None,
                         block_size=None) -> jnp.ndarray:
@@ -146,7 +149,8 @@ def quantized_sum(stacked: jnp.ndarray, exp: int, man: int,
         return kahan_quantized_sum(stacked, exp, man, key=key,
                                    offsets=offsets, block_size=block_size)
     if exp == 8 and man == 23:
-        return jnp.sum(stacked, axis=0)
+        with jax.named_scope(scopes.REDUCE_SCAN):
+            return jnp.sum(stacked, axis=0)
     return ordered_quantized_sum(stacked, exp, man, key=key, offsets=offsets,
                                  block_size=block_size)
 

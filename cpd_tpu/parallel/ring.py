@@ -93,6 +93,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..obs import scopes
 from ..quant.numerics import (cast_body_blocked, cast_to_format,
                               cast_to_format_sr_at, pack_exmy,
                               pack_exmy_blocked, sr_bits_at,
@@ -432,7 +433,8 @@ def ring_quantized_sum(flat: jnp.ndarray, axis_name: str, exp: int, man: int,
             _, wire0 = fused_first(chunk_at(0), False)
 
             def body(carry, t):
-                recv = lax.ppermute(carry, axis_name, perm)
+                with jax.named_scope(scopes.WIRE_COLLECTIVE):
+                    recv = lax.ppermute(carry, axis_name, perm)
                 _, new_wire = fused_hop(recv, t, chunk_at(t), False)
                 return new_wire, None
 
@@ -444,7 +446,9 @@ def ring_quantized_sum(flat: jnp.ndarray, axis_name: str, exp: int, man: int,
             res, comp = accum(zero, zero, jnp.int32(0), chunk_at(0))
 
             def body(carry, t):
-                res, comp = from_wire(lax.ppermute(carry, axis_name, perm))
+                with jax.named_scope(scopes.WIRE_COLLECTIVE):
+                    recv = lax.ppermute(carry, axis_name, perm)
+                res, comp = from_wire(recv)
                 res, comp = accum(res, comp, t, chunk_at(t))
                 return to_wire(res, comp), None
 
@@ -464,7 +468,8 @@ def ring_quantized_sum(flat: jnp.ndarray, axis_name: str, exp: int, man: int,
             wire = pack_exmy_blocked(res, exp, man, block_size)
         else:
             wire = pack_exmy(res, exp, man) if packed else res
-        gathered = lax.all_gather(wire, axis_name, axis=0, tiled=False)
+        with jax.named_scope(scopes.WIRE_COLLECTIVE):
+            gathered = lax.all_gather(wire, axis_name, axis=0, tiled=False)
         if block_scale:
             full = jax.vmap(lambda r: unpack_exmy_blocked(
                 r, exp, man, chunk, block_size))(gathered)
@@ -501,7 +506,8 @@ def ring_quantized_sum(flat: jnp.ndarray, axis_name: str, exp: int, man: int,
 
     def vbody(carry, t):
         wire = carry
-        recv = lax.ppermute(wire, axis_name, perm)
+        with jax.named_scope(scopes.WIRE_COLLECTIVE):
+            recv = lax.ppermute(wire, axis_name, perm)
         if have_fault:
             recv = _apply_hop_fault(recv, wire, f_code,
                                     on_me & (t == jnp.int32(1)))
@@ -557,7 +563,8 @@ def ring_quantized_sum(flat: jnp.ndarray, axis_name: str, exp: int, man: int,
         # tag first, then each body-produced wire's (the last body
         # iteration's wire is never sent — its tag is dropped)
         sent = jnp.concatenate([tag0[None], stags[:-1]])
-        remote_sent = lax.ppermute(sent, axis_name, perm)
+        with jax.named_scope(scopes.WIRE_COLLECTIVE):
+            remote_sent = lax.ppermute(sent, axis_name, perm)
         hop_bad = jnp.sum((remote_sent != rtags).astype(jnp.int32))
 
     # all-gather wire, row-tagged: row i's tag is built by rank i with
@@ -570,7 +577,8 @@ def ring_quantized_sum(flat: jnp.ndarray, axis_name: str, exp: int, man: int,
         gwire = pack_exmy_blocked(res, exp, man, block_size)
     else:
         gwire = pack_exmy(res, exp, man) if packed else res
-    gathered = lax.all_gather(gwire, axis_name, axis=0, tiled=False)
+    with jax.named_scope(scopes.WIRE_COLLECTIVE):
+        gathered = lax.all_gather(gwire, axis_name, axis=0, tiled=False)
     if have_fault:
         # gather-site fault: rank k's RECEIVED copy of row (k+1) mod W
         # is corrupted — only that replica's rebuilt vector diverges,
@@ -626,9 +634,10 @@ def ring_quantized_sum(flat: jnp.ndarray, axis_name: str, exp: int, man: int,
     for i in range(1, w):
         full_digest = digest_concat(full_digest, i * row_words,
                                     row_digests[i])
-    rep = lax.all_gather(
-        jnp.stack([gtag, full_digest, hop_bad.astype(jnp.uint32)]),
-        axis_name, axis=0, tiled=False)
+    with jax.named_scope(scopes.WIRE_COLLECTIVE):
+        rep = lax.all_gather(
+            jnp.stack([gtag, full_digest, hop_bad.astype(jnp.uint32)]),
+            axis_name, axis=0, tiled=False)
     gather_bad = jnp.sum((row_tags != rep[:, 0]).astype(jnp.int32))
     report = {
         "hop_bad": jnp.sum(rep[:, 2].astype(jnp.int32)),

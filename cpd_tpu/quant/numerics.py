@@ -49,6 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import scopes
+
 __all__ = ["cast_to_format", "cast_body", "cast_oracle", "max_finite",
            "cast_body_sr", "cast_to_format_sr", "cast_oracle_sr",
            "sr_bits_at", "cast_to_format_sr_at",
@@ -570,6 +572,7 @@ def pack_code(x: jnp.ndarray, exp_bits: int, man_bits: int) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
+@jax.named_scope(scopes.WIRE_PACK)
 def pack_exmy(x: jnp.ndarray, exp_bits: int, man_bits: int) -> jnp.ndarray:
     """Pack fp32 values already in the (exp_bits, man_bits) value set into
     little-endian uint8 code words of shape ``x.shape + (wire_bytes(),)``."""
@@ -616,6 +619,7 @@ def unpack_code(code: jnp.ndarray, exp_bits: int,
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
+@jax.named_scope(scopes.WIRE_UNPACK)
 def unpack_exmy(packed: jnp.ndarray, exp_bits: int,
                 man_bits: int) -> jnp.ndarray:
     """Inverse of `pack_exmy`: uint8 ``(..., wire_bytes())`` -> fp32 ``(...)``
@@ -828,6 +832,7 @@ def cast_to_format_blocked(x: jnp.ndarray, exp_bits: int, man_bits: int,
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3))
+@jax.named_scope(scopes.WIRE_PACK)
 def pack_exmy_blocked(x: jnp.ndarray, exp_bits: int, man_bits: int,
                       block_size: int) -> jnp.ndarray:
     """Quantize-and-pack into the block-scaled wire: shift, RTNE-cast
@@ -855,6 +860,7 @@ def pack_exmy_blocked(x: jnp.ndarray, exp_bits: int, man_bits: int,
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+@jax.named_scope(scopes.WIRE_UNPACK)
 def unpack_exmy_blocked(packed: jnp.ndarray, exp_bits: int, man_bits: int,
                         n: int, block_size: int) -> jnp.ndarray:
     """Inverse of `pack_exmy_blocked`: split the sidecar lane off the

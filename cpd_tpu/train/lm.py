@@ -33,6 +33,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.transformer import lm_param_specs
 from ..compat import shard_map
+from ..obs import scopes
 from ..parallel.dist import grad_sr_key, sum_gradients
 from ..parallel.emulate import emulate_node_reduce
 from .state import (TrainState, make_sharded_stepper, reject_norm_based,
@@ -222,9 +223,10 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 tgts_u = targets.reshape(n, mb, targets.shape[1])
                 prev = []
                 for mi in range(n - 1):
-                    (_, (s_mi, n_mi, h_mi)), g_mi = jax.value_and_grad(
-                        loss_of, has_aux=True)(state.params, toks_u[mi],
-                                               tgts_u[mi], jnp.int32(mi))
+                    with jax.named_scope(scopes.LOSS_GRAD):
+                        (_, (s_mi, n_mi, h_mi)), g_mi = jax.value_and_grad(
+                            loss_of, has_aux=True)(state.params, toks_u[mi],
+                                                   tgts_u[mi], jnp.int32(mi))
                     micro_sums.append(s_mi)
                     micro_ns.append(n_mi)
                     micro_hits.append(h_mi)
@@ -283,8 +285,9 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                     loss_of, has_aux=True)(state.params, tk, tg, micro_idx)
                 return micro_idx + 1, (grads, *aux)
 
-            _, (stacked, sums, ns, hits) = lax.scan(
-                micro, jnp.zeros([], jnp.int32), (toks, tgts))
+            with jax.named_scope(scopes.LOSS_GRAD):
+                _, (stacked, sums, ns, hits) = lax.scan(
+                    micro, jnp.zeros([], jnp.int32), (toks, tgts))
 
             stacked = jax.tree.map(sp_tp_reduce, stacked, specs)
             if sfac is not None:
@@ -306,21 +309,24 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             if verify_reduce or quant_stats:
                 reduced, vreport = reduced
 
-        updates, new_opt = tx.update(reduced, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, new_opt = tx.update(reduced, state.opt_state,
+                                         state.params)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(step=state.step + 1, params=new_params,
                                batch_stats=state.batch_stats,
                                opt_state=new_opt)
         # metrics use the dp/sp token count only (tp ranks duplicate the
         # same tokens, and these psums exclude tp)
         from ..resilience.guard import guard_metrics
-        total_n = lax.psum(ns.sum(), (axis_dp, axis_sp))
-        metrics = {
-            **guard_metrics(new_opt),
-            "loss": lax.psum(sums.sum(), (axis_dp, axis_sp)) / total_n,
-            "accuracy": lax.psum(hits.sum().astype(jnp.float32),
-                                 (axis_dp, axis_sp)) / total_n,
-        }
+        with jax.named_scope(scopes.METRICS):
+            total_n = lax.psum(ns.sum(), (axis_dp, axis_sp))
+            metrics = {
+                **guard_metrics(new_opt),
+                "loss": lax.psum(sums.sum(), (axis_dp, axis_sp)) / total_n,
+                "accuracy": lax.psum(hits.sum().astype(jnp.float32),
+                                     (axis_dp, axis_sp)) / total_n,
+            }
         if vreport is not None:
             f32 = jnp.float32
             if verify_reduce:

@@ -41,6 +41,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..compat import shard_map
+from ..obs import scopes
 from ..parallel.dist import grad_sr_key, sum_gradients
 from ..parallel.emulate import emulate_node_reduce
 from .state import TrainState
@@ -318,8 +319,10 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             correct, counted = _count_hits(logits, y)
             return (new_stats, micro_idx + 1), (grads, loss, correct, counted)
 
-        (final_stats, _), (stacked_grads, losses, corrects, counts) = lax.scan(
-            micro, (batch_stats, jnp.zeros([], jnp.int32)), (images, labels))
+        with jax.named_scope(scopes.LOSS_GRAD):
+            (final_stats, _), (stacked_grads, losses, corrects, counts) = \
+                lax.scan(micro, (batch_stats, jnp.zeros([], jnp.int32)),
+                         (images, labels))
         return (stacked_grads, final_stats, losses.sum(), corrects.sum(),
                 counts.sum())
 
@@ -436,10 +439,11 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 prev = []
                 for mi in range(n - 1):
                     rngs_mi = micro_rngs(state.step, jnp.int32(mi))
-                    (_, (lg, stats_c, l_mi)), g_mi = jax.value_and_grad(
-                        loss_of, has_aux=True)(model_params, stats_c,
-                                               imgs[mi], lbls[mi],
-                                               rngs_mi)
+                    with jax.named_scope(scopes.LOSS_GRAD):
+                        (_, (lg, stats_c, l_mi)), g_mi = jax.value_and_grad(
+                            loss_of, has_aux=True)(model_params, stats_c,
+                                                   imgs[mi], lbls[mi],
+                                                   rngs_mi)
                     c_mi, n_mi = _count_hits(lg, lbls[mi])
                     micro_losses.append(l_mi)
                     micro_correct.append(c_mi)
@@ -528,38 +532,41 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 if verify_reduce or quant_stats:
                     reduced, vreport = reduced
 
-        if update_fn is not None:
-            # custom update (e.g. parallel/zero.py ZeRO: shard-local
-            # optimizer math); must return params in the STORED layout
-            # (full replicated by default; the rank's shard when
-            # params_spec/unpack_params are in play) and the (possibly
-            # sharded) new opt state.
-            # With reduce_in_update the step's precision settings ride
-            # along so the updater's collective cannot drift from the
-            # emulate-node quantization above.  The SR key is the SAME
-            # fold the replicated path hands sum_gradients, so a ZeRO
-            # reduce-scatter draws exactly the bits the replicated
-            # faithful reduction would (parallel/zero.py).
-            if pre_sharded_vec is not None:
-                # ZeRO-2 overlap: the taps already ran the per-bucket
-                # reduce-scatter — the update just consumes the shards
-                new_params, new_opt = update_fn(pre_sharded_vec, state,
-                                                axis_name,
-                                                pre_sharded=True)
+        with jax.named_scope(scopes.OPTIMIZER):
+            if update_fn is not None:
+                # custom update (e.g. parallel/zero.py ZeRO: shard-local
+                # optimizer math); must return params in the STORED layout
+                # (full replicated by default; the rank's shard when
+                # params_spec/unpack_params are in play) and the (possibly
+                # sharded) new opt state.
+                # With reduce_in_update the step's precision settings ride
+                # along so the updater's collective cannot drift from the
+                # emulate-node quantization above.  The SR key is the SAME
+                # fold the replicated path hands sum_gradients, so a ZeRO
+                # reduce-scatter draws exactly the bits the replicated
+                # faithful reduction would (parallel/zero.py).
+                if pre_sharded_vec is not None:
+                    # ZeRO-2 overlap: the taps already ran the per-bucket
+                    # reduce-scatter — the update just consumes the shards
+                    new_params, new_opt = update_fn(pre_sharded_vec, state,
+                                                    axis_name,
+                                                    pre_sharded=True)
+                else:
+                    quant_kw = dict(use_aps=use_aps, grad_exp=grad_exp,
+                                    grad_man=grad_man, use_kahan=use_kahan,
+                                    mode=mode, rounding=grad_rounding,
+                                    key=sum_key, block_scale=block_scale,
+                                    block_size=block_size) \
+                        if reduce_in_update else {}
+                    new_params, new_opt = update_fn(reduced, state, axis_name,
+                                                    **quant_kw)
             else:
-                quant_kw = dict(use_aps=use_aps, grad_exp=grad_exp,
-                                grad_man=grad_man, use_kahan=use_kahan,
-                                mode=mode, rounding=grad_rounding,
-                                key=sum_key, block_scale=block_scale,
-                                block_size=block_size) \
-                    if reduce_in_update else {}
-                new_params, new_opt = update_fn(reduced, state, axis_name,
-                                                **quant_kw)
-        else:
-            updates, new_opt = tx.update(reduced, state.opt_state,
-                                         state.params)
-            new_params = optax.apply_updates(state.params, updates)
-        new_stats = jax.tree.map(lambda s: lax.pmean(s, axis_name), new_stats)
+                updates, new_opt = tx.update(reduced, state.opt_state,
+                                             state.params)
+                new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(scopes.METRICS):
+            new_stats = jax.tree.map(lambda s: lax.pmean(s, axis_name),
+                                     new_stats)
 
         new_state = TrainState(step=state.step + 1, params=new_params,
                                batch_stats=new_stats, opt_state=new_opt)
@@ -569,21 +576,23 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         # with_fault_injection; {} otherwise, so the metric dict shape is
         # unchanged for unguarded runs.
         from ..resilience.guard import guard_metrics
-        metrics = {
-            **guard_metrics(new_opt),
-            # loss is the per-rank sum of micro losses (already /world/n);
-            # psum across ranks gives the global mean (mix.py:240-242).
-            # (`loss` aux output is the UNSCALED per-micro loss, so no
-            # scale division is needed for either static or dynamic.)
-            "loss": lax.psum(loss, axis_name),
-            # element counts (not shape[0]) so dense label maps (FCN pixel
-            # accuracy, minus ignore_label pixels) and flat class labels
-            # share one metric definition.
-            "accuracy": lax.psum(correct.astype(jnp.float32), axis_name)
-                        / jnp.maximum(
-                            lax.psum(counted.astype(jnp.float32), axis_name),
-                            1.0),
-        }
+        with jax.named_scope(scopes.METRICS):
+            metrics = {
+                **guard_metrics(new_opt),
+                # loss is the per-rank sum of micro losses (already /world/n);
+                # psum across ranks gives the global mean (mix.py:240-242).
+                # (`loss` aux output is the UNSCALED per-micro loss, so no
+                # scale division is needed for either static or dynamic.)
+                "loss": lax.psum(loss, axis_name),
+                # element counts (not shape[0]) so dense label maps (FCN pixel
+                # accuracy, minus ignore_label pixels) and flat class labels
+                # share one metric definition.
+                "accuracy": lax.psum(correct.astype(jnp.float32), axis_name)
+                            / jnp.maximum(
+                                lax.psum(counted.astype(jnp.float32),
+                                         axis_name),
+                                1.0),
+            }
         if vreport is not None:
             # replicated scalars: the wire-integrity verdict / numeric-
             # health telemetry of THIS step's reduce, consumed by the

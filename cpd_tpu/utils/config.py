@@ -154,7 +154,9 @@ def build_obs(args: argparse.Namespace, *, run: str,
                 "finish": lambda **_kw: None}
     from cpd_tpu.obs import FlightRecorder, MetricsRegistry, Tracer
     cap = int(getattr(args, "obs_flight", 256) or 0)
-    tracer = Tracer(run, meta=meta)
+    # with --profile-dir too, the spans also land in the profiler's trace
+    tracer = Tracer(run, meta=meta,
+                    annotate=bool(getattr(args, "profile_dir", None)))
     registry = MetricsRegistry()
     flight = (FlightRecorder(os.path.join(obs_dir, "flight.jsonl"),
                              capacity=cap) if cap > 0 else None)

@@ -152,6 +152,46 @@ def test_tracer_ring_is_bounded_and_counts_drops():
     assert [e[3] for e in tr.events] == [6, 7, 8, 9]   # newest kept
 
 
+def test_annotating_tracer_puts_spans_on_the_profilers_clock(monkeypatch):
+    """`Tracer(annotate=True)`: every span also enters a
+    `jax.profiler.TraceAnnotation` of its name, properly nested and closed
+    on an exception; the records are what they are without it, and the
+    plain tracer never touches jax.profiler."""
+    import jax.profiler
+    log = []
+
+    class FakeAnnotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name, exc[0]))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+    plain, noted = Tracer("t"), Tracer("t", annotate=True)
+    for tr in (plain, noted):
+        with tr.span("step", step=1):
+            with tr.span("data", step=1):
+                pass
+        with pytest.raises(KeyError):
+            with tr.span("checkpoint", step=2):
+                raise KeyError("x")
+    assert log == [("enter", "step"), ("enter", "data"),
+                   ("exit", "data", None), ("exit", "step", None),
+                   ("enter", "checkpoint"), ("exit", "checkpoint", KeyError)]
+    strip = lambda t: [(s[1], s[2], s[3], s[6], s[7]) for s in t.spans]
+    assert strip(plain) == strip(noted)
+    # the CLI wiring: --obs-dir and --profile-dir together turn it on
+    from cpd_tpu.utils.config import build_obs
+    on = build_obs(SimpleNamespace(obs_dir="o", profile_dir="p"), run="r")
+    off = build_obs(SimpleNamespace(obs_dir="o", profile_dir=None), run="r")
+    assert on["tracer"]._annotation is FakeAnnotation
+    assert off["tracer"]._annotation is None
+
+
 def test_null_tracer_is_inert():
     with NULL_TRACER.span("x", step=1):
         NULL_TRACER.event("y")
