@@ -22,6 +22,7 @@ Stdlib-only, like the rest of `obs`.
 | `wire.pack` / `wire.unpack` | `quant/numerics.py` | eXmY values to and from wire bytes |
 | `wire.collective` | `parallel/dist.py`, `parallel/ring.py` | the collectives themselves, whatever XLA renames or combines them into |
 | `reduce.scan` | `parallel/reduction.py` | the ordered requantised sum over ranks |
+| `reduce.local` | `parallel/dist.py` | the faithful reduction over an axis of one rank: no codec, no collective, each leaf in its own shape (`reduce.scan` inside it).  XLA fuses all of it into the update's fusions, whose root is `cpd.optimizer`'s: in a device trace it owns nothing, `cpd.optimizer` includes it, and the pipeline's cost at one rank is read by the e5m2-APS twin minus its fp32 control |
 | `cpd.optimizer` | `train/step.py`, `train/lm.py` | `tx.update` and `apply_updates` (or the custom `update_fn`) |
 | `cpd.metrics` | `train/step.py`, `train/lm.py` | the step's own telemetry `psum`s, and the batch statistics' `pmean` |
 | `kernel.<name>` | `ops/*.py`, around each `pl.pallas_call` | one Pallas kernel; the call's `name=` is the same `<name>` |
@@ -38,6 +39,7 @@ from __future__ import annotations
 __all__ = ["LOSS_GRAD", "EMULATE_NODE", "REDUCE", "OPTIMIZER", "METRICS",
            "APS_MAX_EXP", "APS_SCALE", "APS_UNSCALE", "WIRE_CAST",
            "WIRE_PACK", "WIRE_UNPACK", "WIRE_COLLECTIVE", "REDUCE_SCAN",
+           "REDUCE_LOCAL",
            "KERNEL_PREFIX", "KERNELS", "kernel_name"]
 
 LOSS_GRAD = "cpd.loss_grad"
@@ -54,6 +56,7 @@ WIRE_PACK = "wire.pack"
 WIRE_UNPACK = "wire.unpack"
 WIRE_COLLECTIVE = "wire.collective"
 REDUCE_SCAN = "reduce.scan"
+REDUCE_LOCAL = "reduce.local"
 
 KERNEL_PREFIX = "kernel."
 KERNEL_QUANTIZE = "kernel.quantize"

@@ -4,11 +4,16 @@ TPU-native re-implementation of reference CPDtorch/utils/dist_util.py on top
 of XLA collectives.  The reference runs one NCCL op per parameter from a
 Python loop; here everything is traced once under `shard_map`/`pjit` so XLA
 schedules the collectives on ICI back-to-back (and can overlap them).  On
-TPU the faithful gathers are fused into few large per-dtype buckets
-(`_bucketed_quantized_sum`), and when APS has pre-quantized the values the
-wire carries the bit-packed eXmY code words (`quant.numerics.pack_exmy`,
-1-3 bytes per element for any sub-fp32 format) — both bit-identical to
-the per-leaf fp32 path.
+TPU the faithful gathers of small leaves are fused into few large per-dtype
+buckets, and when APS has pre-quantized the values the wire carries the
+bit-packed eXmY code words (`quant.numerics.pack_exmy`, 1-3 bytes per
+element for any sub-fp32 format) — both bit-identical to the per-leaf
+fp32 path.  A leaf is given the wire's layout (flat, concatenated, packed)
+only where a wire needs it (`faithful_plan`): over an axis of ONE rank
+there is no codec, no gather and no flattening at all, so the ordered sum
+is a few elementwise operations in the leaf's own shape that XLA fuses
+into the optimizer's pass; over several ranks a leaf that fills a bucket
+alone is packed, gathered and unpacked in its own shape.
 
 Semantics map (reference → here):
 
@@ -65,7 +70,7 @@ from .ring import hierarchical_ring_sum
 __all__ = [
     "dist_init", "sum_gradients", "broadcast_from", "replicate",
     "all_reduce_mean", "host_batch_to_global", "quantize_tree_sr",
-    "grad_sr_key",
+    "grad_sr_key", "faithful_plan",
 ]
 
 
@@ -237,55 +242,106 @@ def _gather_leaf(g: jnp.ndarray, axis_name, wire=None) -> jnp.ndarray:
 _BUCKET_ELEMS = DEFAULT_BUCKET_ELEMS
 
 
-def _bucketed_quantized_sum(grads: Any, axis_name, grad_exp: int,
-                            grad_man: int, use_kahan: bool,
-                            bucket_elems: int = _BUCKET_ELEMS,
-                            wire=None, key=None, starts=None) -> Any:
-    """Faithful ordered reduction over few large buckets instead of one
-    collective per parameter (SURVEY.md §7 hard-part 4).
+def _axis_size(axis_name) -> int:
+    """Static size of one mesh axis, or the product over a sequence of
+    them: `lax.psum` of a Python constant is evaluated at trace time."""
+    return lax.psum(1, axis_name)
 
-    Leaves are flattened and concatenated per dtype into buckets of at most
-    `bucket_elems` elements (`overlap.bucket_layout` — the ONE capping
-    function, shared with the bucketed ring and the overlap taps); each
-    bucket is all_gathered ONCE and reduced with ONE rank-ordered
-    requantizing scan, then split back.  The quantized accumulation is
-    elementwise, so concatenation changes nothing about any element's
-    value — results are bit-identical to the per-leaf path (the
-    reference's per-parameter loop, dist_util.py:60-89), with W x leaf_count
-    collective launches collapsed to W x bucket_count.
+
+def faithful_plan(sizes: Sequence[int], world: int,
+                  bucket_elems: Optional[int] = None,
+                  groups: Optional[Sequence] = None) -> dict:
+    """Which layout each leaf of a faithful reduction gets — a pure
+    function of what the trace-time code can see (leaf sizes, axis size,
+    bucket cap), which `_faithful_quantized_sum` itself calls to decide,
+    so a count made from it cannot drift from the path.
+
+    * ``world == 1``: no wire.  Every leaf is reduced in its own shape
+      with no codec and no collective, whatever ``bucket_elems`` says
+      (buckets exist to save collective launches).
+    * ``world > 1``, ``bucket_elems=None``: the per-leaf path — every
+      leaf gathered in its own shape.
+    * ``world > 1`` with a cap: `overlap.bucket_layout` per group
+      (``groups``: one hashable per leaf, e.g. its dtype; leaves of
+      different groups never share a bucket).  A bucket of ONE leaf —
+      every leaf at or above the cap, and a small one the greedy layout
+      leaves alone — needs no flattening: it is packed, gathered and
+      unpacked in its own shape.  Buckets of several leaves are
+      concatenated flat, one gather each.
+
+    Returns what the path consumes: ``codec`` (a wire exists: leaves are
+    gathered, bit-packed where the format packs), ``own_shape_leaves``
+    (leaf indices) and ``buckets`` (tuples of leaf indices, each of two or
+    more)."""
+    sizes = [int(n) for n in sizes]
+    if world == 1 or bucket_elems is None:
+        layout = [[i] for i in range(len(sizes))]
+    else:
+        # group GLOBALLY (order of first appearance), then cap each group
+        # with the shared layout function — an interleaved-dtype tree
+        # still packs into few large per-dtype buckets instead of
+        # breaking a bucket at every dtype change
+        by_group: dict = {}
+        for i in range(len(sizes)):
+            by_group.setdefault(None if groups is None else groups[i],
+                                []).append(i)
+        layout = [[idxs[j] for j in local]
+                  for idxs in by_group.values()
+                  for local in bucket_layout([sizes[i] for i in idxs],
+                                             bucket_elems)]
+    return {"codec": world > 1,
+            "own_shape_leaves": tuple(b[0] for b in layout if len(b) == 1),
+            "buckets": tuple(tuple(b) for b in layout if len(b) > 1)}
+
+
+def _faithful_quantized_sum(grads: Any, axis_name, grad_exp: int,
+                            grad_man: int, use_kahan: bool,
+                            bucket_elems: Optional[int] = None,
+                            wire=None, key=None, starts=None) -> Any:
+    """Faithful ordered reduction, each leaf in the layout `faithful_plan`
+    gives it: the reference's per-parameter loop (dist_util.py:60-89) with
+    its W x leaf_count collective launches collapsed to W x bucket_count
+    (SURVEY.md §7 hard-part 4), and no collective at all over one rank.
+
+    The quantized accumulation is elementwise, so neither concatenation
+    nor a leaf's shape changes any element's value, and the codec is the
+    identity on values the pre-quantize produced (numerics "Losslessness
+    contract"): every layout is bit-identical to the per-leaf path, with
+    or without the wire.
 
     With stochastic rounding (`key` given) the per-element bits are indexed
-    by GLOBAL flat offset (numerics.sr_bits_at), so bucketed and per-leaf
-    reductions draw the SAME bits — bit-identical results, invariant to the
-    bucket layout (and to ZeRO sharding, parallel/zero.py).  ``starts``
-    overrides the leaves' global offsets (overlap taps reducing a bucket
-    of a larger layout).
+    by GLOBAL flat offset (numerics.sr_bits_at), so every layout draws the
+    SAME bits — invariant to bucketing (and to ZeRO sharding,
+    parallel/zero.py).  ``starts`` overrides the leaves' global offsets
+    (overlap taps reducing a bucket of a larger layout).
     """
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     starts = _leaf_starts(grads) if starts is None else list(starts)
+    plan = faithful_plan([l.size for l in leaves], _axis_size(axis_name),
+                         bucket_elems,
+                         groups=[jnp.dtype(l.dtype) for l in leaves])
+
+    qsum = functools.partial(quantized_sum, exp=grad_exp, man=grad_man,
+                             use_kahan=use_kahan, key=key)
+
+    def offsets(i):
+        """Leaf i's global SR bit indices, shaped like the leaf."""
+        return None if key is None else _leaf_offsets(starts[i], leaves[i])
+
     out = [None] * len(leaves)
-    # group by dtype GLOBALLY (order of first appearance), then cap each
-    # group with the shared layout function — an interleaved-dtype tree
-    # still packs into few large per-dtype buckets instead of breaking a
-    # bucket at every dtype change
-    by_dtype: dict = {}
-    for i, g in enumerate(leaves):
-        by_dtype.setdefault(jnp.dtype(g.dtype), []).append(i)
-    buckets = []
-    for idxs in by_dtype.values():
-        for local in bucket_layout([leaves[i].size for i in idxs],
-                                   bucket_elems):
-            buckets.append([idxs[j] for j in local])
-    for bucket in buckets:
-        flat = (leaves[bucket[0]].reshape(-1) if len(bucket) == 1 else
-                jnp.concatenate([leaves[i].reshape(-1)
-                                 for i in bucket]))
-        gathered = _gather_leaf(flat, axis_name, wire=wire)
-        offs = (None if key is None else jnp.concatenate(
-            [_leaf_offsets(starts[i], leaves[i]).ravel()
-             for i in bucket]))
-        red = quantized_sum(gathered, grad_exp, grad_man, use_kahan,
-                            key=key, offsets=offs)
+    for i in plan["own_shape_leaves"]:
+        if plan["codec"]:
+            out[i] = qsum(_gather_leaf(leaves[i], axis_name, wire=wire),
+                          offsets=offsets(i))
+        else:
+            # what a gather over one rank returns, without the gather
+            with jax.named_scope(scopes.REDUCE_LOCAL):
+                out[i] = qsum(leaves[i][None], offsets=offsets(i))
+    for bucket in plan["buckets"]:
+        flat = jnp.concatenate([leaves[i].reshape(-1) for i in bucket])
+        red = qsum(_gather_leaf(flat, axis_name, wire=wire),
+                   offsets=(None if key is None else jnp.concatenate(
+                       [offsets(i).ravel() for i in bucket])))
         off = 0
         for i in bucket:
             n = leaves[i].size
@@ -318,7 +374,7 @@ def sum_gradients(grads: Any, axis_name: str | Sequence[str],
 
     use_aps     → APS exponent shifting around the reduction (aps.py).
     use_kahan   → Kahan-compensated ordered accumulation (dist_util.py:72-89).
-    mode        → "faithful" (gather + ordered scan) | "fast" (quantize+psum)
+    mode        → "faithful" (gather + ordered sum) | "fast" (quantize+psum)
                   | "ring" (chunked ppermute reduce-scatter + all-gather
                   with bit-packed eXmY partials on the wire — the ordered
                   requantized reduction at ~2/W of the gather wire bytes
@@ -334,9 +390,18 @@ def sum_gradients(grads: Any, axis_name: str | Sequence[str],
                   auto: on for TPU — fewer collective launches riding ICI
                   — off elsewhere (on the CPU mesh the gather is a plain
                   memcpy and the bucket concat/split copies measured ~17%
-                  slower on a ResNet-18-sized pytree).
+                  slower on a ResNet-18-sized pytree).  A layout exists
+                  for the wire's sake, so it is decided from the axis
+                  size and the leaf sizes (`faithful_plan`): over an
+                  axis of ONE rank nothing is bucketed, packed or
+                  gathered whatever this says (each leaf is summed in
+                  its own shape, under the scope `reduce.local`), and
+                  over several ranks a leaf left alone in its bucket —
+                  every leaf at or above the cap — is packed, gathered
+                  and unpacked in its own shape, never flattened.
     bucket_elems→ per-bucket element cap (default `_BUCKET_ELEMS`, 4M).
-                  Setting it implies ``bucket=True`` for faithful mode.
+                  Setting it implies ``bucket=True`` for faithful mode
+                  (and, like it, changes nothing over one rank).
                   RING mode is always bucketed at this cap via the same
                   greedy layout the overlapped backward-reduce emits
                   (`overlap.bucket_layout` / `BucketPlan.for_tree`), so
@@ -580,23 +645,13 @@ def sum_gradients(grads: Any, axis_name: str | Sequence[str],
             with jax.named_scope(scopes.WIRE_COLLECTIVE):
                 reduced = jax.tree.map(  # cpd: disable=kahan-ordering
                     lambda g: lax.psum(g, axis_name), grads)
-        elif bucket:
-            reduced = _bucketed_quantized_sum(
+        else:
+            reduced = _faithful_quantized_sum(
                 grads, axis_name, grad_exp, grad_man, use_kahan,
-                bucket_elems=(bucket_elems if bucket_elems is not None
+                bucket_elems=(None if not bucket else
+                              bucket_elems if bucket_elems is not None
                               else _BUCKET_ELEMS),
                 wire=wire, key=k_sum, starts=offset_starts)
-        else:
-            leaves, treedef = jax.tree_util.tree_flatten(grads)
-            starts = (_leaf_starts(grads) if offset_starts is None
-                      else list(offset_starts))
-            out = [quantized_sum(
-                       _gather_leaf(g, axis_name, wire=wire),
-                       grad_exp, grad_man, use_kahan, key=k_sum,
-                       offsets=(None if k_sum is None
-                                else _leaf_offsets(st, g)))
-                   for st, g in zip(starts, leaves)]
-            reduced = jax.tree_util.tree_unflatten(treedef, out)
 
     if use_aps:
         reduced = aps_unscale(reduced, shifts)

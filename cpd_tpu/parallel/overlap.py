@@ -10,7 +10,7 @@ never start a single collective hop while backward compute was still
 running.  This module removes the barrier:
 
 * :func:`bucket_layout` — the ONE greedy bucket-capping function, shared
-  with ``dist._bucketed_quantized_sum`` so the overlapped and the
+  with ``dist._faithful_quantized_sum`` so the overlapped and the
   post-backward bucketed paths can never disagree about the layout;
 * :class:`BucketPlan` — the static layout (leaf sizes, global flat
   offsets in parallel/dist.py's `_leaf_starts` space, bucket membership)
@@ -90,7 +90,7 @@ def bucket_layout(sizes: Sequence[int], bucket_elems: int,
     forms its own bucket), preserving leaf order.  ``group_ids`` (e.g.
     dtypes) force a bucket break between unequal neighbors — the faithful
     gather path buckets per dtype because the gathered stack must be one
-    array.  This is THE layout function: `dist._bucketed_quantized_sum`,
+    array.  This is THE layout function: `dist._faithful_quantized_sum`,
     the bucketed ring and the overlap taps all call it, so their bucket
     boundaries cannot drift."""
     if bucket_elems < 1:

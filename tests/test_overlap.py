@@ -150,6 +150,23 @@ def test_overlap_bitwise_invariance_faithful(world, exp, man, variant):
         _bitwise(per_leaf[name], overlapped[name], f"overlapped {name}")
 
 
+@pytest.mark.parametrize("world,bucket_elems", [(1, 40), (1, None),
+                                                (4, None)])
+def test_overlap_taps_follow_the_faithful_layouts(world, bucket_elems):
+    """The taps call `sum_gradients` per bucket, so they take the
+    faithful path's layouts with it: over one rank no codec and no
+    gather, with no cap one bucket of the whole tree; APS on, so the
+    wire would carry packed bytes.  Bitwise the per-leaf monolith."""
+    mesh = make_mesh(dp=world, devices=jax.devices()[:world])
+    tree = _tree(world, seed=11 + world)
+    per_leaf = _reference(mesh, tree, bucket=False, use_aps=True,
+                          grad_exp=5, grad_man=2)
+    overlapped = _run_overlapped(mesh, tree, mode="faithful",
+                                 bucket_elems=bucket_elems, use_aps=True)
+    for name in tree:
+        _bitwise(per_leaf[name], overlapped[name], f"overlapped {name}")
+
+
 @pytest.mark.parametrize("variant", ["nearest", "stochastic", "kahan"])
 def test_overlap_bitwise_invariance_ring(variant):
     """Ring overlap on/off at a FIXED bucket layout is bitwise equal

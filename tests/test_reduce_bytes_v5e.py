@@ -1,0 +1,84 @@
+"""Hold the byte count of the one-rank gradient reduction, by compiling the
+benchmark's LM step for a described `v5e:2x2` (the `on-chip-measurement`
+guide's third rehearsal, as `benchmark/tests/test_compile_v5e.py` makes it).
+
+The e5m2-APS twin and its fp32 control differ only in the gradient pipeline,
+so the difference of XLA's own `bytes accessed` is what the pipeline moves.
+With the leaf kept in its shape and no codec at one rank that is the one
+write and read of the gradient tree APS cannot avoid (a leaf's maximum must
+be known before any element is scaled); a flattening, a pack or an unpack
+that comes back shows as tens of GB.  Nothing runs: a count, not a time.
+
+Marked `slow`: it loads the TPU's library, which one process at a time may
+do, and compiles two 535 M-parameter steps (minutes).
+
+    python -m pytest tests/test_reduce_bytes_v5e.py -m slow -q -s
+"""
+
+from __future__ import annotations
+
+import re
+import sys
+
+import pytest
+
+# the rehearsal's fixtures (the described topology, compiled kernels, a
+# step that can be lowered) and its compile, as the benchmark has them
+from benchmark.tests.test_compile_v5e import (  # noqa: F401
+    compile_cell, compiled_kernels, lowerable_lm_step, topo)
+from cpd_tpu.obs import scopes
+
+pytestmark = pytest.mark.slow
+
+TWIN, CONTROL = "starcoder2_3b_aps_e5m2_1chip", "starcoder2_3b_fp32_1chip"
+GAP_LIMIT_GB = 10.0      # 48.7 before the one-rank path, 6.5 with it
+
+
+def compile_step(cell: str, topo):
+    """The cell's step compiled for a described chip, with the bucket cap
+    given so that the trace takes the TPU's bucketed path (`bucket`
+    defaults to on only where the backend is a TPU)."""
+    from benchmark import run
+    from cpd_tpu.parallel.dist import _BUCKET_ELEMS
+
+    found = dict(run.discover()[cell])
+    reduce = found["traffic"]["reduce"]
+    if reduce["mode"] == "faithful":
+        found["traffic"] = {**found["traffic"], "reduce": {
+            **reduce, "bucket_elems": _BUCKET_ELEMS}}
+    return compile_cell(found, topo)
+
+
+def census(compiled) -> dict:
+    """XLA's byte count and the entry computation's operations by kind."""
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    ops = re.findall(r"^\s+(?:ROOT )?%?[\w.-]+ = \S+ ([\w-]+)\(", entry,
+                     re.MULTILINE)
+    return {"gbytes": cost["bytes accessed"] / 1e9,
+            "fusions": ops.count("fusion"), "copies": ops.count("copy"),
+            "reshapes": ops.count("reshape"),
+            "op_names": re.findall(r'op_name="([^"]+)"', text)}
+
+
+def test_one_rank_pipeline_moves_little_more_than_the_control(
+        topo, compiled_kernels, lowerable_lm_step, capsys):
+    counts = {cell: census(compile_step(cell, topo))
+              for cell in (TWIN, CONTROL)}
+    gap = counts[TWIN]["gbytes"] - counts[CONTROL]["gbytes"]
+    with capsys.disabled():
+        for cell, c in counts.items():
+            print(f"\n{cell}: {c['gbytes']:.2f} GB accessed a step, "
+                  f"{c['fusions']} fusions, {c['copies']} copy, "
+                  f"{c['reshapes']} reshape in the entry computation",
+                  file=sys.stderr)
+        print(f"twin minus control: {gap:.2f} GB (limit {GAP_LIMIT_GB})",
+              file=sys.stderr)
+    names = counts[TWIN]["op_names"]
+    assert any(scopes.REDUCE_LOCAL in n for n in names)
+    wired = sorted({n for n in names
+                    if scopes.WIRE_PACK in n or scopes.WIRE_UNPACK in n})
+    assert not wired, wired[:5]
+    assert 0 < gap < GAP_LIMIT_GB

@@ -59,11 +59,11 @@ def scope_paths(compiled) -> set:
             for n in names} - {()}
 
 
-def _vision(mode, use_aps):
+def _vision(mode, use_aps, dp=4):
     from cpd_tpu.models.tiny import tiny_cnn
     from cpd_tpu.train import (create_train_state, make_optimizer,
                                make_train_step)
-    mesh = make_mesh(dp=4, devices=jax.devices()[:4])
+    mesh = make_mesh(dp=dp, devices=jax.devices()[:dp])
     model = tiny_cnn(num_classes=4, width=4)
     tx = make_optimizer("sgd", lambda step: 0.1, momentum=0.9)
     state = create_train_state(model, tx, jnp.zeros((2, 8, 8, 3)),
@@ -118,6 +118,24 @@ def test_compiled_step_names_its_layers(builder, mode, use_aps):
         sorted(under ^ UNDER_REDUCE[mode, use_aps]))
     if builder is _lm:
         assert scopes.KERNEL_FLASH_GQA_FWD in found
+
+
+def test_one_rank_reduction_is_local_and_has_no_wire():
+    """Over an axis of one rank the faithful reduction runs under
+    `reduce.local`: nothing is packed, unpacked or gathered, so a
+    one-chip trace reads nothing under those names."""
+    step, args = _vision("faithful", True, dp=1)
+    paths = scope_paths(step.lower(*args).compile())
+    found = {c for p in paths for c in p}
+    SEEN.update(found)
+    assert found <= KNOWN and TOP <= found
+    under = {c for p in paths if scopes.REDUCE in p
+             for c in p[p.index(scopes.REDUCE) + 1:]}
+    assert under == APS | {scopes.WIRE_CAST, scopes.REDUCE_LOCAL,
+                           scopes.REDUCE_SCAN}, sorted(under)
+    assert all(p[p.index(scopes.REDUCE_SCAN) - 1] == scopes.REDUCE_LOCAL
+               for p in paths
+               if scopes.REDUCE in p and scopes.REDUCE_SCAN in p)
 
 
 def test_backward_is_marked_after_the_loss_grad_scope():
