@@ -52,6 +52,7 @@ class Window:
     step_samples: list         # seconds per step, one per group after the first
     losses: list               # device scalars, one per step
     spans: Spans
+    last_metrics: dict = None  # the last step's whole `metrics`, on the device
 
 
 def measure(step, state, batches, group: int, seconds: float,
@@ -83,4 +84,5 @@ def measure(step, state, batches, group: int, seconds: float,
     edges = [t_start] + stamps
     samples = [(b - a) / group for a, b in zip(edges, edges[1:])]
     return Window(state=state, steps=steps, seconds=t_end - t_start,
-                  step_samples=samples, losses=losses, spans=spans)
+                  step_samples=samples, losses=losses, spans=spans,
+                  last_metrics=metrics)

@@ -39,3 +39,17 @@ def wire_bytes_per_step(ctx: dict, args: dict):
 
 def peak_hbm_mb(ctx: dict, args: dict):
     return ctx["memory_peak_bytes"] / 1e6
+
+
+def step_metric(ctx: dict, args: dict):
+    """What the step itself reported under `key` in its last step of the
+    window (`loop.Window.last_metrics`): a counter a model puts into its
+    step's metrics.  Nothing when the step reports no such key."""
+    return ctx.get("last_metrics", {}).get(args["key"])
+
+
+def reading(ctx: dict, args: dict):
+    """A reading of `benchmark/check.py` (the first steps against the
+    plain reference's), by name: a distance, not a time."""
+    value = ctx.get("readings", {}).get(args["name"])
+    return value if value is not None and value == value else None

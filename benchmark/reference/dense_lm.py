@@ -38,45 +38,62 @@ def _gelu_tanh(x):
         jnp.sqrt(2.0 / jnp.pi) * (x + 0.044715 * x ** 3)))
 
 
+def _head_attention(q, k, v):
+    """One query head against its key-value head, causal: (T, hd) each."""
+    t, hd = q.shape
+    s = (q @ k.T) / jnp.sqrt(float(hd))
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jax.nn.softmax(s, -1) @ v
+
+
+def _block(x, p, heads, kv):
+    """One pre-LayerNorm block on one sequence, (T, d) -> (T, d)."""
+    t, d = x.shape
+    hd = d // heads
+    h = _layer_norm(x, p["ln1"])
+    q = (h @ p["wq"]["kernel"]).reshape(t, heads, hd)
+    kvp = (h @ p["wkv"]["kernel"]).reshape(t, kv, 2, hd)
+    q, k, v = _rope(q), _rope(kvp[:, :, 0]), kvp[:, :, 1]
+    # query head i reads key-value head i // (heads // kv).  A head at a
+    # time, (T, T) scores, recomputed in a backward pass
+    of = jnp.arange(heads) // (heads // kv)
+    a = jax.lax.map(
+        jax.checkpoint(lambda qkv: _head_attention(*qkv)),
+        (q.transpose(1, 0, 2), k.transpose(1, 0, 2)[of],
+         v.transpose(1, 0, 2)[of]))
+    x = x + a.transpose(1, 0, 2).reshape(t, d) @ p["wo"]["kernel"]
+    h = _layer_norm(x, p["ln2"])
+    return x + _gelu_tanh(h @ p["wi"]["kernel"]) @ p["wo_mlp"]["kernel"]
+
+
 def logits(params, tokens, config):
     """(T,) int32 tokens of ONE sequence -> (T, vocab) float32 logits."""
     heads = config["num_attention_heads"]
     kv = config["num_key_value_heads"]
-    d = config["hidden_size"]
-    hd = d // heads
-    t = tokens.shape[0]
     emb = params["embed"]["embedding"].astype(jnp.float32)
     x = emb[tokens]
-    mask = jnp.tril(jnp.ones((t, t), bool))
+    block = jax.checkpoint(_block, static_argnums=(2, 3))
     for i in range(config["num_hidden_layers"]):
-        p = params[f"block{i}"]
-        h = _layer_norm(x, p["ln1"])
-        q = (h @ p["wq"]["kernel"]).reshape(t, heads, hd)
-        kvp = (h @ p["wkv"]["kernel"]).reshape(t, kv, 2, hd)
-        q, k, v = _rope(q), _rope(kvp[:, :, 0]), kvp[:, :, 1]
-        rep = heads // kv        # query heads [g*rep, (g+1)*rep) read kv head g
-        groups = []
-        for g in range(kv):      # a group at a time: (rep, T, T) scores
-            s = jnp.einsum("qhd,kd->hqk", q[:, g * rep:(g + 1) * rep],
-                           k[:, g]) / jnp.sqrt(float(hd))
-            s = jnp.where(mask[None], s, -jnp.inf)
-            groups.append(jnp.einsum("hqk,kd->qhd", jax.nn.softmax(s, -1),
-                                     v[:, g]))
-        a = jnp.concatenate(groups, axis=1)
-        x = x + a.reshape(t, d) @ p["wo"]["kernel"]
-        h = _layer_norm(x, p["ln2"])
-        x = x + _gelu_tanh(h @ p["wi"]["kernel"]) @ p["wo_mlp"]["kernel"]
+        x = block(x, params[f"block{i}"], heads, kv)
     return _layer_norm(x, params["ln_f"]) @ emb.T
+
+
+def _sequence_loss(params, tokens, targets, config):
+    logp = jax.nn.log_softmax(logits(params, tokens, config), -1)
+    return -jnp.take_along_axis(logp, targets[:, None], 1).mean()
 
 
 def loss(params, tokens, targets, config):
     """Mean next-token cross-entropy over a (B, T) batch, one sequence at
-    a time, at the matmul precision a float32 reference needs on a TPU."""
+    a time, at the matmul precision a float32 reference needs on a TPU.
+
+    The `jax.checkpoint`s (a sequence, a layer, an attention head) change
+    no value: they make a backward pass through this function recompute
+    instead of keep, so that `jax.grad` of it fits beside nothing else on
+    a 16 GB chip at 2 x 4,096 tokens and the published widths."""
+    one = jax.checkpoint(
+        lambda p, a, b: _sequence_loss(p, a, b, config))
     with jax.default_matmul_precision("highest"):
-        rows = []
-        for i in range(tokens.shape[0]):
-            lg = logits(params, tokens[i], config)
-            logp = jax.nn.log_softmax(lg, -1)
-            rows.append(-jnp.take_along_axis(
-                logp, targets[i][:, None], 1).mean())
+        rows = [one(params, tokens[i], targets[i])
+                for i in range(tokens.shape[0])]
         return sum(rows) / len(rows)

@@ -35,16 +35,21 @@ def _bottleneck(x, p, stride):
     return jax.nn.relu(y + x)
 
 
+def _stem(images, conv, bn):
+    x = jax.nn.relu(_bn(_conv(images.astype(jnp.float32), conv, 2, 3), bn))
+    return lax.reduce_window(x, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
+                             [(0, 0), (1, 1), (1, 1), (0, 0)])
+
+
 def logits(params, images):
-    x = images.astype(jnp.float32)
-    x = jax.nn.relu(_bn(_conv(x, params["stem_conv"], 2, 3),
-                        params["stem_bn"]))
-    x = lax.reduce_window(x, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
-                          [(0, 0), (1, 1), (1, 1), (0, 0)])
+    x = jax.checkpoint(_stem)(images, params["stem_conv"], params["stem_bn"])
+    # `jax.checkpoint` makes a backward pass recompute a block from its
+    # input (256 images' activations do not fit otherwise); no value changes
+    block = jax.checkpoint(_bottleneck, static_argnums=(2,))
     for stage, blocks in enumerate(STAGES):
         for b in range(blocks):
             stride = 2 if (b == 0 and stage > 0) else 1
-            x = _bottleneck(x, params[f"layer{stage + 1}_block{b}"], stride)
+            x = block(x, params[f"layer{stage + 1}_block{b}"], stride)
     x = x.mean((1, 2))
     return x @ params["fc"]["kernel"] + params["fc"]["bias"]
 
@@ -55,3 +60,4 @@ def loss(params, images, labels, config=None):
     with jax.default_matmul_precision("highest"):
         logp = jax.nn.log_softmax(logits(params, images), -1)
         return -jnp.take_along_axis(logp, labels[:, None], 1).mean()
+

@@ -9,7 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from benchmark.runners.base import Runner, dtype_of, optimizer_of
+from benchmark.runners.base import (Runner, dtype_of, first_gradient_of,
+                                    optimizer_of)
 
 
 def model_kwargs(config: dict) -> dict:
@@ -52,7 +53,12 @@ def build(config: dict, traffic: dict, mesh, reference) -> Runner:
     def reference_loss(state, tokens, targets):
         return reference(state.params, tokens, targets, config)
 
+    def reference_grad(params, tokens, targets):
+        return jax.value_and_grad(reference)(params, tokens, targets, config)
+
     return Runner(init_state=init_state, make_batch=make_batch,
                   step=make_lm_train_step(model, tx, mesh,
                                           **traffic["reduce"]),
-                  items_per_step=batch * seq, reference_loss=reference_loss)
+                  items_per_step=batch * seq, reference_loss=reference_loss,
+                  reference_grad=reference_grad,
+                  first_gradient=first_gradient_of(config["optimizer"]))

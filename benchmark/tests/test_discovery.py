@@ -13,6 +13,7 @@ import subprocess
 import sys
 
 import conftest
+from benchmark import check
 
 ROOT = conftest.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -29,13 +30,18 @@ def test_new_files_are_found_with_no_code_edited(tmp_path):
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     b = bench()
     base = tmp_path / "benchmark"
+    limits = {}
+    for name in check.CHECKS:        # every reading: a limit, or null and why
+        limits[name + "_max"] = 0.05 if name == "update_rel_err" else None
+        limits[name + "_why"] = "drop-in"
     config = json.loads((base / "configs" / "starcoder2_3b_d4.json")
                         .read_text())
     config["num_hidden_layers"] = 2
     (base / "configs" / "another_lm.json").write_text(json.dumps(config))
     (base / "traffic" / "fp32_1x2048.json").write_text(json.dumps(
         {"batch_per_chip": 1, "seq_len": 2048, "group": 2,
-         "reduce": {"use_aps": False, "mode": "fast"}}))
+         "reduce": {"use_aps": False, "mode": "fast"},
+         **limits}))
     (base / "metrics" / "step.host_ms_p50.json").write_text(json.dumps(
         {"reader": "window:step_ms_percentile", "args": {"q": 50}}))
     b["configs"].append({"name": "another_lm", "source": "https://x.example",
@@ -62,6 +68,20 @@ def test_new_files_are_found_with_no_code_edited(tmp_path):
     assert old and all("step.host_ms_p50" not in cells[c]["metrics"]
                        ["per_layer"] for c in old)
 
+    # a traffic file says what each reading of its cell's first updates
+    # is held to: one left out, or null with no reason, is refused
+    for lacking, named in (
+            ({k: v for k, v in limits.items() if k != "loss_gap_max"},
+             "loss_gap_max"),
+            ({**limits, "grad_norm_gap_why": ""}, "grad_norm_gap_why")):
+        (base / "traffic" / "fp32_1x2048.json").write_text(json.dumps(
+            {"batch_per_chip": 1, "seq_len": 2048, "group": 2,
+             "reduce": {"use_aps": False, "mode": "fast"}, **lacking}))
+        failed = subprocess.run(
+            [sys.executable, str(base / "run.py"), "--list"],
+            capture_output=True, text=True, timeout=120)
+        assert failed.returncode != 0 and named in failed.stderr
+
     # a name that resolves to no file is an error, not a skipped cell
     b["workloads"][-1]["traffic"] = "no_such_traffic"
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(b))
@@ -76,7 +96,8 @@ def test_code_names_no_cell_configuration_or_metric():
              + [w["name"] for w in b["workloads"]]
              + [w["traffic"] for w in b["workloads"]]
              + [m["name"] for m in b["end_to_end"] + b["per_layer"]])
-    code = ["run.py", "loop.py", "trace_reduce.py",
+    code = ["run.py", "loop.py", "check.py", "readings.py",
+            "trace_reduce.py", "trace_scopes.py", "reference/sgd.py",
             "runners/base.py", "runners/train_lm.py",
             "runners/train_vision.py"]
     for rel in code:
@@ -139,6 +160,7 @@ def test_benchmark_json_keeps_to_the_drivers_rules():
         assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher")
         assert set(m.get("workloads", [])) <= set(cells)
+        assert m.get("workloads") != []         # a list names a cell
         assert os.path.exists(os.path.join(ROOT, "benchmark", "metrics",
                                            m["name"] + ".json"))
     for name in cells:

@@ -29,11 +29,23 @@ def drive(config, traffic, chips, seconds=0.5):
     return found, ready, window, losses, in_window, sums
 
 
+CLEAN = {"traces": 0, "backend_compiles": 0, "cache_requests": 0,
+         "cache_hits": 0}
+
+
+def judged(found, ready, losses, in_window, sums, readings=None,
+           step_compiled=CLEAN) -> dict:
+    """`run.judge`'s checks; the first steps' readings have tests of
+    their own (test_check.py)."""
+    return run.judge(found, ready, losses, in_window, sums, readings or {},
+                     step_compiled)[0]
+
+
 @pytest.mark.parametrize("chips", [1, 4])
 def test_lm_runner_through_the_loop(chips):
     found, ready, window, losses, in_window, sums = drive(
         tiny.LM_CONFIG, tiny.LM_TRAFFIC, chips)
-    checks = run.judge(found, ready, losses, in_window, sums)
+    checks = judged(found, ready, losses, in_window, sums)
     assert all(checks.values()), checks
     # bf16 compute against the float32 reference, same weights and batch
     assert ready["first_loss"] == pytest.approx(ready["reference_loss"],
@@ -51,7 +63,7 @@ def test_lm_runner_through_the_loop(chips):
 def test_vision_runner_through_the_loop():
     found, ready, window, losses, in_window, sums = drive(
         tiny.VISION_CONFIG, tiny.VISION_TRAFFIC, 1)
-    checks = run.judge(found, ready, losses, in_window, sums)
+    checks = judged(found, ready, losses, in_window, sums)
     assert all(checks.values()), checks
     assert window.steps % tiny.VISION_TRAFFIC["group"] == 0
     assert len(window.step_samples) == window.steps // 2 - 1
@@ -59,21 +71,53 @@ def test_vision_runner_through_the_loop():
 
 
 def test_judge_names_each_failure():
-    found = tiny.found(tiny.LM_CONFIG, tiny.LM_TRAFFIC, 2)
+    found = tiny.found(tiny.LM_CONFIG,
+                       {**tiny.LM_TRAFFIC,
+                        **tiny.limits(update_rel_err=0.1)}, 2)
     ready = {"first_loss": math.log(256), "reference_loss": math.log(256)}
-    clean = {"traces": 0, "backend_compiles": 0, "cache_requests": 0,
-             "cache_hits": 0}
-    assert all(run.judge(found, ready, [5.0, 4.0], clean, [1.0, 1.0])
-               .values())
-    bad = run.judge(found, {**ready, "reference_loss": 9.0},
-                    [5.0, float("nan")], {**clean, "backend_compiles": 1},
-                    [1.0, 1.5])
+    good = {"update_rel_err": 0.06, "grad_norm_gap": 0.5}   # second: null
+    checks, compared = run.judge(found, ready, [5.0, 4.0], CLEAN, [1.0, 1.0],
+                                 good, CLEAN)
+    assert all(checks.values()) and "update_matches_reference" in checks
+    assert compared == {
+        "first_loss_gap": {"value": 0.0, "limit": 0.05},
+        "replica_checksum_spread": {"value": 0.0, "limit": 0.0},
+        "update_rel_err": {"value": 0.06, "limit": 0.1}}
+    bad = judged(found, {**ready, "reference_loss": 9.0},
+                 [5.0, float("nan")], {**CLEAN, "backend_compiles": 1},
+                 [1.0, 1.5], {"update_rel_err": 0.11}, {**CLEAN, "traces": 1})
     assert bad == {"losses_finite": False, "init_loss_in_band": True,
                    "matches_reference": False,
                    "nothing_compiled_in_window": False,
-                   "replicas_agree": False}
-    assert not run.judge(found, {**ready, "first_loss": 30.0}, [1.0], clean,
-                         None)["init_loss_in_band"]
+                   "replicas_agree": False,
+                   "check_reran_the_timed_step": False,
+                   "update_matches_reference": False}
+    assert not judged(found, {**ready, "first_loss": 30.0}, [1.0], CLEAN,
+                      None)["init_loss_in_band"]
+    # a replica whose checksum is NaN agrees with nothing
+    for sums in ([1.0, float("nan")], [float("nan"), float("nan")]):
+        checks, compared = run.judge(found, ready, [5.0], CLEAN, sums, good,
+                                     CLEAN)
+        assert not checks["replicas_agree"]
+        assert compared["replica_checksum_spread"]["value"] == math.inf
+    # a traffic file that leaves a reading's limit out is refused
+    with pytest.raises(KeyError, match="loss_gap_max"):
+        run.judge(tiny.found(tiny.LM_CONFIG, {
+            k: v for k, v in found["traffic"].items()
+            if k != "loss_gap_max"}, 2), ready, [5.0], CLEAN, None, good,
+            CLEAN)
+
+
+def test_a_backend_without_memory_statistics_is_refused():
+    """`memory_peak_bytes` is a measurement: the CPU's runtime reports
+    none, and only a test may stand in for it."""
+    import jax
+    with pytest.raises(RuntimeError, match="memory statistics"):
+        run.device_memory_stats(jax.devices()[:1])
+    with pytest.raises(KeyError):
+        run.peak_bytes({})
+    assert run.plain({"a": [float("nan"), 1.5], "b": float("inf")}) == {
+        "a": ["nan", 1.5], "b": "inf"}
 
 
 def test_same_seed_same_inputs_and_large_seeds():
@@ -90,6 +134,7 @@ def test_readers_on_a_window():
                      ("dispatch", 1.0, 1.5)]
     w = loop.Window(state=None, steps=20, seconds=2.0,
                     step_samples=[0.1] * 9 + [0.2], losses=[], spans=spans)
+    assert w.last_metrics is None
     ctx = {"window": w, "chips": 2, "items_per_step": 8, "setup_seconds": 3.0,
            "config": copy.deepcopy(tiny.LM_CONFIG),
            "traffic": tiny.LM_TRAFFIC, "peaks": {"bf16_tflops": 1e-6},

@@ -234,30 +234,34 @@ def test_roofline_reader_and_the_attention_count():
     assert readers.roofline_pct(ctx, {**args, "include": "nothing"}) is None
 
 
-# ------------------------------ the entries that wait for run.py's lines
+# --------------------------- the per_layer entries that read the scopes
 
-def test_staged_entries_keep_to_benchmark_jsons_rules():
-    """`scopes_per_layer.json` holds per_layer entries in BENCHMARK.json's
-    form; each has its metric file, a reader in `readers/scopes.py`, and
-    regular expressions that compile."""
+def scope_entries():
+    """(entry of BENCHMARK.json, its metric file) for every per_layer
+    metric that a `readers/scopes.py` function reads."""
     with open(os.path.join(conftest.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
+    out = []
+    for e in bench["per_layer"]:
+        spec = run.load_json(run.HERE, "metrics", e["name"] + ".json")
+        if spec["reader"].startswith("scopes:"):
+            out.append((e, spec))
+    return bench, out
+
+
+def test_scope_entries_keep_to_benchmark_jsons_rules():
+    """The ten entries PR 25 staged are BENCHMARK.json's now: each has
+    its metric file, a reader in `readers/scopes.py`, regular expressions
+    that compile, and lists only cells that exist; the staging file is
+    gone and nothing reads it."""
+    bench, entries = scope_entries()
     cells = {w["name"] for w in bench["workloads"]}
-    taken = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
-    layers = {m["layer"] for m in bench["per_layer"]} | {"kernels"}
-    entries = run.load_json(run.HERE, "scopes_per_layer.json")["per_layer"]
-    assert len(entries) == len({e["name"] for e in entries}) == 10
-    for e in entries:
-        assert set(e) - {"workloads"} == {"name", "unit", "better", "source",
-                                          "layer", "moves"}
-        assert re.match(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$", e["name"])
-        assert re.match(r"^[A-Za-z0-9_/%.\-]{1,16}$", e["unit"])
-        assert e["name"] not in taken and e["layer"] in layers
+    assert len(entries) == len({e["name"] for e, _ in entries}) >= 10
+    assert not os.path.exists(os.path.join(run.HERE, "scopes_per_layer.json"))
+    for e, spec in entries:
         assert e["source"] == "device_trace"
         assert e["moves"] == "train_rate_per_chip"
         assert set(e.get("workloads", [])) <= cells
-        spec = run.load_json(run.HERE, "metrics", e["name"] + ".json")
-        assert spec["reader"].startswith("scopes:")
         assert callable(run.resolve(spec["reader"], "readers"))
         for key in ("include", "exclude"):
             if key in spec.get("args", {}):
@@ -267,14 +271,24 @@ def test_staged_entries_keep_to_benchmark_jsons_rules():
 
 
 METRIC_PATHS = {
+    # a model's own scope under `cpd.loss_grad` (any `cpd.*` but the
+    # step's five) is still forward or backward
     "step.forward_ms_per_step": (
-        ["cpd.loss_grad", "cpd.loss_grad/kernel.flash_gqa_fwd"],
+        ["cpd.loss_grad", "cpd.loss_grad/kernel.flash_gqa_fwd",
+         "cpd.loss_grad/cpd.experts", "cpd.loss_grad/cpd.reduced_rank",
+         "cpd.loss_grad/cpd.experts/kernel.grouped_gemm"],
         ["cpd.loss_grad@bwd", "cpd.loss_grad/cpd.reduce/aps.scale",
-         "cpd.optimizer", "unscoped"]),
+         "cpd.loss_grad/cpd.reduce", "cpd.loss_grad/cpd.experts@bwd",
+         "cpd.loss_grad/cpd.experts/cpd.reduce/wire.cast",
+         "cpd.optimizer", "cpd.metrics", "unscoped"]),
     "step.backward_ms_per_step": (
-        ["cpd.loss_grad@bwd", "cpd.loss_grad/kernel.flash_gqa_bwd_dq@bwd"],
+        ["cpd.loss_grad@bwd", "cpd.loss_grad/kernel.flash_gqa_bwd_dq@bwd",
+         "cpd.loss_grad/cpd.experts@bwd",
+         "cpd.loss_grad/cpd.experts/kernel.grouped_gemm@bwd"],
         ["cpd.loss_grad", "cpd.loss_grad/cpd.reduce/wire.cast",
-         "cpd.loss_grad/cpd.emulate_node/reduce.scan"]),
+         "cpd.loss_grad/cpd.experts",
+         "cpd.loss_grad/cpd.emulate_node/reduce.scan",
+         "cpd.loss_grad/cpd.reduce@bwd", "cpd.optimizer@bwd"]),
     "step.optimizer_ms_per_step": (
         ["cpd.optimizer"],
         ["cpd.optimizer/cpd.reduce/wire.collective", "cpd.metrics"]),
@@ -341,6 +355,53 @@ def test_recorded_steps_reduce_to_their_pinned_values(piece):
     old = trace_reduce.reduce(fx["tables"])
     assert r["busy_s"] == pytest.approx(old["busy_s_first_device"], rel=1e-6)
     assert r["unscoped_s"] < 0.10 * r["busy_s"]
+
+
+# what the two metric files held before a model's own scopes counted
+OLD_FORWARD = r"^cpd\.loss_grad(/kernel\.[^/@]+)*$"
+OLD_BACKWARD = r"^cpd\.loss_grad(/kernel\.[^/@]+)*@bwd$"
+
+
+@pytest.mark.parametrize("piece", ["lm_step", "dp4_step"])
+def test_readers_on_the_recorded_steps_through_ctx(piece):
+    """What `run.py` does with a trace: the table as `ctx["scopes"]`, the
+    readers by their metric files.  The values are the sums of the pinned
+    rows that `python -m benchmark.trace_scopes` printed for these traces,
+    and the widened forward and backward patterns pick the rows the old
+    ones picked."""
+    fx = recorded(piece)
+    by = fx["pinned"]["by_scope"]
+    ctx = {"scopes": trace_scopes.reduce_scopes(fx["tables"], fx["metadata"],
+                                                fx["async"]),
+           "window": types.SimpleNamespace(steps=1), "chips": 1}
+
+    def read(metric):
+        spec = run.load_json(run.HERE, "metrics", metric + ".json")
+        return run.resolve(spec["reader"], "readers")(ctx, spec.get("args", {}))
+
+    for metric, old in (("step.forward_ms_per_step", OLD_FORWARD),
+                        ("step.backward_ms_per_step", OLD_BACKWARD)):
+        rows = [row["s"] for path, row in by.items() if re.search(old, path)]
+        assert rows and read(metric) == pytest.approx(1e3 * sum(rows),
+                                                      rel=1e-9)
+    assert read("step.optimizer_ms_per_step") == pytest.approx(
+        1e3 * by["cpd.optimizer"]["s"], rel=1e-9)
+    assert read("step.unscoped_pct") == pytest.approx(
+        100 * fx["pinned"]["unscoped_s"] / fx["pinned"]["busy_s"], rel=1e-9)
+    pipeline = [row for path, row in by.items()
+                if re.search(r"(^|/)cpd\.(reduce|emulate_node)", path)
+                and "wire.collective" not in path]
+    assert read("quant.pipeline_ms_per_step") == pytest.approx(
+        1e3 * sum(r["s"] for r in pipeline), rel=1e-9)
+    assert read("quant.pipeline_gbytes_per_step") == pytest.approx(
+        sum(r["bytes"] for r in pipeline) / 1e9, rel=1e-9)
+    assert read("reduce.exposed_collective_ms_per_step") == pytest.approx(
+        1e3 * fx["pinned"]["collectives"]["exposed_s"], rel=1e-9)
+    assert read("reduce.wire_bytes_measured_per_step") == pytest.approx(
+        fx["pinned"]["collectives"]["received_bytes"], rel=1e-9)
+    assert trace_scopes.top_scopes(ctx["scopes"], 3) == [
+        [path, row["s"]] for path, row in sorted(
+            ctx["scopes"]["by_scope"].items(), key=lambda kv: -kv[1]["s"])[:3]]
 
 
 def test_recorded_lm_step_has_its_layers():

@@ -3,6 +3,28 @@ the same keys as `configs/*.json` and `traffic/*.json`, toy sizes."""
 
 from __future__ import annotations
 
+import json
+import os
+
+with open(os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                       "configs", "resnet50_imagenet.json")) as _f:
+    _RESNET50 = json.load(_f)
+
+READINGS = ("update_rel_err", "update_rel_err_worst_part",
+            "update_rel_err_head", "grad_norm_gap", "change_norm_gap",
+            "loss_gap")
+
+
+def limits(**held) -> dict:
+    """A traffic file's limits: every reading of `check.py` named, those
+    not in `held` stated as not compared."""
+    out = {}
+    for name in READINGS:
+        out[name + "_max"] = held.get(name)
+        out[name + "_why"] = "a test's"
+    return out
+
+
 REDUCE = {"use_aps": True, "grad_exp": 5, "grad_man": 2, "mode": "faithful",
           "donate": True}
 
@@ -15,11 +37,11 @@ LM_CONFIG = {
     "optimizer": {"name": "sgd", "momentum": 0.9, "weight_decay": 0.0,
                   "lr": 0.01},
     "ops_per_item": "dense_lm:train_flops_per_token",
-    "reference": "dense_lm:loss",
+    "reference": "dense_lm:loss", "head_part": "embed",
     "init_loss_band": [0.8, 1.5], "reference_loss_rtol": 0.05,
 }
 LM_TRAFFIC = {"batch_per_chip": 2, "seq_len": 128, "group": 1, "ring": 3,
-              "reduce": REDUCE}
+              "reduce": REDUCE, **limits()}
 
 VISION_CONFIG = {
     "runner": "train_vision", "item": "img", "model": "resnet50",
@@ -28,14 +50,16 @@ VISION_CONFIG = {
     "optimizer": {"name": "sgd", "momentum": 0.9, "weight_decay": 1e-4,
                   "lr_per_256_items": 0.1},
     "ops_per_item": "resnet:train_flops_per_image",
-    "reference": "resnet:loss",
+    "reference": "resnet:loss", "head_part": "fc",
+    # the seeded weights the committed configuration is checked on
+    "residual_last_bn_scale": _RESNET50["residual_last_bn_scale"],
     # bf16 through 50 layers whose batch statistics are over 4 images of
     # 1x1 to 8x8 pixels: a tenth of the loss at this size (float32 compute
     # agrees with the reference to 6e-4, see test_harness)
     "init_loss_band": [0.8, 1.5], "reference_loss_rtol": 0.15,
 }
 VISION_TRAFFIC = {"batch_per_chip": 4, "group": 2, "ring": 3,
-                  "reduce": REDUCE}
+                  "reduce": REDUCE, **limits()}
 
 
 def found(config: dict, traffic: dict, chips: int) -> dict:

@@ -24,10 +24,12 @@ False`, and every reader then reports nothing, not zero.
         --line RESULT.json [--base-line RESULT0.json]
 
 prints, for a trace kept with `run.py --trace 1 --keep-trace DIR` and the
-result line of that run, the scope table and every metric that
-`scopes_per_layer.json` lists for the cell.  (`run.py` does not hand the
-table to the readers yet; when it does, as `ctx["scopes"]` while the trace
-directory still exists, those entries move to `BENCHMARK.json`.)
+result line of that run, the whole scope table and every `per_layer`
+metric of `BENCHMARK.json` that a `readers/scopes.py` function reads for
+the cell.  (`run.py` prints the same metrics itself: it hands
+`load_scopes`'s table to the readers as `ctx["scopes"]` while the trace's
+directory still exists, and the ten largest scopes as
+`breakdown.device_scopes`.)
 """
 
 from __future__ import annotations
@@ -362,15 +364,13 @@ def main(argv=None) -> int:
            "peaks": run.load_json(run.HERE, "peaks.json")[
                line["device"]["kind"]]}
     metrics = {}
-    for entry in run.load_json(run.HERE, "scopes_per_layer.json")[
-            "per_layer"]:
-        if not run.applies(entry, args.workload):
+    for name, spec in found["metrics"]["per_layer"].items():
+        if not spec["reader"].startswith("scopes:"):
             continue
-        spec = run.load_json(run.HERE, "metrics", entry["name"] + ".json")
         value = run.resolve(spec["reader"], "readers")(
             ctx, spec.get("args", {}))
         if value is not None:
-            metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
+            metrics[name] = {"value": value, "unit": spec["unit"]}
     facts = {"scopes_found": scopes["scopes_found"],
              "wire_dtypes": scopes["collectives"]["dtypes"],
              "collective_calls_per_step":
