@@ -18,7 +18,7 @@ from jax.experimental import multihost_utils, pallas
 from jax.experimental.pallas import tpu as pallas_tpu
 
 __all__ = ["shard_map", "pallas", "pallas_tpu", "multihost_utils",
-           "flash_attention_import"]
+           "flash_attention_import", "megablox_gmm_import"]
 
 
 def flash_attention_import():
@@ -31,3 +31,11 @@ def flash_attention_import():
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         flash_attention)
     return flash_attention
+
+
+def megablox_gmm_import():
+    """jax's megablox grouped matrix product (Pallas TPU; `gmm` with its
+    `tgmm` backward under a `custom_vjp`), resolved lazily like the flash
+    kernel.  `ops/grouped.py` is its one caller."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    return gmm

@@ -13,6 +13,7 @@ from .tiny import TinyCNN, tiny_cnn
 from .transformer import TransformerLM, lm_param_specs, transformer_lm
 from .pipeline_lm import PipelinedLM, pipelined_lm, pp_param_specs
 from .moe import MoETransformerLM, moe_lm, moe_param_specs
+from .mla_moe import MLAMoELM, mla_moe_lm
 from .davidnet_graph import graph_davidnet
 from .generate import generate
 from .vit import ViT, vit
@@ -30,6 +31,7 @@ _REGISTRY = {
     "transformer_lm": transformer_lm,
     "pipelined_lm": pipelined_lm,
     "moe_lm": moe_lm,
+    "mla_moe_lm": mla_moe_lm,         # latent attention + routed experts
     "davidnet_graph": graph_davidnet,  # dict-graph definition (TorchGraph)
     "vit": vit,                       # RoPE-ViT encoder (models/vit.py)
 }
@@ -48,4 +50,5 @@ __all__ = ["ResNetCIFAR", "resnet18_cifar", "DavidNet", "davidnet",
            "TransformerLM", "transformer_lm", "lm_param_specs",
            "PipelinedLM", "pipelined_lm", "pp_param_specs",
            "MoETransformerLM", "moe_lm", "moe_param_specs",
+           "MLAMoELM", "mla_moe_lm",
            "graph_davidnet", "generate", "get_model"]

@@ -25,6 +25,9 @@ Stdlib-only, like the rest of `obs`.
 | `reduce.local` | `parallel/dist.py` | the faithful reduction over an axis of one rank: no codec, no collective, each leaf in its own shape (`reduce.scan` inside it).  XLA fuses all of it into the update's fusions, whose root is `cpd.optimizer`'s: in a device trace it owns nothing, `cpd.optimizer` includes it, and the pipeline's cost at one rank is read by the e5m2-APS twin minus its fp32 control |
 | `cpd.optimizer` | `train/step.py`, `train/lm.py` | `tx.update` and `apply_updates` (or the custom `update_fn`) |
 | `cpd.metrics` | `train/step.py`, `train/lm.py` | the step's own telemetry `psum`s, and the batch statistics' `pmean` |
+| `cpd.mla` | `models/mla_moe.py` | latent attention with its projections (the kernel's scope nests under it) |
+| `cpd.moe_router` / `cpd.moe_dispatch` / `cpd.moe_experts` / `cpd.moe_combine` | `models/mla_moe.py:RoutedExperts` | scores, selection and gates / sort, group sizes and the gather of rows / the three grouped products and the gating product / the sum back into tokens |
+| `cpd.moe_shared` / `cpd.dense_mlp` | `models/mla_moe.py` | the shared expert / a leading layer's dense gated MLP |
 | `kernel.<name>` | `ops/*.py`, around each `pl.pallas_call` | one Pallas kernel; the call's `name=` is the same `<name>` |
 
 Ownership, as the reader applies it: an operation belongs to the LAST
@@ -37,6 +40,8 @@ runs the reduction inside the backward pass); the reader unwraps it.
 from __future__ import annotations
 
 __all__ = ["LOSS_GRAD", "EMULATE_NODE", "REDUCE", "OPTIMIZER", "METRICS",
+           "MLA", "MOE_ROUTER", "MOE_DISPATCH", "MOE_EXPERTS", "MOE_COMBINE",
+           "MOE_SHARED", "DENSE_MLP",
            "APS_MAX_EXP", "APS_SCALE", "APS_UNSCALE", "WIRE_CAST",
            "WIRE_PACK", "WIRE_UNPACK", "WIRE_COLLECTIVE", "REDUCE_SCAN",
            "REDUCE_LOCAL",
@@ -47,6 +52,15 @@ EMULATE_NODE = "cpd.emulate_node"
 REDUCE = "cpd.reduce"
 OPTIMIZER = "cpd.optimizer"
 METRICS = "cpd.metrics"
+
+# model layers (models/mla_moe.py); all nest under LOSS_GRAD
+MLA = "cpd.mla"
+MOE_ROUTER = "cpd.moe_router"
+MOE_DISPATCH = "cpd.moe_dispatch"
+MOE_EXPERTS = "cpd.moe_experts"
+MOE_COMBINE = "cpd.moe_combine"
+MOE_SHARED = "cpd.moe_shared"
+DENSE_MLP = "cpd.dense_mlp"
 
 APS_MAX_EXP = "aps.max_exp"
 APS_SCALE = "aps.scale"

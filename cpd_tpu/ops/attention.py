@@ -174,9 +174,11 @@ def _chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                        block: int = _CHUNK) -> jnp.ndarray:
     """Flash-style attention in pure XLA: online softmax over K/V blocks.
 
-    Supports GQA natively — q (B, Tq, H, D) against k/v (B, Tk, H_kv, D)
-    with H_kv | H — via the same grouped contraction as
-    `grouped_query_attention`, so no expansion is materialized either.
+    Supports GQA natively — q (B, Tq, H, D) against k (B, Tk, H_kv, D)
+    and v (B, Tk, H_kv, Dv) with H_kv | H — via the same grouped
+    contraction as `grouped_query_attention`, so no expansion is
+    materialized either.  Dv may differ from D (latent attention); the
+    softmax scale is 1/sqrt(D).
     Peak score memory is (B, H, Tq, block) instead of (B, H, Tq, Tk) —
     in the BACKWARD pass too: the scan body is `jax.checkpoint`ed, so AD
     stores only the per-block (o, m, l) carries (O(Tq·D) each, smaller
@@ -202,7 +204,8 @@ def _chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vp = jnp.pad(v.astype(q.dtype), ((0, 0), (0, pad), (0, 0), (0, 0)))
     # (N, B, block, H_kv, D) — scan carries one block at a time
     kb = kp.reshape(b, n_blocks, block, hkv, d).transpose(1, 0, 2, 3, 4)
-    vb = vp.reshape(b, n_blocks, block, hkv, d).transpose(1, 0, 2, 3, 4)
+    vb = vp.reshape(b, n_blocks, block, hkv, v.shape[-1]).transpose(
+        1, 0, 2, 3, 4)
     qi = q_offset + jnp.arange(tq)[:, None]            # (tq, 1)
 
     def step(carry, xs):

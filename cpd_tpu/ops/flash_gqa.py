@@ -59,7 +59,8 @@ from .backend import interpret_mode
 
 __all__ = ["flash_gqa"]
 
-_BQ = 128   # query rows per program (pre-rep); MXU/sublane aligned
+_BQ = 128   # query rows per program and head of the group (pre-rep);
+            # MXU/sublane aligned; `_dims` lengthens it for rep < 8
 _BK = 128   # K/V block; == the lane width so (.., bk) masks are one tile
 
 
@@ -123,15 +124,24 @@ def _flash_gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
                          + jnp.log(jnp.maximum(l, 1e-30)))[..., 0]
 
 
+def _pad128(d: int) -> int:
+    return max(128, -(-d // 128) * 128)
+
+
 def _dims(q, k):
     b, tq, h, d = q.shape
     tk, hkv = k.shape[1], k.shape[2]
     rep = h // hkv
-    bq, bk = min(_BQ, max(8, -(-tq // 8) * 8)), _BK
+    # a program works on rep x bq rows: a group of few query heads takes a
+    # longer block, so that the grid's per-step cost is spread over about
+    # as many rows as a wide group's (v5e, 2 x 8,192 tokens, 16 heads of
+    # 192/128 at rep 1: 61.7 ms a call at 128 rows, 27.2 ms at 1,024;
+    # PERF.md section 6, PR 30).  rep >= 8 keeps _BQ
+    bq = min(_BQ * max(1, 8 // rep), max(8, -(-tq // 8) * 8))
+    bk = _BK
     tq_p = -(-tq // bq) * bq
     tk_p = -(-tk // bk) * bk
-    d_p = max(128, -(-d // 128) * 128)
-    return b, tq, h, d, tk, hkv, rep, bq, bk, tq_p, tk_p, d_p
+    return b, tq, h, d, tk, hkv, rep, bq, bk, tq_p, tk_p, _pad128(d)
 
 
 def _q_layout(x, hkv, rep, tq_p, d_p):
@@ -151,22 +161,24 @@ def _kv_layout(x, tk_p, d_p):
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
 def _flash_gqa_fwd_call(q, k, v, causal: bool, interpret: bool):
-    """Returns ((B, Tq, H, D) out, (B, H_kv, rep, Tq_p) lse)."""
+    """Returns ((B, Tq, H, Dv) out, (B, H_kv, rep, Tq_p) lse)."""
     (b, tq, h, d, tk, hkv, rep, bq, bk, tq_p, tk_p, d_p) = _dims(q, k)
+    dv = v.shape[-1]            # v's own width (latent attention: Dv < D)
+    dv_p = _pad128(dv)
     scale = 1.0 / float(d) ** 0.5
     # layouts: q -> (B, H_kv, rep, Tq, D); k/v -> (B, H_kv, Tk, D).
     # D zero-pad changes no logit (q·k unaffected) and only adds zero
     # output columns, sliced off below; pad keys are masked by position.
     qt = _q_layout(q, hkv, rep, tq_p, d_p)
     kt = _kv_layout(k, tk_p, d_p)
-    vt = _kv_layout(v, tk_p, d_p)
+    vt = _kv_layout(v, tk_p, dv_p)
 
     n_q, n_k = tq_p // bq, tk_p // bk
     call = pl.pallas_call(
         functools.partial(_flash_gqa_kernel, causal=causal, scale=scale,
                           tq=tq, tk=tk, bq=bq, bk=bk, n_k=n_k),
         out_shape=(
-            jax.ShapeDtypeStruct((b, hkv, rep, tq_p, d_p), q.dtype),
+            jax.ShapeDtypeStruct((b, hkv, rep, tq_p, dv_p), q.dtype),
             jax.ShapeDtypeStruct((b, hkv, rep, tq_p), jnp.float32),
         ),
         grid=(b, hkv, n_q, n_k),
@@ -177,12 +189,12 @@ def _flash_gqa_fwd_call(q, k, v, causal: bool, interpret: bool):
             pl.BlockSpec((1, 1, bk, d_p),
                          lambda bi, g, i, j: (bi, g, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bk, d_p),
+            pl.BlockSpec((1, 1, bk, dv_p),
                          lambda bi, g, i, j: (bi, g, j, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=(
-            pl.BlockSpec((1, 1, rep, bq, d_p),
+            pl.BlockSpec((1, 1, rep, bq, dv_p),
                          lambda bi, g, i, j: (bi, g, 0, i, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, rep, bq),
@@ -190,7 +202,7 @@ def _flash_gqa_fwd_call(q, k, v, causal: bool, interpret: bool):
                          memory_space=pltpu.VMEM),
         ),
         scratch_shapes=[
-            pltpu.VMEM((rep, bq, d_p), jnp.float32),
+            pltpu.VMEM((rep, bq, dv_p), jnp.float32),
             pltpu.VMEM((rep, bq, 128), jnp.float32),
             pltpu.VMEM((rep, bq, 128), jnp.float32),
         ],
@@ -199,9 +211,9 @@ def _flash_gqa_fwd_call(q, k, v, causal: bool, interpret: bool):
     )
     with jax.named_scope(scopes.KERNEL_FLASH_GQA_FWD):
         out, lse = call(qt, kt, vt)
-    # (B, H_kv, rep, Tq_p, D_p) -> (B, Tq, H, D)
-    out = out[:, :, :, :tq, :d].transpose(0, 3, 1, 2, 4).reshape(
-        b, tq, h, d)
+    # (B, H_kv, rep, Tq_p, Dv_p) -> (B, Tq, H, Dv)
+    out = out[:, :, :, :tq, :dv].transpose(0, 3, 1, 2, 4).reshape(
+        b, tq, h, dv)
     return out, lse
 
 
@@ -384,11 +396,14 @@ def flash_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               causal: bool = True, bwd: str = "chunked") -> jnp.ndarray:
     """Flash attention with GQA-native unexpanded K/V, on the MXU.
 
-    q: (B, Tq, H, D); k, v: (B, Tk, H_kv, D) with H_kv | H (kv head g
-    serves q heads [g·rep, (g+1)·rep), the `grouped_query_attention`
-    convention).  rep == 1 is plain MHA.  Tq/Tk/D need no alignment —
-    padding is handled internally (masked, never averaged in).  Returns
-    (B, Tq, H, D) in q.dtype; fp32 softmax.
+    q: (B, Tq, H, D); k: (B, Tk, H_kv, D); v: (B, Tk, H_kv, Dv) with
+    H_kv | H (kv head g serves q heads [g·rep, (g+1)·rep), the
+    `grouped_query_attention` convention).  rep == 1 is plain MHA.  Dv
+    may differ from D (latent attention: 192-wide q/k, 128-wide v); the
+    softmax scale is 1/sqrt(D) and each width is padded on its own.
+    Tq/Tk/D/Dv need no alignment — padding is handled internally
+    (masked, never averaged in).  Returns (B, Tq, H, Dv) in q.dtype;
+    fp32 softmax.
 
     Matches `_chunked_attention` / `grouped_query_attention` to fp32
     round-off (different contraction order — not bitwise).  Compiled by
@@ -406,23 +421,28 @@ def flash_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     attention to fp32 round-off and are tested against each other and
     the XLA AD oracle; pallas_check compiles both on the chip.
     """
-    _validate_call(q, k, bwd)
+    _validate_call(q, k, v, bwd)
     interpret = interpret_mode()
     out, _ = _flash_gqa_fwd_call(q, k, v, causal, interpret)
     return out
 
 
-def _validate_call(q, k, bwd):
+def _validate_call(q, k, v, bwd):
     # shared by the primal AND _fwd: custom_vjp bypasses the primal
     # under jax.grad, so validation only there would silently accept a
     # bad bwd string / head ratio in exactly the differentiated case
     _gqa_rep(q, k)  # H_kv | H (shared contract, attention.py)
     if bwd not in ("chunked", "pallas"):
         raise ValueError(f"unknown bwd {bwd!r}; 'chunked' or 'pallas'")
+    if bwd == "pallas" and v.shape[-1] != q.shape[-1]:
+        raise ValueError(
+            f"bwd='pallas' needs v as wide as q/k (got {v.shape[-1]} and "
+            f"{q.shape[-1]}): the backward kernels share one block "
+            f"width; use bwd='chunked'")
 
 
 def _fwd(q, k, v, causal, bwd):
-    _validate_call(q, k, bwd)
+    _validate_call(q, k, v, bwd)
     interpret = interpret_mode()
     out, lse = _flash_gqa_fwd_call(q, k, v, causal, interpret)
     res = (q, k, v, out, lse) if bwd == "pallas" else (q, k, v)

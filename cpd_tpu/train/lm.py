@@ -28,6 +28,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import optax
+from flax.traverse_util import flatten_dict
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -65,6 +66,11 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                        block_scale: bool = False,
                        block_size: int = 128):
     """Build jitted ``(state, tokens, targets) -> (state, metrics)``.
+
+    ``metrics`` holds ``loss`` and ``accuracy`` and, for a model that
+    declares ``step_counters`` ({name: "sum" | "max"}; its layers ``sow``
+    them into the ``"counters"`` collection), each counter merged over
+    layers, micro-batches and the dp/sp ranks (tp ranks repeat them).
 
     tokens/targets: (global_batch * emulate_node, T_global) int32, sharded
     (dp, sp).  Loss is next-token CE averaged over all target positions;
@@ -118,6 +124,23 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
         reject_norm_based(tx, "tp-sharded LM step")
 
     has_dropout = getattr(model, "dropout_rate", 0.0) > 0.0
+    # counters a model reports: {name: "sum" | "max"}, sown into the
+    # "counters" collection by its layers (models/mla_moe.py).  A model
+    # that declares none is applied as before, equation for equation
+    counters = dict(getattr(model, "step_counters", None) or {})
+    merge = {"sum": (jnp.sum, lax.psum), "max": (jnp.max, lax.pmax)}
+    for name, how in counters.items():
+        if how not in merge:
+            raise ValueError(f"step counter {name!r}: unknown merge {how!r}")
+
+    def model_counts(sown) -> dict:
+        """One value a counter: what the layers sowed under its name
+        (`{layer: {..: {name: (value,)}}}`), merged over the layers."""
+        by_name = {name: [] for name in counters}
+        for path, values in flatten_dict(sown).items():
+            by_name[path[-1]].extend(values)
+        return {name: merge[counters[name]][0](jnp.stack(values))
+                for name, values in by_name.items()}
 
     def step_fn(state: TrainState, tokens, targets):
         def loss_of(params, toks, tgts, micro_idx):
@@ -136,8 +159,15 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 key = jax.random.fold_in(
                     key, lax.axis_index(axis_sp).astype(jnp.int32))
                 rngs = {"dropout": key}
-            logits = model.apply({"params": params}, toks, train=True,
-                                 rngs=rngs)
+            counts = {}
+            if counters:
+                logits, sown = model.apply(
+                    {"params": params}, toks, train=True, rngs=rngs,
+                    mutable=["counters"])
+                counts = model_counts(sown["counters"])
+            else:
+                logits = model.apply({"params": params}, toks, train=True,
+                                     rngs=rngs)
             ce = optax.softmax_cross_entropy_with_integer_labels(
                 logits, tgts)                       # (B_local, T_local)
             if label_smoothing:
@@ -162,7 +192,7 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             # divide-so-the-sum-is-the-mean, per micro-batch)
             loss = local_sum / global_n / emulate_node
             hits = jnp.sum(jnp.argmax(logits, -1) == tgts)
-            return loss, (local_sum, local_n, hits)
+            return loss, (local_sum, local_n, hits, counts)
 
         n = emulate_node
         mb = tokens.shape[0] // n
@@ -217,19 +247,21 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 return sp_tp_reduce(g, specs_flat[i])
 
             extras = emulate_fn = emu_key = None
-            micro_sums, micro_ns, micro_hits = [], [], []
+            micro_sums, micro_ns, micro_hits, micro_counts = [], [], [], []
             if n > 1:
                 toks_u = tokens.reshape(n, mb, tokens.shape[1])
                 tgts_u = targets.reshape(n, mb, targets.shape[1])
                 prev = []
                 for mi in range(n - 1):
                     with jax.named_scope(scopes.LOSS_GRAD):
-                        (_, (s_mi, n_mi, h_mi)), g_mi = jax.value_and_grad(
-                            loss_of, has_aux=True)(state.params, toks_u[mi],
-                                                   tgts_u[mi], jnp.int32(mi))
+                        (_, (s_mi, n_mi, h_mi, c_mi)), g_mi = (
+                            jax.value_and_grad(loss_of, has_aux=True)(
+                                state.params, toks_u[mi], tgts_u[mi],
+                                jnp.int32(mi)))
                     micro_sums.append(s_mi)
                     micro_ns.append(n_mi)
                     micro_hits.append(h_mi)
+                    micro_counts.append(c_mi)
                     prev.append(jax.tree_util.tree_leaves(g_mi))
                 # sp/tp-reduce + sat-scale the prior micros here (the
                 # taps apply leaf_pre/aux[0] to the LAST micro's
@@ -259,7 +291,7 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 loss, aux = loss_of(p, tk_last, tg_last, last_idx)
                 return loss, aux
 
-            ((_, (l_sum, l_n, l_hits)), reduced,
+            ((_, (l_sum, l_n, l_hits, l_counts)), reduced,
              vreport) = overlapped_grads(
                 loss_closure, state.params, axis_name=axis_dp, plan=plan,
                 reduce_kw=dict(use_aps=use_aps, grad_exp=grad_exp,
@@ -275,6 +307,9 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             sums = jnp.stack(micro_sums + [l_sum])
             ns = jnp.stack(micro_ns + [l_n])
             hits = jnp.stack(micro_hits + [l_hits])
+            counts = {name: jnp.stack([c[name] for c in
+                                       micro_counts + [l_counts]])
+                      for name in counters}
         else:
             toks = tokens.reshape(n, mb, tokens.shape[1])
             tgts = targets.reshape(n, mb, targets.shape[1])
@@ -286,7 +321,7 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 return micro_idx + 1, (grads, *aux)
 
             with jax.named_scope(scopes.LOSS_GRAD):
-                _, (stacked, sums, ns, hits) = lax.scan(
+                _, (stacked, sums, ns, hits, counts) = lax.scan(
                     micro, jnp.zeros([], jnp.int32), (toks, tgts))
 
             stacked = jax.tree.map(sp_tp_reduce, stacked, specs)
@@ -327,6 +362,10 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 "accuracy": lax.psum(hits.sum().astype(jnp.float32),
                                      (axis_dp, axis_sp)) / total_n,
             }
+            for name, how in counters.items():
+                over_micros, over_ranks = merge[how]
+                metrics[name] = over_ranks(over_micros(counts[name]),
+                                           (axis_dp, axis_sp))
         if vreport is not None:
             f32 = jnp.float32
             if verify_reduce:

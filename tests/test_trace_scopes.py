@@ -174,6 +174,41 @@ def test_overlapped_reduction_is_the_reductions_not_the_backwards():
                for p in paths)
 
 
+MODEL_LAYERS = {scopes.MLA, scopes.MOE_ROUTER, scopes.MOE_DISPATCH,
+                scopes.MOE_EXPERTS, scopes.MOE_COMBINE, scopes.MOE_SHARED,
+                scopes.DENSE_MLP}
+
+
+def test_model_layers_are_named_under_the_loss_grad_scope():
+    """`models/mla_moe.py` names its layers; through the LM step each is
+    nested under `cpd.loss_grad` (so the step's forward and backward
+    metrics count them), the flash kernel's scope under `cpd.mla`."""
+    from cpd_tpu.models import mla_moe_lm
+    from cpd_tpu.train import make_lm_train_step, make_optimizer
+    from cpd_tpu.train.state import TrainState
+    mesh = make_mesh(dp=1, devices=jax.devices()[:1])
+    kw = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=4, d_ff=48,
+              kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4, v_head_dim=6,
+              n_experts=8, experts_held=2, top_k=3, moe_d_ff=24,
+              n_shared_experts=2, remat=True)
+    model = mla_moe_lm(**kw, attn_impl="flash")
+    tx = make_optimizer("sgd", lambda step: 0.01, momentum=0.9)
+    toks = jnp.zeros((2, 16), jnp.int32)
+    params = mla_moe_lm(**kw).init(jax.random.PRNGKey(0), toks)["params"]
+    state = TrainState(step=jnp.zeros([], jnp.int32), params=params,
+                       batch_stats={}, opt_state=tx.init(params))
+    step = jax.jit(make_lm_train_step(model, tx, mesh, use_aps=True,
+                                      grad_exp=5, grad_man=2, donate=False))
+    paths = scope_paths(step.lower(state, toks, toks).compile())
+    found = {c for p in paths for c in p}
+    SEEN.update(found)
+    assert found <= KNOWN and MODEL_LAYERS <= found
+    for layer in MODEL_LAYERS:
+        assert (scopes.LOSS_GRAD, layer) in {p[:2] for p in paths}, layer
+    assert (scopes.LOSS_GRAD, scopes.MLA,
+            scopes.KERNEL_FLASH_GQA_FWD) in paths
+
+
 def test_every_scope_is_used_and_lives_in_one_place():
     """Runs after the parametrised cases (same file, same worker): every
     non-kernel constant showed up in some compiled program; every kernel
