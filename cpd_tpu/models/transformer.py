@@ -93,9 +93,6 @@ class Block(nn.Module):
     causal: bool = True         # False = bidirectional attention (ViT
                                 # encoder use, models/vit.py); decode and
                                 # sp paths require causal
-    flash_bwd: str = "chunked"  # GQA flash backward: "chunked" (XLA
-                                # recompute, default) | "pallas" (flash-
-                                # backward kernels; ops/flash_gqa.py)
     attn_impl: str = "xla"      # "flash" = Pallas TPU flash-attention
                                 # kernel for the non-decode single-
                                 # sequence path (O(T) memory; MHA only);
@@ -200,12 +197,6 @@ class Block(nn.Module):
         if self.attn_impl not in ("xla", "flash", "chunked"):
             raise ValueError(f"unknown attn_impl {self.attn_impl!r}; "
                              "expected 'xla', 'flash' or 'chunked'")
-        if self.flash_bwd not in ("chunked", "pallas"):
-            # validated here, not only inside flash_gqa: a typo'd value
-            # on a non-flash path would otherwise ride along silently
-            # until the user flips attn_impl mid-experiment
-            raise ValueError(f"unknown flash_bwd {self.flash_bwd!r}; "
-                             "expected 'chunked' or 'pallas'")
         if (self.attn_impl == "flash" and self.sp_axis
                 and self.sp_mode == "ring"):
             raise ValueError("attn_impl='flash' does not compose with "
@@ -222,8 +213,7 @@ class Block(nn.Module):
             # (ops/attention.py)
             if self.sp_mode == "ulysses":
                 attn = ulysses_attention(q, k, v, self.sp_axis,
-                                         causal=True, impl=self.attn_impl,
-                                         flash_bwd=self.flash_bwd)
+                                         causal=True, impl=self.attn_impl)
             else:
                 # ring accepts impl='chunked' (inner sub-block fold, for
                 # T_local >> block); 'flash' was rejected above
@@ -233,8 +223,7 @@ class Block(nn.Module):
                                             else "xla"))
         else:
             attn = grouped_query_attention(q, k, v, causal=self.causal,
-                                           impl=self.attn_impl,
-                                           flash_bwd=self.flash_bwd)
+                                           impl=self.attn_impl)
         attn = attn.reshape(*attn.shape[:-2], n_local * self.head_dim)
         proj = nn.Dense(self.d_model, use_bias=False, dtype=self.dtype,
                         name="wo")(attn)
@@ -301,7 +290,6 @@ class TransformerLM(nn.Module):
     ffn_man: int = 23       # (8, 23) — see Block.ffn_exp
     ffn_mode: str = "faithful"
     attn_impl: str = "xla"  # "flash" = Pallas TPU kernel (see Block)
-    flash_bwd: str = "chunked"  # GQA flash backward path (see Block)
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
@@ -350,8 +338,7 @@ class TransformerLM(nn.Module):
                         dropout_rate=self.dropout_rate,
                         deterministic=not train, ffn_exp=self.ffn_exp,
                         ffn_man=self.ffn_man, ffn_mode=self.ffn_mode,
-                        attn_impl=self.attn_impl,
-                        flash_bwd=self.flash_bwd)
+                        attn_impl=self.attn_impl)
         if self.scan_layers:
             if self.decode:
                 raise ValueError("scan_layers does not compose with "

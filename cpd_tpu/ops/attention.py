@@ -344,8 +344,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 def grouped_query_attention(q: jnp.ndarray, k: jnp.ndarray,
                             v: jnp.ndarray, causal: bool = True,
-                            q_offset=0, impl: str = "xla",
-                            flash_bwd: str = "chunked") -> jnp.ndarray:
+                            q_offset=0, impl: str = "xla") -> jnp.ndarray:
     """GQA softmax attention without materializing the K/V expansion.
 
     q: (B, Tq, H, D) with H = rep * H_kv; k, v: (B, Tk, H_kv, D).
@@ -360,8 +359,9 @@ def grouped_query_attention(q: jnp.ndarray, k: jnp.ndarray,
     tools/pallas_check.py on a v5e chip (2026-09-26, libtpu 0.0.34): both
     forwards compile and agree with the XLA reference to 2e-2 (fp32
     matmuls run as bf16 passes there), flash_gqa also at short Tq
-    (q block 8 and 40); its Pallas backward compiles since its dk/dv
-    contraction was merged to one dimension.  No speed was measured.
+    (q block 8 and 40); its gradient is the pair of Pallas flash-backward
+    kernels, checked there at the benchmark's own shapes.  Timings of
+    both passes beside the chunked XLA path: PERF.md section 6, PR 31.
     impl="chunked" runs the grouped contraction through the
     online-softmax K/V-block scan (`_chunked_attention`) — GQA-native,
     O(Tq·block) score memory, any backend.
@@ -375,7 +375,7 @@ def grouped_query_attention(q: jnp.ndarray, k: jnp.ndarray,
             raise ValueError("impl='flash' does not support q offsets; "
                              "use the default impl inside ring steps")
         from .flash_gqa import flash_gqa
-        return flash_gqa(q, k, v, causal, flash_bwd)
+        return flash_gqa(q, k, v, causal)
     if impl == "chunked":
         return _chunked_attention(q, k, v, causal, q_offset, 0)
     if h == hkv:
@@ -402,8 +402,7 @@ def grouped_query_attention(q: jnp.ndarray, k: jnp.ndarray,
 
 def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       axis_name: str, causal: bool = True,
-                      impl: str = "xla",
-                      flash_bwd: str = "chunked") -> jnp.ndarray:
+                      impl: str = "xla") -> jnp.ndarray:
     """All-to-all sequence-parallel attention; call inside shard_map with
     the sequence dim sharded over `axis_name`.
 
@@ -453,6 +452,5 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                               tiled=True)
 
     qh, kh, vh = seq_to_heads(q), seq_to_heads(k), seq_to_heads(v)
-    out = grouped_query_attention(qh, kh, vh, causal=causal, impl=impl,
-                                  flash_bwd=flash_bwd)
+    out = grouped_query_attention(qh, kh, vh, causal=causal, impl=impl)
     return heads_to_seq(out)

@@ -31,17 +31,15 @@ Design notes:
   * fp32 logits/softmax; p is cast to the V dtype for the PV matmul —
     the same precision recipe as `_fold_segment` (attention.py).
 
-Backward: `jax.custom_vjp` with two selectable paths (``bwd=``).  The
-default "chunked" recomputes through `_chunked_attention`'s
-checkpointed scan (same recurrence, O(Tq·block) score memory in
-reverse) and takes ITS gradient — pure XLA.  "pallas" runs the
-flash-backward recipe on the MXU (compiles on v5e and agrees with the
-XLA gradient to 5e-2, tools/pallas_check.py; neither is timed yet): the forward also
-emits the per-row LSE, and two kernels — dq (K innermost) and fused
-dk/dv (Q innermost, the GQA group-sums folded into (rep, bq)
-contractions) — re-exponentiate p = exp(s − lse) per block.  Both are
-valid gradients of softmax attention to fp32 round-off, tested against
-each other and the XLA AD oracle.
+Backward: `jax.custom_vjp`, the flash-backward recipe on the MXU.  The
+forward also emits the per-row LSE, and two kernels — dq (K innermost)
+and fused dk/dv (Q innermost, the GQA group-sums folded into (rep, bq)
+contractions) — re-exponentiate p = exp(s − lse) per block, at v's own
+width and with block lengths of their own (`_bwd_blocks`); causal steps
+above the diagonal neither compute nor copy.  What it costs on the
+chip beside the XLA gradient of `_chunked_attention` (which
+`attn_impl="chunked"` still runs, and the tests hold this one to):
+PERF.md section 6, PR 31.
 """
 
 from __future__ import annotations
@@ -219,14 +217,14 @@ def _flash_gqa_fwd_call(q, k, v, causal: bool, interpret: bool):
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j, *,
               causal, scale, tk, bq, bk):
-    """Shared flash-backward block recompute: (p, ds) for q block i vs
-    k block j — the numerically delicate mask/re-exponentiation recipe,
-    ONE copy consumed by both backward kernels (only their final
-    contractions differ)."""
+    """Shared flash-backward block recompute: (p, ds / scale) for q block
+    i vs k block j — the numerically delicate mask/re-exponentiation
+    recipe, ONE copy consumed by both backward kernels (only their final
+    contractions differ; each scales its accumulator once, at the end)."""
     q = q_ref[0, 0]                                   # (rep, bq, D)
     k = k_ref[0, 0]                                   # (bk, D)
-    v = v_ref[0, 0]
-    do = do_ref[0, 0]                                 # (rep, bq, D)
+    v = v_ref[0, 0]                                   # (bk, Dv)
+    do = do_ref[0, 0]                                 # (rep, bq, Dv)
     lse = lse_ref[0, 0][..., None]                    # (rep, bq, 1)
     delta = delta_ref[0, 0][..., None]                # (rep, bq, 1)
     s = lax.dot_general(
@@ -241,8 +239,7 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, i, j, *,
     dp = lax.dot_general(
         do, v, (((2,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)           # (rep, bq, bk)
-    ds = p * (dp - delta) * scale
-    return p, ds
+    return p, p * (dp - delta)
 
 
 def _flash_gqa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -270,7 +267,7 @@ def _flash_gqa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(j == n_k - 1)
     def _():
-        dq_ref[0, 0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0, 0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_gqa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -304,7 +301,7 @@ def _flash_gqa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_acc[...] += lax.dot_general(
             p.reshape(rows, bk).astype(do.dtype), do,
             (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (bk, D)
+            preferred_element_type=jnp.float32)           # (bk, Dv)
         dk_acc[...] += lax.dot_general(
             ds.reshape(rows, bk).astype(q.dtype), q,
             (((0,), (0,)), ((), ())),
@@ -312,88 +309,184 @@ def _flash_gqa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     @pl.when(i == n_q - 1)
     def _():
-        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[0, 0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+# The backward kernels' block lengths (`_bwd_blocks`), timed on a v5e for
+# one layer's call, both kernels and the layout passes around them
+# (`tools/bench_flash_gqa.py`; PERF.md section 6, PR 31).  Moonlight's (2 x
+# 8,192 tokens, 16 heads of 192/128 at rep 1; the chunked XLA gradient:
+# 168.1 ms): (bq, bk) = (1,024, 1,024) 23.8 ms, (1,024, 512) 24.5, (512,
+# 512) 25.4, (2,048, 512) 25.6, (512, 256) 31.3, (256, 256) 39.7, (1,024,
+# 128) 43.4.  StarCoder2's (2 x 4,096, 24 heads of 128 at rep 12; chunked
+# 60.7): (256, 512) 5.54, (128, 512) 5.76, (256, 256) 6.01, (128, 1,024)
+# 6.21, (128, 256) 6.53, (128, 128) 10.48.  An accumulator is read and
+# written once a step, so long steps pay; past a million scores a block
+# little or nothing is won.  The rule takes (1,024, 1,024), the best, and
+# (128, 512), 4% behind a pair that wants 1.5 million scores.
+_BWD_SCORES = 2 ** 20          # most scores, rep x bq x bk, a step holds
+# Mosaic's own limit of 16 MiB a kernel holds the benchmark's two bf16
+# calls and refuses a million float32 scores (8 heads over 2 of 256, a
+# group of 32 at 192/128; `tests/test_reduce_bytes_v5e.py` compiles them).
+# 48 MiB is a number for the v5e, whose core has 128 MiB of VMEM: a part
+# with less wants a smaller one, and `_BWD_SCORES` with it
+_BWD_VMEM_LIMIT = 48 * 2 ** 20
+
+
+def _fit(block: int, t: int) -> int:
+    """`block` halved while half of it still holds all t rows, never
+    under the 128 lanes of a score tile."""
+    while block > 128 and block // 2 >= t:
+        block //= 2
+    return block
+
+
+def _bwd_blocks(rep, tq, tk):
+    """(bq, bk) of both backward kernels: the forward's rows a program
+    (`_dims`) and as many keys, 1,024 at most, as keep the block of
+    scores within `_BWD_SCORES`."""
+    bq = _fit(_BQ * max(1, 8 // rep), tq)
+    if tq < 128:      # one block of all the rows, as the forward's
+        bq = -(-tq // 8) * 8
+    bk = _fit(1024, tk)
+    while bk > 128 and rep * bq * bk > _BWD_SCORES:
+        bk //= 2
+    return bq, bk
+
+
+# Pallas copies whatever block a spec names, for a step whose compute
+# `pl.when` skips too.  A causal step above the diagonal names the block
+# its neighbour on the diagonal needs, which is then in VMEM already and
+# is not copied again (23.8 against 25.4 ms at the Moonlight shape, 5.76
+# against 6.17 at StarCoder2's)
+
+def _dq_k_block(i, j, bq, bk):
+    """The k block that step (q block i, k block j) of the causal dq
+    kernel names: j up to the last block q block i's rows reach."""
+    return jnp.minimum(j, (i * bq + bq - 1) // bk)
+
+
+def _dkv_q_block(j, i, bq, bk, n_q):
+    """The q block that step (k block j, q block i) of the causal dk/dv
+    kernel names: i from the first block whose rows reach k block j (the
+    last q block where the keys go on past every query)."""
+    return jnp.maximum(i, jnp.minimum(j * bk // bq, n_q - 1))
+
+
+def _row_layout(x, hkv, rep, tq_p):
+    """(B, Tq, H) -> padded (B, H_kv, rep, Tq_p)."""
+    b, tq, _ = x.shape
+    return jnp.pad(x.reshape(b, tq, hkv, rep).transpose(0, 2, 3, 1),
+                   ((0, 0), (0, 0), (0, 0), (0, tq_p - tq)))
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7))
 def _flash_gqa_bwd_call(q, k, v, out, lse, do, causal: bool,
                         interpret: bool):
-    """Pallas flash backward: (dq, dk, dv) in the input shapes/dtypes."""
-    (b, tq, h, d, tk, hkv, rep, bq, bk, tq_p, tk_p, d_p) = _dims(q, k)
+    """Pallas flash backward: (dq, dk, dv) in the input shapes/dtypes.
+    q/k/dq/dk are laid out and padded at D's width, v/do/dv at Dv's."""
+    b, tq, h, d = q.shape
+    tk, hkv, dv = k.shape[1], k.shape[2], v.shape[-1]
+    rep = h // hkv
+    d_p, dv_p = _pad128(d), _pad128(dv)
     scale = 1.0 / float(d) ** 0.5
+    bq, bk = _bwd_blocks(rep, tq, tk)
+    tq_p, tk_p = -(-tq // bq) * bq, -(-tk // bk) * bk
+    n_q, n_k = tq_p // bq, tk_p // bk
     qt = _q_layout(q, hkv, rep, tq_p, d_p)
     kt = _kv_layout(k, tk_p, d_p)
-    vt = _kv_layout(v, tk_p, d_p)
-    dot = _q_layout(do, hkv, rep, tq_p, d_p)
-    ot = _q_layout(out, hkv, rep, tq_p, d_p)
-    # delta_i = Σ_d dO_id · O_id (the flash-backward row constant); pad
-    # rows are all-zero -> delta 0
-    delta = (dot.astype(jnp.float32) * ot.astype(jnp.float32)).sum(-1)
+    vt = _kv_layout(v, tk_p, dv_p)
+    dot = _q_layout(do, hkv, rep, tq_p, dv_p)
+    # delta_i = Σ_d dO_id · O_id (the flash-backward row constant).  Pad
+    # rows (the forward's block length is not this one's: lse is cut to
+    # Tq and padded anew) have q = dO = 0 and lse = delta = 0: p = 1
+    # there, ds = 0, and they add nothing to dk or dv
+    delta = _row_layout(
+        (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1),
+        hkv, rep, tq_p)
+    lse = jnp.pad(lse[..., :tq], ((0, 0),) * 3 + ((0, tq_p - tq),))
 
-    n_q, n_k = tq_p // bq, tk_p // bk
-    qspec = pl.BlockSpec((1, 1, rep, bq, d_p),
-                         lambda bi, g, i, j: (bi, g, 0, i, 0),
-                         memory_space=pltpu.VMEM)
-    rowspec = pl.BlockSpec((1, 1, rep, bq),
-                           lambda bi, g, i, j: (bi, g, 0, i),
+    def specs(qi, ki):
+        """Block specs on a grid (b, kv head, x, y); `qi`/`ki` give the q
+        and the k block's index from (x, y)."""
+        def wide(width):
+            return pl.BlockSpec(
+                (1, 1, rep, bq, width),
+                lambda bi, g, x, y: (bi, g, 0, qi(x, y), 0),
+                memory_space=pltpu.VMEM)
+
+        def kv(width):
+            return pl.BlockSpec(
+                (1, 1, bk, width),
+                lambda bi, g, x, y: (bi, g, ki(x, y), 0),
+                memory_space=pltpu.VMEM)
+
+        row = pl.BlockSpec((1, 1, rep, bq),
+                           lambda bi, g, x, y: (bi, g, 0, qi(x, y)),
                            memory_space=pltpu.VMEM)
-    kvspec = pl.BlockSpec((1, 1, bk, d_p),
-                          lambda bi, g, i, j: (bi, g, j, 0),
-                          memory_space=pltpu.VMEM)
+        return wide(d_p), wide(dv_p), kv(d_p), kv(dv_p), row
+
+    params = pltpu.CompilerParams(vmem_limit_bytes=_BWD_VMEM_LIMIT)
+    qd, qdv, kd, kdv, row = specs(
+        lambda i, j: i,
+        (lambda i, j: _dq_k_block(i, j, bq, bk)) if causal
+        else (lambda i, j: j))
     call = pl.pallas_call(
         functools.partial(_flash_gqa_bwd_dq_kernel, causal=causal,
                           scale=scale, tk=tk, bq=bq, bk=bk, n_k=n_k),
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, tq_p, d_p), q.dtype),
         grid=(b, hkv, n_q, n_k),
-        in_specs=[qspec, kvspec, kvspec, qspec, rowspec, rowspec],
-        out_specs=qspec,
+        in_specs=[qd, kd, kdv, qdv, row, row],
+        out_specs=pl.BlockSpec((1, 1, rep, bq, d_p),
+                               lambda bi, g, i, j: (bi, g, 0, i, 0),
+                               memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((rep, bq, d_p), jnp.float32)],
-        interpret=interpret,
+        compiler_params=params, interpret=interpret,
         name=scopes.kernel_name(scopes.KERNEL_FLASH_GQA_BWD_DQ),
     )
     with jax.named_scope(scopes.KERNEL_FLASH_GQA_BWD_DQ):
         dq = call(qt, kt, vt, dot, lse, delta)
 
     # k-major grid: the q-block index is innermost for the accumulators
-    qspec_kmaj = pl.BlockSpec((1, 1, rep, bq, d_p),
-                              lambda bi, g, j, i: (bi, g, 0, i, 0),
-                              memory_space=pltpu.VMEM)
-    rowspec_kmaj = pl.BlockSpec((1, 1, rep, bq),
-                                lambda bi, g, j, i: (bi, g, 0, i),
-                                memory_space=pltpu.VMEM)
-    kvspec_kmaj = pl.BlockSpec((1, 1, bk, d_p),
-                               lambda bi, g, j, i: (bi, g, j, 0),
-                               memory_space=pltpu.VMEM)
+    qd, qdv, kd, kdv, row = specs(
+        (lambda j, i: _dkv_q_block(j, i, bq, bk, n_q)) if causal
+        else (lambda j, i: i),
+        lambda j, i: j)
     call = pl.pallas_call(
         functools.partial(_flash_gqa_bwd_dkv_kernel, causal=causal,
                           scale=scale, tk=tk, bq=bq, bk=bk, n_q=n_q),
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, tk_p, d_p), k.dtype),
-            jax.ShapeDtypeStruct((b, hkv, tk_p, d_p), v.dtype),
+            jax.ShapeDtypeStruct((b, hkv, tk_p, dv_p), v.dtype),
         ),
         grid=(b, hkv, n_k, n_q),
-        in_specs=[qspec_kmaj, kvspec_kmaj, kvspec_kmaj, qspec_kmaj,
-                  rowspec_kmaj, rowspec_kmaj],
-        out_specs=(kvspec_kmaj, kvspec_kmaj),
+        in_specs=[qd, kd, kdv, qdv, row, row],
+        out_specs=(
+            pl.BlockSpec((1, 1, bk, d_p),
+                         lambda bi, g, j, i: (bi, g, j, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, bk, dv_p),
+                         lambda bi, g, j, i: (bi, g, j, 0),
+                         memory_space=pltpu.VMEM)),
         scratch_shapes=[pltpu.VMEM((bk, d_p), jnp.float32),
-                        pltpu.VMEM((bk, d_p), jnp.float32)],
-        interpret=interpret,
+                        pltpu.VMEM((bk, dv_p), jnp.float32)],
+        compiler_params=params, interpret=interpret,
         name=scopes.kernel_name(scopes.KERNEL_FLASH_GQA_BWD_DKV),
     )
     with jax.named_scope(scopes.KERNEL_FLASH_GQA_BWD_DKV):
-        dk, dv = call(qt, kt, vt, dot, lse, delta)
+        dk, dvv = call(qt, kt, vt, dot, lse, delta)
 
     dq = dq[:, :, :, :tq, :d].transpose(0, 3, 1, 2, 4).reshape(
         b, tq, h, d)
     dk = dk[:, :, :tk, :d].transpose(0, 2, 1, 3)
-    dv = dv[:, :, :tk, :d].transpose(0, 2, 1, 3)
-    return dq, dk, dv
+    dvv = dvv[:, :, :tk, :dv].transpose(0, 2, 1, 3)
+    return dq, dk, dvv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def flash_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-              causal: bool = True, bwd: str = "chunked") -> jnp.ndarray:
+              causal: bool = True) -> jnp.ndarray:
     """Flash attention with GQA-native unexpanded K/V, on the MXU.
 
     q: (B, Tq, H, D); k: (B, Tk, H_kv, D); v: (B, Tk, H_kv, Dv) with
@@ -411,57 +504,28 @@ def flash_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     (`ops.backend.interpret_mode`); `tools/pallas_check.py` checks the
     compiled kernels on the chip.
 
-    ``bwd`` selects the gradient path: "chunked" (default) recomputes
-    through `_chunked_attention`'s checkpointed scan — pure XLA;
-    "pallas" runs the flash-backward
-    recipe as two Pallas kernels (dq with K innermost; fused dk/dv with
-    Q innermost, the GQA group-sums folded into the (rep, bq)
-    contractions) against the forward's saved LSE — O(1) extra memory,
-    the full fwd+bwd on the MXU.  Both are valid gradients of softmax
-    attention to fp32 round-off and are tested against each other and
-    the XLA AD oracle; pallas_check compiles both on the chip.
+    The gradient is the flash-backward recipe as two Pallas kernels (dq
+    with K innermost; fused dk/dv with Q innermost, the GQA group-sums
+    folded into the (rep, bq) contractions) against the forward's saved
+    LSE — O(1) extra memory, forward and backward on the MXU.  It is
+    tested against `jax.grad` of `_chunked_attention` (the XLA gradient,
+    which `attn_impl="chunked"` still runs) and the exact XLA gradient.
     """
-    _validate_call(q, k, v, bwd)
-    interpret = interpret_mode()
-    out, _ = _flash_gqa_fwd_call(q, k, v, causal, interpret)
+    _gqa_rep(q, k)  # H_kv | H (shared contract, attention.py)
+    out, _ = _flash_gqa_fwd_call(q, k, v, causal, interpret_mode())
     return out
 
 
-def _validate_call(q, k, v, bwd):
-    # shared by the primal AND _fwd: custom_vjp bypasses the primal
-    # under jax.grad, so validation only there would silently accept a
-    # bad bwd string / head ratio in exactly the differentiated case
-    _gqa_rep(q, k)  # H_kv | H (shared contract, attention.py)
-    if bwd not in ("chunked", "pallas"):
-        raise ValueError(f"unknown bwd {bwd!r}; 'chunked' or 'pallas'")
-    if bwd == "pallas" and v.shape[-1] != q.shape[-1]:
-        raise ValueError(
-            f"bwd='pallas' needs v as wide as q/k (got {v.shape[-1]} and "
-            f"{q.shape[-1]}): the backward kernels share one block "
-            f"width; use bwd='chunked'")
+def _fwd(q, k, v, causal):
+    # custom_vjp bypasses the primal under jax.grad: the head ratio is
+    # checked here too
+    _gqa_rep(q, k)
+    out, lse = _flash_gqa_fwd_call(q, k, v, causal, interpret_mode())
+    return out, (q, k, v, out, lse)
 
 
-def _fwd(q, k, v, causal, bwd):
-    _validate_call(q, k, v, bwd)
-    interpret = interpret_mode()
-    out, lse = _flash_gqa_fwd_call(q, k, v, causal, interpret)
-    res = (q, k, v, out, lse) if bwd == "pallas" else (q, k, v)
-    return out, res
-
-
-def _bwd(causal, bwd, res, g):
-    if bwd == "pallas":
-        q, k, v, out, lse = res
-        interpret = interpret_mode()
-        return _flash_gqa_bwd_call(q, k, v, out, lse, g, causal,
-                                   interpret)
-    q, k, v = res
-    from .attention import _chunked_attention
-
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _chunked_attention(q_, k_, v_, causal, 0, 0),
-        q, k, v)
-    return vjp(g)
+def _bwd(causal, res, g):
+    return _flash_gqa_bwd_call(*res, g, causal, interpret_mode())
 
 
 flash_gqa.defvjp(_fwd, _bwd)

@@ -116,11 +116,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="faithful = ordered Kahan accumulation (bit-exact "
                         "reference emulation, the API default); fast = "
                         "cast-and-dot")
-    p.add_argument("--flash-bwd", default="chunked",
-                   choices=["chunked", "pallas"],
-                   help="GQA flash-attention backward: chunked XLA "
-                        "recompute (default) or the Pallas flash-"
-                        "backward kernels (with --attn-impl flash)")
     p.add_argument("--attn-impl", default="xla",
                    choices=["xla", "flash", "chunked"],
                    help="flash = Pallas flash-attention kernels, O(T) "
@@ -262,15 +257,7 @@ def main(argv=None) -> dict:
         # round-5 GQA-native Pallas kernel (ops/flash_gqa.py): plain,
         # ulysses (unexpanded through the all_to_all), decode excluded
         # by the decode path's own gating.  chunked is GQA-native too.
-        model_kw.update(attn_impl=args.attn_impl,
-                        flash_bwd=args.flash_bwd)
-    if args.flash_bwd != "chunked" and not (
-            args.attn_impl == "flash" and args.n_kv_heads is not None):
-        raise ValueError(
-            "--flash-bwd pallas selects the GQA flash-backward kernels, "
-            "which only run with --attn-impl flash AND --n-kv-heads "
-            "(the MHA flash path uses the stock kernel's own backward) "
-            "— without them the flag would be a silent no-op")
+        model_kw.update(attn_impl=args.attn_impl)
     if (args.ffn_exp, args.ffn_man) != (8, 23):
         if args.pp > 1 or args.moe:
             raise ValueError("--ffn-exp/--ffn-man apply to the default "

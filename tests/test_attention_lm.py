@@ -414,9 +414,9 @@ def test_ulysses_flash_gqa_native_unexpanded(monkeypatch):
     calls = {}
     real = fg_mod.flash_gqa
 
-    def spy(q, k, v, causal=True, bwd="chunked"):
+    def spy(q, k, v, causal=True):
         calls["heads"] = (q.shape[2], k.shape[2])
-        return real(q, k, v, causal, bwd)
+        return real(q, k, v, causal)
 
     monkeypatch.setattr(fg_mod, "flash_gqa", spy)
     rng = np.random.RandomState(24)
@@ -996,35 +996,34 @@ def test_flash_attention_impl_gating():
 
 
 def test_block_gqa_flash_pallas_bwd_matches_xla():
-    """Block with attn_impl='flash' + GQA + flash_bwd='pallas' (round
-    5): forward AND parameter gradients match the attn_impl='xla' block
-    on the same params — the model-level composition of the GQA-native
-    kernel with its Pallas backward (CLI: --attn-impl flash
-    --flash-bwd pallas --n-kv-heads)."""
+    """Block with attn_impl='flash' + GQA: forward AND parameter
+    gradients match the attn_impl='xla' block on the same params — the
+    model-level composition of the GQA-native kernel with its Pallas
+    backward (CLI: --attn-impl flash --n-kv-heads)."""
     from cpd_tpu.models.transformer import Block
 
-    def blk(impl, bwd="chunked"):
+    def blk(impl):
         # 4 q heads over 2 kv heads — genuinely grouped, so the flash
         # route lands on the in-repo GQA kernel, not the stock MHA one
         return Block(head_dim=32, d_ff=64, d_model=128, tp_axis=None,
                      sp_axis=None, tp_size=1, dtype=jnp.float32,
-                     n_kv_heads=2, attn_impl=impl, flash_bwd=bwd)
+                     n_kv_heads=2, attn_impl=impl)
 
     rng = np.random.RandomState(17)
     h = jnp.asarray(rng.randn(1, 64, 128).astype(np.float32))
     pos = jnp.arange(64)
     vb = blk("xla").init(jax.random.PRNGKey(6), h, pos)
 
-    def loss(impl, bwd="chunked"):
+    def loss(impl):
         return lambda p: jnp.sum(
-            blk(impl, bwd).apply({"params": p}, h, pos) ** 2)
+            blk(impl).apply({"params": p}, h, pos) ** 2)
 
     out_x = blk("xla").apply(vb, h, pos)
-    out_f = blk("flash", "pallas").apply(vb, h, pos)
+    out_f = blk("flash").apply(vb, h, pos)
     np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_x),
                                rtol=2e-5, atol=2e-5)
     gx = jax.grad(loss("xla"))(vb["params"])
-    gf = jax.grad(loss("flash", "pallas"))(vb["params"])
+    gf = jax.grad(loss("flash"))(vb["params"])
     for (path, a), (_, b) in zip(
             jax.tree_util.tree_flatten_with_path(gf)[0],
             jax.tree_util.tree_flatten_with_path(gx)[0]):

@@ -621,32 +621,39 @@ def test_lm_trainer_smoke(tmp_path):
 
 def test_lm_trainer_flash_gqa_pallas_bwd_reaches_kernel(tmp_path,
                                                        monkeypatch):
-    """--attn-impl flash --n-kv-heads --flash-bwd pallas must actually
-    route through the GQA flash kernel WITH the requested backward —
-    regression for the round-5 indentation slip that left
-    `model_kw.update(attn_impl=...)` stranded after a raise, silently
-    training with xla attention while the flags validated clean."""
+    """--attn-impl flash --n-kv-heads must actually route through the GQA
+    flash kernel, and its gradient through the Pallas backward
+    kernels' call, with no flag to ask for them — regression for the round-5
+    indentation slip that left `model_kw.update(attn_impl=...)` stranded
+    after a raise, silently training with xla attention while the flags
+    validated clean."""
     import sys
 
     import cpd_tpu.ops.flash_gqa  # noqa: F401
     fg_mod = sys.modules["cpd_tpu.ops.flash_gqa"]
     from lm.train import main
 
-    calls = []
-    real = fg_mod.flash_gqa
+    calls, bwd_calls = [], []
+    real, real_bwd = fg_mod.flash_gqa, fg_mod._flash_gqa_bwd_call
 
-    def spy(q, k, v, causal=True, bwd="chunked"):
-        calls.append((q.shape[2], k.shape[2], bwd))
-        return real(q, k, v, causal, bwd)
+    def spy(q, k, v, causal=True):
+        calls.append((q.shape[2], k.shape[2]))
+        return real(q, k, v, causal)
+
+    def spy_bwd(q, k, v, *rest):
+        bwd_calls.append((q.shape[2], k.shape[2]))
+        return real_bwd(q, k, v, *rest)
 
     monkeypatch.setattr(fg_mod, "flash_gqa", spy)
+    monkeypatch.setattr(fg_mod, "_flash_gqa_bwd_call", spy_bwd)
     res = main(["--dp", "8", "--seq-len", "16", "--d-model", "32",
                 "--n-layers", "1", "--n-heads", "4", "--n-kv-heads", "2",
-                "--attn-impl", "flash", "--flash-bwd", "pallas",
+                "--attn-impl", "flash",
                 "--vocab-size", "32", "--batch-size", "2",
                 "--max-iter", "2", "--save-path", str(tmp_path / "lm")])
     assert math.isfinite(res["loss"])
-    assert calls and all(c == (4, 2, "pallas") for c in calls), calls
+    assert calls and all(c == (4, 2) for c in calls), calls
+    assert bwd_calls and all(c == (4, 2) for c in bwd_calls), bwd_calls
 
 
 def test_lm_trainer_pp_and_moe_paths(tmp_path):

@@ -88,7 +88,7 @@ def _oracle(q, k, v, causal=True):
                            causal=causal)
 
 
-IMPLS = {"flash": lambda q, k, v: flash_gqa(q, k, v, True, "chunked"),
+IMPLS = {"flash": lambda q, k, v: flash_gqa(q, k, v, True),
          "chunked": lambda q, k, v: _chunked_attention(q, k, v, True, 0, 0)}
 
 
@@ -117,12 +117,6 @@ def test_flash_long_block_for_a_narrow_group_matches_short_rows():
                                atol=2e-6)
 
 
-def test_flash_pallas_backward_refuses_another_v_width():
-    q, k, v = _qkv(4, 2, 24, 16)
-    with pytest.raises(ValueError, match="bwd='pallas'"):
-        flash_gqa(q, k, v, True, "pallas")
-
-
 # ---- latent attention ---------------------------------------------------
 
 @pytest.mark.parametrize("impl", ["xla", "chunked", "flash"])
@@ -134,6 +128,23 @@ def test_latent_attention_matches_reference(impl):
     got = attn.apply({"params": params}, h, jnp.arange(16))
     want = jnp.stack([ref._attention(h[i], params, CFG) for i in range(2)])
     assert rel(got, want) < 1e-5
+
+
+@pytest.mark.parametrize("impl", ["flash", "chunked"])
+def test_latent_attention_parameter_gradient_matches_xla(impl):
+    """Through the flash kernels' own backward (q/k 12 wide, v 6) and
+    through the scan's, against `attn_impl="xla"` on the same parameters."""
+    h = jax.random.normal(jax.random.PRNGKey(3), (2, 16, 32))
+    pos = jnp.arange(16)
+    attn = lambda impl: mm.LatentAttention(
+        4, 16, 8, 4, 6, rope_theta=50000.0, attn_impl=impl, init_std=0.2)
+    params = attn("xla").init(jax.random.PRNGKey(0), h, pos)["params"]
+    grad = lambda impl: jax.grad(lambda p: jnp.sum(jnp.sin(
+        attn(impl).apply({"params": p}, h, pos))))(params)
+    got, want = grad(impl), grad("xla")
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                            jax.tree.leaves(want)):
+        assert rel(a, b) < 1e-5, path
 
 
 # ---- the routed experts -------------------------------------------------
@@ -307,6 +318,14 @@ def test_parameter_names_are_not_tensor_parallel_ones():
 def test_factory_refuses_fewer_key_heads():
     with pytest.raises(ValueError, match="n_kv_heads"):
         mla_moe_lm(n_heads=8, n_kv_heads=2)
+
+
+def test_factory_drops_the_benchmark_files_backward_key():
+    """`benchmark/configs/moonlight_16b_a3b_ep8_d5.json` still hands the
+    factory the key that once chose attention's backward (ROADMAP D11)."""
+    assert (mla_moe_lm(n_heads=8, attn_impl="flash", flash_bwd="chunked")
+            == mla_moe_lm(n_heads=8, attn_impl="flash"))
+    assert "flash_bwd" not in mm.MLAMoELM.__dataclass_fields__
 
 
 # ---- through make_lm_train_step -----------------------------------------

@@ -113,59 +113,155 @@ def test_flash_gqa_matches_chunked():
     np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
 
 
-def test_flash_gqa_grad_matches_oracle():
-    """custom_vjp backward (chunked-recompute) vs the XLA path's AD."""
-    from cpd_tpu.ops.attention import grouped_query_attention
+def _exact_attention(q, k, v, causal):
+    """Plain softmax attention on expanded K/V: the exact XLA gradient's
+    function (`local_attention` takes a v of any width)."""
+    from cpd_tpu.ops.attention import local_attention
+    rep = q.shape[2] // k.shape[2]
+    return local_attention(q, jnp.repeat(k, rep, 2), jnp.repeat(v, rep, 2),
+                           causal=causal)
+
+
+def _assert_flash_grads(q, k, v, causal):
+    """`jax.grad` through `flash_gqa` against `jax.grad` through
+    `_chunked_attention` and against the exact XLA gradient."""
+    from cpd_tpu.ops.attention import _chunked_attention
     from cpd_tpu.ops.flash_gqa import flash_gqa
 
-    rng = np.random.RandomState(3)
-    q = jnp.asarray(rng.randn(1, 128, 4, 32).astype(np.float32))
-    k = jnp.asarray(rng.randn(1, 128, 2, 32).astype(np.float32))
-    v = jnp.asarray(rng.randn(1, 128, 2, 32).astype(np.float32))
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(
+            fn(q, k, v).astype(jnp.float32))), argnums=(0, 1, 2))(q, k, v)
 
-    def loss(fn):
-        return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
+    gp = grads(lambda q, k, v: flash_gqa(q, k, v, causal))
+    gc = grads(lambda q, k, v: _chunked_attention(q, k, v, causal, 0, 0))
+    gx = grads(lambda q, k, v: _exact_attention(q, k, v, causal))
+    # float32: the three read within 2e-6 of each other at these shapes.
+    # bf16: p and ds are rounded to 8 bits before their products in all
+    # three, at different places
+    tol = 2e-5 if q.dtype == jnp.float32 else 6e-2
+    for a, b_, c in zip(gp, gc, gx):
+        assert a.shape == b_.shape and a.dtype == q.dtype
+        a = np.asarray(a.astype(jnp.float32))
+        np.testing.assert_allclose(a, np.asarray(b_.astype(jnp.float32)),
+                                   rtol=tol, atol=tol)
+        np.testing.assert_allclose(a, np.asarray(c.astype(jnp.float32)),
+                                   rtol=tol, atol=tol)
 
-    gf = jax.grad(loss(lambda q, k, v: flash_gqa(q, k, v, True)),
-                  argnums=(0, 1, 2))(q, k, v)
-    gx = jax.grad(loss(lambda q, k, v: grouped_query_attention(
-        q, k, v, causal=True)), argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(gf, gx):
+
+def _qkv(tq, tk, h, hkv, d, dv, dtype=jnp.float32, seed=9):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (1, tq, h, d)).astype(dtype),
+            jax.random.normal(ks[1], (1, tk, hkv, d)).astype(dtype),
+            jax.random.normal(ks[2], (1, tk, hkv, dv)).astype(dtype))
+
+
+_WIDTHS = {"rep12": (12, 1, 16, 16), "v_narrower": (2, 2, 24, 16),
+           "v_wider": (4, 2, 16, 24)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("widths", list(_WIDTHS))
+def test_flash_gqa_grad_matches_chunked_and_exact(widths, causal, dtype):
+    """The two Pallas flash-backward kernels (dq with K innermost; fused
+    dk/dv with Q innermost, the GQA group sums inside the (rep, bq)
+    contractions; each width padded on its own) at a length (300) that is
+    a multiple of no block."""
+    _assert_flash_grads(*_qkv(300, 300, *_WIDTHS[widths], dtype), causal)
+
+
+@pytest.mark.parametrize("tq,tk,causal,widths,dtype", [
+    (130, 100, False, "v_narrower", jnp.float32),
+    (130, 100, False, "rep12", jnp.bfloat16),
+    (40, 100, False, "v_narrower", jnp.float32),   # Tq < 128: one block
+    (40, 100, False, "v_wider", jnp.bfloat16),     # of ceil8(Tq) rows
+    (64, 192, True, "v_wider", jnp.float32),
+    (200, 72, True, "v_narrower", jnp.float32),
+])
+def test_flash_gqa_grad_ragged_lengths(tq, tk, causal, widths, dtype):
+    """Tq != Tk: bq comes from Tq and bk from Tk, the forward's lse is cut
+    to Tq and padded to the backward's own block, and q's pad rows and
+    k's pad rows differ in number."""
+    _assert_flash_grads(*_qkv(tq, tk, *_WIDTHS[widths], dtype), causal)
+
+
+@pytest.mark.parametrize("tq,tk,bq,bk,causal", [
+    (984, 984, 128, 128, True),
+    (1112, 1112, 128, 256, True),
+    (1240, 1240, 256, 128, True),
+    (130, 520, 128, 128, True),    # key blocks past every query row: the
+    (200, 900, 128, 256, True),    # dk/dv maps' clamp to the last q block
+    (520, 130, 128, 128, True),
+    (300, 200, 128, 128, False),
+    (40, 300, 40, 128, False),
+])
+def test_flash_gqa_backward_over_many_blocks(monkeypatch, tq, tk, bq, bk,
+                                             causal):
+    """At the lengths `_bwd_blocks` picks, a CPU-sized sequence is one
+    block; with short ones handed in, the causal steps that are skipped
+    and those whose copies are skipped too all run, square and ragged."""
+    import sys
+    from cpd_tpu.ops.flash_gqa import flash_gqa
+    fg = sys.modules["cpd_tpu.ops.flash_gqa"]
+
+    # (the lengths are read when the call is traced: no other test has
+    # these shapes, so none meets this one's entries in the jit's cache)
+    q, k, v = _qkv(tq, tk, 2, 1, 16, 8, seed=4)
+
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v))),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    want = grads(lambda q, k, v: _exact_attention(q, k, v, causal))
+    monkeypatch.setattr(fg, "_bwd_blocks", lambda *a: (bq, bk))
+    got = grads(lambda q, k, v: flash_gqa(q, k, v, causal))
+    for a, b_ in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-5, atol=2e-5)
 
 
-def test_flash_gqa_pallas_backward_matches_oracle():
-    """bwd='pallas' (round 5): the two flash-backward kernels (dq with K
-    innermost; fused dk/dv with Q innermost, GQA group-sums inside the
-    (rep, bq) contractions) against the forward's saved LSE — grads must
-    match the XLA AD oracle AND the default chunked-recompute bwd."""
-    from cpd_tpu.ops.attention import grouped_query_attention
-    from cpd_tpu.ops.flash_gqa import flash_gqa
+def test_flash_gqa_backward_block_lengths():
+    """The rule's choices at the benchmark's two calls (timed on the
+    chip, PERF.md section 6, PR 31), at short and ragged sequences, and
+    at a large group."""
+    import sys
+    import cpd_tpu.ops.flash_gqa  # noqa: F401
+    blocks = sys.modules["cpd_tpu.ops.flash_gqa"]._bwd_blocks
 
-    rng = np.random.RandomState(9)
-    for (tq, tk, hkv, causal) in [(128, 128, 2, True),
-                                  (130, 100, 2, False)]:
-        q = jnp.asarray(rng.randn(1, tq, 4, 32).astype(np.float32))
-        k = jnp.asarray(rng.randn(1, tk, hkv, 32).astype(np.float32))
-        v = jnp.asarray(rng.randn(1, tk, hkv, 32).astype(np.float32))
+    assert blocks(1, 8192, 8192) == (1024, 1024)
+    assert blocks(12, 4096, 4096) == (128, 512)
+    assert blocks(2, 300, 300) == (512, 512)
+    assert blocks(2, 130, 100) == (256, 128)
+    assert blocks(2, 40, 100) == (40, 128)
+    assert blocks(4, 4096, 4096) == (256, 1024)
+    assert blocks(32, 4096, 4096) == (128, 256)
 
-        def loss(fn):
-            return lambda q, k, v: jnp.sum(jnp.sin(fn(q, k, v)))
 
-        gp = jax.grad(loss(lambda q, k, v: flash_gqa(
-            q, k, v, causal, "pallas")), argnums=(0, 1, 2))(q, k, v)
-        gc = jax.grad(loss(lambda q, k, v: flash_gqa(
-            q, k, v, causal)), argnums=(0, 1, 2))(q, k, v)
-        gx = jax.grad(loss(lambda q, k, v: grouped_query_attention(
-            q, k, v, causal=causal)), argnums=(0, 1, 2))(q, k, v)
-        for a, b_, c in zip(gp, gc, gx):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(c),
-                                       rtol=2e-5, atol=2e-5)
-            np.testing.assert_allclose(np.asarray(b_), np.asarray(c),
-                                       rtol=2e-5, atol=2e-5)
-    with pytest.raises(ValueError, match="bwd"):
-        flash_gqa(q, k, v, True, "nope")
+@pytest.mark.parametrize("tq,tk,bq,bk", [
+    (8192, 8192, 1024, 1024), (4096, 4096, 128, 512), (130, 520, 128, 128),
+    (200, 900, 128, 256), (520, 130, 128, 128), (1500, 4000, 1024, 1024)])
+def test_flash_gqa_backward_causal_steps_name_blocks_in_range(tq, tk, bq,
+                                                              bk):
+    """The interpreter forgives a block index past the array, the chip
+    does not: every step of both causal grids names a block that exists,
+    and a step that computes names its own."""
+    import sys
+    import cpd_tpu.ops.flash_gqa  # noqa: F401
+    fg = sys.modules["cpd_tpu.ops.flash_gqa"]
+
+    n_q, n_k = -(-tq // bq), -(-tk // bk)
+    i, j = np.meshgrid(np.arange(n_q), np.arange(n_k), indexing="ij")
+    computes = j * bk <= i * bq + (bq - 1)       # the kernels' own test
+    kj = np.asarray(fg._dq_k_block(i, j, bq, bk))
+    qi = np.asarray(fg._dkv_q_block(j, i, bq, bk, n_q))
+    assert kj.min() >= 0 and kj.max() < n_k
+    assert qi.min() >= 0 and qi.max() < n_q
+    np.testing.assert_array_equal(kj[computes], j[computes])
+    np.testing.assert_array_equal(qi[computes], i[computes])
+    # a skipped step names the block of the nearest step that computes
+    assert (np.diff(kj, axis=1) >= 0).all()
+    assert (np.diff(qi, axis=0) >= 0).all()
 
 
 def test_flash_gqa_routing_and_validation():

@@ -117,7 +117,8 @@ def test_compiled_step_names_its_layers(builder, mode, use_aps):
     assert under == UNDER_REDUCE[mode, use_aps], (
         sorted(under ^ UNDER_REDUCE[mode, use_aps]))
     if builder is _lm:
-        assert scopes.KERNEL_FLASH_GQA_FWD in found
+        assert {scopes.KERNEL_FLASH_GQA_FWD, scopes.KERNEL_FLASH_GQA_BWD_DQ,
+                scopes.KERNEL_FLASH_GQA_BWD_DKV} <= found
 
 
 def test_one_rank_reduction_is_local_and_has_no_wire():
@@ -205,8 +206,9 @@ def test_model_layers_are_named_under_the_loss_grad_scope():
     assert found <= KNOWN and MODEL_LAYERS <= found
     for layer in MODEL_LAYERS:
         assert (scopes.LOSS_GRAD, layer) in {p[:2] for p in paths}, layer
-    assert (scopes.LOSS_GRAD, scopes.MLA,
-            scopes.KERNEL_FLASH_GQA_FWD) in paths
+    for kernel in (scopes.KERNEL_FLASH_GQA_FWD, scopes.KERNEL_FLASH_GQA_BWD_DQ,
+                   scopes.KERNEL_FLASH_GQA_BWD_DKV):
+        assert (scopes.LOSS_GRAD, scopes.MLA, kernel) in paths, kernel
 
 
 def test_every_scope_is_used_and_lives_in_one_place():

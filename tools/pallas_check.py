@@ -26,8 +26,8 @@ Checks (bitwise vs the XLA composition unless noted):
      vs the reference implementation (allclose)
   4. a transformer Block with attn_impl="flash" vs "xla" (allclose)
   5. chunked attention — pure XLA; cross-checked against 3 on the chip
-  6. flash_gqa — forward (incl. a short-Tq case, bq < 128), the chunked
-     backward and the Pallas backward (allclose)
+  6. flash_gqa — forward (incl. a short-Tq case, bq < 128) and its Pallas
+     backward, at small shapes and at the benchmark cells' own (allclose)
   7. the ring's wire kernels — quantize_add, quantize_pack, hop_pack
      (plain / digest / blocked / multi-tile) and digest_rows: the three
      kernels `--mode ring` selects by default on TPU
@@ -200,27 +200,72 @@ def _flash_gqa_fwd(shapes):
     return check
 
 
-def _flash_gqa_bwd(bwd):
+def _flash_gqa_bwd():
+    """The Pallas gradient against the exact XLA one at small float32
+    shapes, square and ragged, with a v of its own width, then against the
+    chunked XLA gradient at the benchmark cells' own shapes in bf16: the
+    padded columns (192 -> 256) and pad rows are what a CPU's zeros can
+    hide."""
     def check(rng):
         import jax
         import jax.numpy as jnp
-        from cpd_tpu.ops.attention import grouped_query_attention
+        import numpy as np
+        from cpd_tpu.ops.attention import (_chunked_attention,
+                                           local_attention)
         from cpd_tpu.ops.flash_gqa import flash_gqa
 
-        q, k, v = _qkv(rng, 1, 128, 128, 4, 2, 32)
+        def grads(fn, *qkv):
+            return jax.jit(jax.grad(
+                lambda a, b, c: jnp.sum(jnp.sin(fn(a, b, c).astype(
+                    jnp.float32))), argnums=(0, 1, 2)))(*qkv)
 
-        def loss(fn):
-            return lambda a, b, c: jnp.sum(jnp.sin(fn(a, b, c)))
-
-        want = _exact(jax.grad(loss(lambda a, b, c: grouped_query_attention(
-            a, b, c, causal=True)), argnums=(0, 1, 2)), q, k, v)
-        got = jax.grad(loss(lambda a, b, c: flash_gqa(a, b, c, True, bwd)),
-                       argnums=(0, 1, 2))(q, k, v)
+        flash = lambda a, b, c: flash_gqa(a, b, c, True)
         bad = []
-        for name, a, b in zip("qkv", got, want):
-            diff = _close(a, b, 5e-2)
-            if diff:
-                bad.append(f"d{name} {diff}")
+        # (Tq, Tk, heads, kv heads, D, Dv, causal).  The ragged ones: pad
+        # rows of q and of k that differ in number, one block of 40 rows,
+        # and at (1,024 x 1,024) blocks four key blocks against two of
+        # queries, where a dk/dv step names a q block past the array
+        # unless its index is clamped (the interpreter forgives that),
+        # then five q blocks against two of keys.  Groups of one or two
+        # heads: twelve heads' rows summed into one key head put dk past
+        # this tolerance by the bf16 rounding of the inputs alone (1.4
+        # times it at 4,000 x 1,500; the chip read maxdiff 0.41 of 172),
+        # so a group of twelve is held to the chunked gradient below
+        for (tq, tk, h, hkv, d, dv, causal) in [
+                (128, 128, 4, 2, 32, 32, True),
+                (300, 300, 2, 2, 24, 16, True),
+                (130, 100, 4, 2, 32, 32, False),
+                (40, 100, 2, 2, 24, 16, False),
+                (1500, 4000, 2, 2, 24, 16, True),
+                (2500, 1300, 4, 2, 24, 16, True)]:
+            q, k, v = _qkv(rng, 1, tq, tk, h, hkv, d)
+            v = v[..., :dv]
+            want = _exact(grads, lambda a, b, c: local_attention(
+                a, jnp.repeat(b, h // hkv, 2), jnp.repeat(c, h // hkv, 2),
+                causal=causal), q, k, v)
+            got = grads(lambda a, b, c: flash_gqa(a, b, c, causal), q, k, v)
+            for name, a, b in zip("qkv", got, want):
+                diff = _close(a, b, 5e-2)
+                if diff:
+                    bad.append(f"tq={tq} tk={tk} {d}/{dv} d{name} {diff}")
+        # (batch, Tq, Tk, heads, kv heads, D, Dv): Moonlight's attention
+        # a sequence (the chunked gradient of two keeps 8.7 GiB of
+        # temporaries), StarCoder2's, and its group of twelve ragged
+        for (bsz, t, tk, h, hkv, d, dv) in [
+                (1, 8192, 8192, 16, 16, 192, 128),
+                (2, 4096, 4096, 24, 2, 128, 128),
+                (1, 4000, 1500, 24, 2, 128, 128)]:
+            q, k, v = (x.astype(jnp.bfloat16)
+                       for x in _qkv(rng, bsz, t, tk, h, hkv, d))
+            v = v[..., :dv]
+            want = grads(lambda a, b, c: _chunked_attention(
+                a, b, c, True, 0, 0), q, k, v)
+            for name, a, b in zip("qkv", grads(flash, q, k, v), want):
+                a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+                diff = _close(a, b, 5e-2)
+                if diff:
+                    bad.append(f"tq={t} tk={tk} h={h}/{hkv} {d}/{dv} "
+                               f"bf16 d{name} {diff}")
         return bad
     return check
 
@@ -378,8 +423,7 @@ def checks() -> list:
         # lane dimension is bq
         ("flash_gqa fwd short-Tq", _flash_gqa_fwd(
             [(8, 128, 4, 2, 64, True), (40, 256, 8, 2, 64, False)])),
-        ("flash_gqa bwd=chunked", _flash_gqa_bwd("chunked")),
-        ("flash_gqa bwd=pallas", _flash_gqa_bwd("pallas")),
+        ("flash_gqa bwd", _flash_gqa_bwd()),
         ("quantize_add_pallas[_bits]", check_quantize_add),
     ]
     # 384 elements = one kernel tile; 200_000 = four, exercising the
