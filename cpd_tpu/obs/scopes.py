@@ -14,8 +14,8 @@ Stdlib-only, like the rest of `obs`.
 
 | scope | entered in | covers |
 | --- | --- | --- |
-| `cpd.loss_grad` | `train/step.py`, `train/lm.py`, `parallel/overlap.py` | forward and backward; jax's own `jvp(` / `transpose(` markers deeper in the stack split the two |
-| `cpd.emulate_node` | `parallel/emulate.py` | the in-chip emulated-node reduction |
+| `cpd.loss_grad` | `train/grads.py`, `parallel/overlap.py` | forward and backward; jax's own `jvp(` / `transpose(` markers deeper in the stack split the two |
+| `cpd.emulate_node` | `parallel/emulate.py`, called by `train/grads.py` | the in-chip emulated-node reduction |
 | `cpd.reduce` | `parallel/dist.py:sum_gradients` | the whole gradient reduction, for every caller |
 | `aps.max_exp` / `aps.scale` / `aps.unscale` | `parallel/aps.py` | the APS passes (the maximum includes its `pmax`) |
 | `wire.cast` | `parallel/dist.py` | the eXmY cast before (and, in `fast` mode, after) the wire |

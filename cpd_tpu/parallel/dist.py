@@ -190,8 +190,8 @@ def quantize_tree_sr(tree, grad_exp: int, grad_man: int, key,
 
 
 def grad_sr_key(grad_seed: int, step, site: int) -> jax.Array:
-    """The ONE derivation of gradient-pipeline SR keys, shared by every
-    train-step builder (train/step.py, lm.py, pp.py, moe.py).
+    """The ONE derivation of gradient-pipeline SR keys; the gradient
+    stage (train/grads.py) calls it for every train-step builder.
 
     Depends only on (grad_seed, step, site) — NEVER a rank index: the
     same key must reach every sp/tp/pp/ep copy so replicated leaves

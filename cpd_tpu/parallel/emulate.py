@@ -79,11 +79,11 @@ def reduce_stacked_leaf(g: jnp.ndarray, n: int, use_aps: bool = False,
 
 def make_overlap_emulate_fn(n: int, use_aps: bool, grad_exp: int,
                             grad_man: int, sr: bool):
-    """The ONE `overlapped_grads(emulate_reduce=...)` hook body, shared
-    by both step builders (train/step.py, train/lm.py) so the SR-key
-    contract — `fold_in(emu_key, GLOBAL leaf index)` feeding
-    `reduce_stacked_leaf`, exactly `emulate_node_reduce`'s per-leaf
-    streams — cannot drift between them.
+    """The `overlapped_grads(emulate_reduce=...)` hook body of the
+    gradient stage (train/grads.py), kept beside `emulate_node_reduce`
+    so the SR-key contract — `fold_in(emu_key, GLOBAL leaf index)`
+    feeding `reduce_stacked_leaf`, exactly `emulate_node_reduce`'s
+    per-leaf streams — is read in one file.
 
     Returns ``fn(cotangent, extra, leaf_index, emu_key)``: stacks the
     LAST micro-batch's cotangent under the prior micro-batches' stacked

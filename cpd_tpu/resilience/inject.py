@@ -144,8 +144,8 @@ GRAD_KINDS = {"grad_nan": 1, "grad_inf": 2, "grad_blowup": 3}
 # wire-level kinds -> corruption opcode inside ring_quantized_sum
 # (parallel/ring.py _apply_hop_fault / the gather-wire fault)
 WIRE_KINDS = {"wire_flip": 1, "wire_stale": 2, "wire_drop": 3}
-# saturation-pressure kind, executed by the step builders' baked 2^k
-# gradient-scale table (train/step.py, train/lm.py sat_fault_plan) —
+# saturation-pressure kind, executed by the gradient stage's baked 2^k
+# gradient-scale table (train/grads.py, the builders' sat_fault_plan) —
 # the attack the precision ladder is exercised against
 SAT_KINDS = frozenset({"sat_pressure"})
 SAT_PRESSURE_DEFAULT_EXP = 24          # arg -1 -> scale by 2^24
@@ -459,9 +459,8 @@ class FaultPlan:
 
 def sat_pressure_factor(table, step):
     """The 2^k gradient scale for optimizer update ``step`` from a dense
-    `FaultPlan.sat_schedule` table — jit-safe, the ONE lookup shared by
-    the step builders (train/step.py, train/lm.py) so the clip/where
-    indexing cannot drift between them.  Entry 0 -> 2^0 == 1.0, an
+    `FaultPlan.sat_schedule` table — jit-safe, the ONE lookup, called
+    by the gradient stage (train/grads.py) for every step builder.  Entry 0 -> 2^0 == 1.0, an
     exact fp32 no-op; steps past the table are unpressured."""
     import jax.numpy as jnp
 

@@ -16,7 +16,8 @@ framework supports:
   are already complete on their shard.
 
 Gradient flow: local grads → psum over sp (all) → psum over tp
-(replicated params only) → quantized sum_gradients over dp → optimizer.
+(replicated params only) → the gradient stage's emulated-node reduce and
+quantized sum over dp (train/grads.py) → optimizer.
 The optimizer update runs shard-local, which is exact for the elementwise
 SGD family (train/optim.py); LARS trust ratios would need global norms —
 use sgd/nesterov here.
@@ -35,8 +36,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..models.transformer import lm_param_specs
 from ..compat import shard_map
 from ..obs import scopes
-from ..parallel.dist import grad_sr_key, sum_gradients
-from ..parallel.emulate import emulate_node_reduce
+from .grads import ReduceOptions, reduced, report_metrics
 from .state import (TrainState, make_sharded_stepper, reject_norm_based,
                     state_specs_like)
 
@@ -77,45 +77,27 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     ``label_smoothing`` in [0, 1) mixes the one-hot targets with uniform
     mass (training loss only — eval stays plain CE).
 
-    verify_reduce / wire_fault_plan: the self-verifying dp reduction and
-    its deterministic wire-fault table, exactly as on
-    `train.step.make_train_step` (the reduce_ok/... metrics feed the
-    transport supervisor).  The sp/tp psums stay unverified — they are
-    XLA's own collectives with no custom wire.
-
-    quant_stats / sat_fault_plan: reduce-wire numeric-health telemetry
-    (``prec_wire_*`` / ``prec_aps_bad`` metrics feeding the
-    `resilience.precision.PrecisionSupervisor`) and the deterministic
-    2^k saturation-pressure table, exactly as on `make_train_step` —
-    the pressure scales the post-sp/tp-psum local gradients, so every
-    dp rank's wire cast sees it identically.
-
-    overlap_reduce / bucket_elems: the bucketed, dependency-scheduled
-    transport, exactly as on `make_train_step` (parallel/overlap.py) —
-    per-bucket taps run the dp reduction inside the backward; each
-    leaf's sp psum (and tp psum for replicated params) moves INTO its
-    bucket's tap, so the whole per-leaf reduction chain starts when
-    that bucket closes.  Bitwise identical to the monolithic step.
-    Composes with emulate_node > 1 (ISSUE 12): the first N-1
-    micro-batches run unrolled and their sp/tp-reduced stacked grads
-    ride into the last micro-batch's taps, whose per-bucket
-    emulate-node reduce + dp collective fire as each bucket closes.
-
-    block_scale / block_size: the EQuARX-style block-scaled ring wire
-    for the dp reduction, exactly as on `make_train_step` — ring mode
-    only; a distinct accumulation numerics (own StepTable key via
-    `ladder_step_key(block=...)`); composes with overlap_reduce
-    bitwise.  The sp/tp psums are untouched (fp32 XLA collectives).
+    use_aps ... block_size, the fifteen keywords of the dp reduction, are
+    `train.grads.ReduceOptions`' fields and are described there, once.
+    What is this builder's own about them: the sp/tp psums stay
+    unverified, unblocked and fp32 (XLA's own collectives, no custom
+    wire); the saturation pressure scales the post-sp/tp-psum gradients,
+    so every dp rank's wire cast sees it identically; under
+    overlap_reduce each leaf's sp psum (and tp psum for replicated
+    params) moves INTO its bucket's tap, so the whole per-leaf reduction
+    chain starts when that bucket closes.
     """
     if not 0.0 <= label_smoothing < 1.0:
         raise ValueError(f"label_smoothing must be in [0, 1), got "
                          f"{label_smoothing}")
-    if grad_rounding not in ("nearest", "stochastic"):
-        raise ValueError(f"unknown grad_rounding {grad_rounding!r}")
-    if block_scale and mode != "ring":
-        raise ValueError(
-            f"block_scale=True needs mode='ring' (got {mode!r}): the "
-            f"per-block scale sidecar rides the ring's packed wire")
+    opts = ReduceOptions(
+        use_aps=use_aps, grad_exp=grad_exp, grad_man=grad_man,
+        use_kahan=use_kahan, mode=mode, grad_rounding=grad_rounding,
+        grad_seed=grad_seed, verify_reduce=verify_reduce,
+        wire_fault_plan=wire_fault_plan, quant_stats=quant_stats,
+        sat_fault_plan=sat_fault_plan, overlap_reduce=overlap_reduce,
+        bucket_elems=bucket_elems, block_scale=block_scale,
+        block_size=block_size).check()
     # Guard: the optimizer update runs shard-local, which is only exact for
     # elementwise transforms (see reject_norm_based).  With tp=1 all params
     # are replicated and grads fully reduced before the update, so
@@ -143,7 +125,8 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 for name, values in by_name.items()}
 
     def step_fn(state: TrainState, tokens, targets):
-        def loss_of(params, toks, tgts, micro_idx):
+        def loss_of(params, _, xy, micro_idx):
+            toks, tgts = xy
             rngs = {}
             if has_dropout:
                 # deterministic in (seed, global step, micro index) and
@@ -192,160 +175,26 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             # divide-so-the-sum-is-the-mean, per micro-batch)
             loss = local_sum / global_n / emulate_node
             hits = jnp.sum(jnp.argmax(logits, -1) == tgts)
-            return loss, (local_sum, local_n, hits, counts)
+            return loss, (None, (local_sum, local_n, hits, counts))
 
-        n = emulate_node
-        mb = tokens.shape[0] // n
         # --- cross-axis gradient reduction (see module docstring) ---
-        specs = lm_param_specs(state.params, axis_tp)
+        specs = jax.tree.leaves(lm_param_specs(state.params, axis_tp),
+                                is_leaf=lambda s: isinstance(s, P))
 
-        def sp_tp_reduce(stacked_g, spec):
-            g = lax.psum(stacked_g, axis_sp)
-            if spec == P():                 # replicated param: finish tp sum
+        def sp_tp_reduce(g, i):
+            g = lax.psum(g, axis_sp)
+            if specs[i] == P():             # replicated param: finish tp sum
                 g = lax.psum(g, axis_tp)
             return g
 
-        # SR keys (grad_rounding='stochastic'): the rank-local emulate key
-        # folds ONLY the dp index — post-psum grads are identical across
-        # sp (and across tp for replicated params), so sp/tp copies must
-        # draw identical bits or their optimizer states would diverge;
-        # dp ranks hold different grads and decorrelate (see
-        # parallel/dist.py on coherent rounding error).
-        sr = grad_rounding == "stochastic"
-        sum_key = grad_sr_key(grad_seed, state.step, 1) if sr else None
-        wf = None
-        if wire_fault_plan is not None and mode == "ring":
-            codes = jnp.asarray(wire_fault_plan[0], jnp.int32)
-            ranks = jnp.asarray(wire_fault_plan[1], jnp.int32)
-            idx = jnp.clip(state.step, 0, codes.shape[0] - 1)
-            wf = (jnp.where(state.step < codes.shape[0], codes[idx], 0),
-                  ranks[idx])
-        sfac = None
-        if sat_fault_plan is not None:
-            # saturation-pressure attack (resilience/inject.py
-            # `sat_pressure`): 2^k exact power-of-two scaling, shared
-            # lookup (see make_train_step)
-            from ..resilience.inject import sat_pressure_factor
-            sfac = sat_pressure_factor(sat_fault_plan, state.step)
-        vreport = None
-        if overlap_reduce:
-            # Bucketed dependency-scheduled transport (parallel/
-            # overlap.py): per-bucket taps own the WHOLE per-leaf
-            # reduction chain — sp psum, tp psum for replicated params
-            # (leaf_pre), sat pressure, emulate-node reduce (n > 1:
-            # micro-batches 0..N-2 run unrolled and their sp/tp-reduced
-            # stacked grads ride into the LAST micro-batch's taps as
-            # extras, ISSUE 12 leg 3), then the dp quantized collective
-            # — so a bucket's work starts the moment its last cotangent
-            # closes.  Bitwise identical to the monolithic path below.
-            from ..parallel.overlap import BucketPlan, overlapped_grads
-            plan = BucketPlan.for_tree(state.params, bucket_elems)
-            specs_flat = jax.tree_util.tree_flatten(
-                specs, is_leaf=lambda s: isinstance(s, P))[0]
-
-            def leaf_pre(g, i):
-                return sp_tp_reduce(g, specs_flat[i])
-
-            extras = emulate_fn = emu_key = None
-            micro_sums, micro_ns, micro_hits, micro_counts = [], [], [], []
-            if n > 1:
-                toks_u = tokens.reshape(n, mb, tokens.shape[1])
-                tgts_u = targets.reshape(n, mb, targets.shape[1])
-                prev = []
-                for mi in range(n - 1):
-                    with jax.named_scope(scopes.LOSS_GRAD):
-                        (_, (s_mi, n_mi, h_mi, c_mi)), g_mi = (
-                            jax.value_and_grad(loss_of, has_aux=True)(
-                                state.params, toks_u[mi], tgts_u[mi],
-                                jnp.int32(mi)))
-                    micro_sums.append(s_mi)
-                    micro_ns.append(n_mi)
-                    micro_hits.append(h_mi)
-                    micro_counts.append(c_mi)
-                    prev.append(jax.tree_util.tree_leaves(g_mi))
-                # sp/tp-reduce + sat-scale the prior micros here (the
-                # taps apply leaf_pre/aux[0] to the LAST micro's
-                # cotangent only) — elementwise psums, so per-micro
-                # equals the monolith's stacked psum bit for bit
-                extras = []
-                for i in range(len(plan.sizes)):
-                    st = jnp.stack([prev[mi][i] for mi in range(n - 1)])
-                    st = sp_tp_reduce(st, specs_flat[i])
-                    if sfac is not None:
-                        st = st * sfac
-                    extras.append(st)
-                if sr:
-                    emu_key = jax.random.fold_in(
-                        grad_sr_key(grad_seed, state.step, 0),
-                        lax.axis_index(axis_dp).astype(jnp.int32))
-                from ..parallel.emulate import make_overlap_emulate_fn
-                emulate_fn = make_overlap_emulate_fn(
-                    n, use_aps, grad_exp, grad_man, sr)
-                tk_last, tg_last = toks_u[n - 1], tgts_u[n - 1]
-                last_idx = jnp.int32(n - 1)
-            else:
-                tk_last, tg_last = tokens, targets
-                last_idx = jnp.zeros([], jnp.int32)
-
-            def loss_closure(p):
-                loss, aux = loss_of(p, tk_last, tg_last, last_idx)
-                return loss, aux
-
-            ((_, (l_sum, l_n, l_hits, l_counts)), reduced,
-             vreport) = overlapped_grads(
-                loss_closure, state.params, axis_name=axis_dp, plan=plan,
-                reduce_kw=dict(use_aps=use_aps, grad_exp=grad_exp,
-                               grad_man=grad_man, use_kahan=use_kahan,
-                               mode=mode, rounding=grad_rounding,
-                               bucket_elems=bucket_elems,
-                               block_scale=block_scale,
-                               block_size=block_size),
-                key=sum_key, sat_factor=sfac, wire_fault=wf,
-                verify=verify_reduce, stats=quant_stats,
-                leaf_pre=leaf_pre, collective=None, extras=extras,
-                emulate_reduce=emulate_fn, emulate_key=emu_key)
-            sums = jnp.stack(micro_sums + [l_sum])
-            ns = jnp.stack(micro_ns + [l_n])
-            hits = jnp.stack(micro_hits + [l_hits])
-            counts = {name: jnp.stack([c[name] for c in
-                                       micro_counts + [l_counts]])
-                      for name in counters}
-        else:
-            toks = tokens.reshape(n, mb, tokens.shape[1])
-            tgts = targets.reshape(n, mb, targets.shape[1])
-
-            def micro(micro_idx, xy):
-                tk, tg = xy
-                (_, aux), grads = jax.value_and_grad(
-                    loss_of, has_aux=True)(state.params, tk, tg, micro_idx)
-                return micro_idx + 1, (grads, *aux)
-
-            with jax.named_scope(scopes.LOSS_GRAD):
-                _, (stacked, sums, ns, hits, counts) = lax.scan(
-                    micro, jnp.zeros([], jnp.int32), (toks, tgts))
-
-            stacked = jax.tree.map(sp_tp_reduce, stacked, specs)
-            if sfac is not None:
-                stacked = jax.tree.map(lambda g: g * sfac, stacked)
-            local = emulate_node_reduce(
-                stacked, n, use_aps, grad_exp, grad_man,
-                rounding=grad_rounding,
-                key=jax.random.fold_in(
-                    grad_sr_key(grad_seed, state.step, 0),
-                    lax.axis_index(axis_dp).astype(jnp.int32)) if sr
-                else None)
-            reduced = sum_gradients(
-                local, axis_dp, use_aps=use_aps,
-                grad_exp=grad_exp, grad_man=grad_man,
-                use_kahan=use_kahan, mode=mode, rounding=grad_rounding,
-                key=sum_key, verify=verify_reduce, wire_fault=wf,
-                stats=quant_stats, bucket_elems=bucket_elems,
-                block_scale=block_scale, block_size=block_size)
-            if verify_reduce or quant_stats:
-                reduced, vreport = reduced
+        out = reduced(
+            loss_of, state.params, (tokens, targets), n=emulate_node,
+            carry=None, step=state.step, axis_dp=axis_dp, opts=opts,
+            leaf_pre=sp_tp_reduce)
+        sums, ns, hits, counts = out.aux
 
         with jax.named_scope(scopes.OPTIMIZER):
-            updates, new_opt = tx.update(reduced, state.opt_state,
+            updates, new_opt = tx.update(out.grads, state.opt_state,
                                          state.params)
             new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(step=state.step + 1, params=new_params,
@@ -366,22 +215,7 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 over_micros, over_ranks = merge[how]
                 metrics[name] = over_ranks(over_micros(counts[name]),
                                            (axis_dp, axis_sp))
-        if vreport is not None:
-            f32 = jnp.float32
-            if verify_reduce:
-                metrics.update(
-                    reduce_ok=vreport["ok"].astype(f32),
-                    reduce_hop_bad=vreport["hop_bad"].astype(f32),
-                    reduce_gather_bad=vreport["gather_bad"].astype(f32),
-                    reduce_agree=vreport["agree"].astype(f32))
-            if quant_stats:
-                metrics.update(
-                    prec_wire_sat=vreport["wire_sat"].astype(f32),
-                    prec_wire_underflow=vreport["wire_underflow"]
-                    .astype(f32),
-                    prec_wire_nan=vreport["wire_nan"].astype(f32),
-                    prec_wire_total=vreport["wire_total"].astype(f32),
-                    prec_aps_bad=vreport["aps_bad"].astype(f32))
+        metrics.update(report_metrics(out.report, opts))
         return new_state, metrics
 
     return make_sharded_stepper(
@@ -433,7 +267,8 @@ def ir_programs(reg):
     from .optim import make_optimizer
     from .state import create_train_state
 
-    deps = ("cpd_tpu.train.lm", "cpd_tpu.parallel.dist",
+    deps = ("cpd_tpu.train.lm", "cpd_tpu.train.grads",
+            "cpd_tpu.parallel.dist",
             "cpd_tpu.parallel.ring", "cpd_tpu.parallel.overlap",
             "cpd_tpu.parallel.aps", "cpd_tpu.quant.numerics",
             "cpd_tpu.models.transformer")

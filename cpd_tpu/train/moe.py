@@ -26,7 +26,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.moe import MoETransformerLM, moe_param_specs
 from ..compat import shard_map
-from ..parallel.dist import grad_sr_key, sum_gradients
+from .grads import ReduceOptions, reduce_local
 from .state import (TrainState, make_sharded_stepper, reject_norm_based,
                     state_specs_like)
 
@@ -49,15 +49,18 @@ def make_moe_train_step(model: MoETransformerLM,
 
     tokens/targets: (global_batch, T) int32 sharded over (dp, ep).
 
-    grad_rounding='stochastic': unbiased SR through the dp all-reduce.
-    The key depends only on (grad_seed, step) — identical across ep,
+    use_aps ... grad_seed are the seven of `train.grads.ReduceOptions`'
+    fields this builder takes (described there; the rest keep their
+    defaults).  Its own about grad_rounding='stochastic': the stage's key
+    depends only on (grad_seed, step), so it is identical across ep,
     which is required for replicated leaves (their post-ep-psum grads
     are identical on every ep copy and must round identically) and
     harmless for expert stacks (ep ranks own disjoint experts, nothing
-    sums across ep); `sum_gradients` folds the dp rank into its
-    pre-quantize key for the dp-sum decorrelation."""
-    if grad_rounding not in ("nearest", "stochastic"):
-        raise ValueError(f"unknown grad_rounding {grad_rounding!r}")
+    sums across ep)."""
+    opts = ReduceOptions(use_aps=use_aps, grad_exp=grad_exp,
+                         grad_man=grad_man, use_kahan=use_kahan, mode=mode,
+                         grad_rounding=grad_rounding,
+                         grad_seed=grad_seed).check()
     reject_norm_based(tx, "ep-sharded step")
     data_axes = (axis_dp, axis_ep)
 
@@ -91,12 +94,8 @@ def make_moe_train_step(model: MoETransformerLM,
             lambda g, s: g if axis_ep in tuple(
                 a for a in s if a is not None) else lax.psum(g, axis_ep),
             grads, specs, is_leaf=lambda x: isinstance(x, P))
-        gkey = (grad_sr_key(grad_seed, state.step, 1)
-                if grad_rounding == "stochastic" else None)
-        grads = sum_gradients(grads, axis_dp, use_aps=use_aps,
-                              grad_exp=grad_exp, grad_man=grad_man,
-                              use_kahan=use_kahan, mode=mode,
-                              rounding=grad_rounding, key=gkey)
+        grads, _ = reduce_local(grads, step=state.step, axis_dp=axis_dp,
+                                opts=opts)
 
         updates, new_opt = tx.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)

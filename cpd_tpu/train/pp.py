@@ -34,7 +34,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..models.pipeline_lm import (PipelinedLM, pp_param_specs,
                                   vocab_parallel_ce)
 from ..compat import shard_map
-from ..parallel.dist import grad_sr_key, sum_gradients
+from .grads import ReduceOptions, reduce_local
 from .state import (TrainState, make_sharded_stepper, reject_norm_based,
                     state_specs_like)
 
@@ -63,16 +63,19 @@ def make_pp_train_step(model: PipelinedLM, tx: optax.GradientTransformation,
     pipeline microbatches.  Keep n_microbatches >= pp for a small bubble
     (fraction (pp-1)/(n_microbatches+pp-1)).
 
-    grad_rounding='stochastic': unbiased SR through the dp all-reduce
-    (same contract as train/step.py).  The key depends only on
-    (grad_seed, step) — identical across pp/tp ranks, which is required
-    (replicated leaves like the embedding must reduce to identical bits
-    on every pp copy) and harmless for stage-sharded leaves (pp ranks
-    hold different parameters, nothing sums across pp);
-    `sum_gradients` itself folds the dp rank into its pre-quantize key.
+    use_aps ... grad_seed are the seven of `train.grads.ReduceOptions`'
+    fields this builder takes (described there; the rest keep their
+    defaults).  Its own about grad_rounding='stochastic': the stage's key
+    depends only on (grad_seed, step), so it is identical across pp/tp
+    ranks, which is required (replicated leaves like the embedding must
+    reduce to identical bits on every pp copy) and harmless for
+    stage-sharded leaves (pp ranks hold different parameters, nothing
+    sums across pp).
     """
-    if grad_rounding not in ("nearest", "stochastic"):
-        raise ValueError(f"unknown grad_rounding {grad_rounding!r}")
+    opts = ReduceOptions(use_aps=use_aps, grad_exp=grad_exp,
+                         grad_man=grad_man, use_kahan=use_kahan, mode=mode,
+                         grad_rounding=grad_rounding,
+                         grad_seed=grad_seed).check()
     reject_norm_based(tx, "pp-sharded step")
     pp_size = mesh.shape.get(axis_pp, 1)
     all_axes = (axis_dp, axis_pp, axis_tp)  # size-1 axes psum as no-ops
@@ -132,12 +135,8 @@ def make_pp_train_step(model: PipelinedLM, tx: optax.GradientTransformation,
 
         grads = jax.tree.map(reduce_leaf, grads, specs,
                              is_leaf=lambda x: isinstance(x, P))
-        gkey = (grad_sr_key(grad_seed, state.step, 1)
-                if grad_rounding == "stochastic" else None)
-        grads = sum_gradients(grads, axis_dp, use_aps=use_aps,
-                              grad_exp=grad_exp, grad_man=grad_man,
-                              use_kahan=use_kahan, mode=mode,
-                              rounding=grad_rounding, key=gkey)
+        grads, _ = reduce_local(grads, step=state.step, axis_dp=axis_dp,
+                                opts=opts)
 
         updates, new_opt = tx.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)

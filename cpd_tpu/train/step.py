@@ -42,8 +42,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..compat import shard_map
 from ..obs import scopes
-from ..parallel.dist import grad_sr_key, sum_gradients
-from ..parallel.emulate import emulate_node_reduce
+from .grads import ReduceOptions, reduced, report_metrics
 from .state import TrainState
 
 __all__ = ["cross_entropy_loss", "seg_cross_entropy_loss",
@@ -136,81 +135,33 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     flat-shard all_gather + unflatten of parallel/zero.py `_Zero3`);
     update_fn then returns params back in the STORED layout.
 
-    verify_reduce=True runs the self-verifying reduction
-    (`sum_gradients(..., verify=True)`, parallel/integrity.py) and adds
-    the replicated scalars ``reduce_ok`` / ``reduce_hop_bad`` /
-    ``reduce_gather_bad`` / ``reduce_agree`` to the metrics — the feed
-    for `resilience.transport.TransportSupervisor`.  wire_fault_plan is
-    a ``FaultPlan.wire_schedule(n_steps)`` (codes, ranks) table baked
-    into the program; entry ``state.step`` corrupts the ring wire on
-    that rank (ignored outside mode="ring" — the ring's wire IS the one
-    under attack, and downgrading transports is the escape).
+    use_aps ... block_size, the fifteen keywords of the reduction, are
+    `train.grads.ReduceOptions`' fields and are described there, once.
+    What is this builder's own about them:
 
-    quant_stats=True threads the reduce-wire numeric-health telemetry
-    (`sum_gradients(..., stats=True)`) into the metrics as the
-    replicated scalars ``prec_wire_sat`` / ``prec_wire_underflow`` /
-    ``prec_wire_nan`` / ``prec_wire_total`` / ``prec_aps_bad`` — the
-    feed for `resilience.precision.PrecisionSupervisor`'s escalation
-    ladder.  The gradient path stays bitwise unchanged.  sat_fault_plan
-    is a ``FaultPlan.sat_schedule(n_steps)`` int32 exponent table baked
-    into the program: entry ``state.step`` scales this step's LOCAL
-    post-backward gradients by 2^k before the emulate-node reduce and
-    the quantized collective, deterministically driving the wire cast
-    into saturation (the attack the ladder is exercised against; 0 =
-    off, and scaling by 2^0 == 1.0 is an exact fp32 no-op).
-
-    overlap_reduce=True replaces the post-backward reduction monolith
-    with the bucketed, dependency-scheduled transport
-    (parallel/overlap.py): per-bucket custom_vjp taps on the parameters
-    run each bucket's quantized all-reduce INSIDE the backward pass, the
-    moment that bucket's last gradient closes — late-layer buckets ring
-    while early-layer backward compute is still pending, which is the
-    dependency structure XLA needs to overlap collectives with compute
-    (MLPerf TPU-pod bucketed gradient summation, PAPERS.md #4).  The
-    reduced gradients — and therefore the updated parameters — are
-    BITWISE identical to the non-overlapped step (tests/test_overlap.py);
-    verify/stats reports ride out of the backward on the tap-cotangent
-    channel, and sat_pressure / wire faults keep firing (wire faults hit
-    bucket 0 only, preserving exact drill counters).  bucket_elems caps
-    the bucket size for BOTH the overlapped taps and the post-backward
-    bucketed/ring layouts (default: parallel/dist._BUCKET_ELEMS).
-
-    overlap_reduce composes with emulate_node > 1 (ISSUE 12): the first
-    N-1 micro-batches run as an unrolled value_and_grad chain (same
-    sequential BN-stat order as the scan) and their stacked gradients
-    ride into the LAST micro-batch's taps, where each bucket's
-    rank-local emulate-node reduce + cross-device collective fire as
-    that bucket's final cotangent closes.  Gradients and therefore
-    PARAMS are bitwise identical to the scan + post-backward monolith
-    (tests/test_overlap.py); BN running stats agree to the last ulp
-    only — XLA fuses the scanned vs unrolled forward differently, and a
-    batch-mean reduction can differ in its final bit (training-mode BN
-    normalizes by the batch stats, so gradients never see the drift).
-
-    overlap_reduce also composes with reduce_in_update when the updater
+    overlap_reduce keeps the sequential batch-statistics order of the
+    scan; the statistics agree with the monolith's to the last ulp only
+    (training-mode BN normalizes by the batch's own, so gradients never
+    see the drift).  It composes with reduce_in_update where the updater
     provides the ``tap_reduce`` hook (ZeRO-2's
     `zero2_sgd(...).mesh_layout` wires it): the taps run the updater's
     per-bucket all_to_all reduce-scatter inside the backward and
     `update_fn` consumes the extracted bucket shards
-    (``pre_sharded=True``) — bitwise identical to the post-backward
+    (``pre_sharded=True``), bitwise identical to the post-backward
     reduce_in_update monolith at a fixed bucket layout.
 
-    block_scale / block_size thread the EQuARX-style block-scaled ring
-    wire (`sum_gradients(block_scale=...)`, quant/numerics.py
-    "Block-scaled eXmY codec"): every hop cast shares one power-of-2
-    scale per `block_size` consecutive elements and the 1-byte-per-block
-    shift sidecar rides the packed wire.  Ring mode only (validated at
-    build time — the other transports have no sidecar lane), EXCEPT
-    with reduce_in_update, where the pair is forwarded to the updater
-    and ZeRO-2's faithful all_to_all carries the blocked wire instead
-    (parallel/zero.py, ISSUE 12 leg 1).  A DIFFERENT documented
-    accumulation numerics than per-tensor: steps with and without it
-    are distinct StepTable entries (`ladder_step_key(block=...)`).
-    Composes with overlap_reduce — overlap on/off stays bitwise
-    identical with block scaling on.
+    block_scale needs mode="ring", EXCEPT with reduce_in_update, where
+    the pair is forwarded to the updater and ZeRO-2's faithful
+    all_to_all carries the blocked wire instead (ISSUE 12 leg 1).
     """
-    if grad_rounding not in ("nearest", "stochastic"):
-        raise ValueError(f"unknown grad_rounding {grad_rounding!r}")
+    opts = ReduceOptions(
+        use_aps=use_aps, grad_exp=grad_exp, grad_man=grad_man,
+        use_kahan=use_kahan, mode=mode, grad_rounding=grad_rounding,
+        grad_seed=grad_seed, verify_reduce=verify_reduce,
+        wire_fault_plan=wire_fault_plan, quant_stats=quant_stats,
+        sat_fault_plan=sat_fault_plan, overlap_reduce=overlap_reduce,
+        bucket_elems=bucket_elems, block_scale=block_scale,
+        block_size=block_size).check(reduce=not reduce_in_update)
     dynamic_scale = loss_scale == "dynamic"
     if dynamic_scale and update_fn is not None:
         raise ValueError("loss_scale='dynamic' requires the default optax "
@@ -249,35 +200,6 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             "it); ZeRO-3 and other custom updaters without one own the "
             "whole post-backward collective — run without "
             "overlap_reduce")
-    if block_scale and mode != "ring" and not reduce_in_update:
-        raise ValueError(
-            f"block_scale=True needs mode='ring' (got {mode!r}): the "
-            f"per-block scale sidecar rides the ring's packed wire "
-            f"(with reduce_in_update the ZeRO-2 updater's all_to_all "
-            f"carries it instead — parallel/zero.py)")
-    has_stats_cache: dict = {}
-
-    def make_loss_of(world, scale):
-        """The per-micro-batch loss closure — ONE definition feeding both
-        the scan path and the overlapped-taps path, so their numerics
-        cannot drift."""
-
-        def loss_of(p, stats, x, y, rngs):
-            variables = {"params": p}
-            kwargs = {"rngs": rngs} if rngs else {}
-            has_stats = bool(jax.tree.leaves(stats))
-            if has_stats:
-                variables["batch_stats"] = stats
-                logits, mut = model.apply(variables, x, train=True,
-                                          mutable=["batch_stats"], **kwargs)
-                new_stats = mut["batch_stats"]
-            else:
-                logits = model.apply(variables, x, train=True, **kwargs)
-                new_stats = stats
-            loss = loss_fn(logits, y) / (world * emulate_node)  # mix.py:239
-            return loss * scale, (logits, new_stats, loss)
-
-        return loss_of
 
     def micro_rngs(step, micro_idx):
         """Per-micro-step stream rngs (dropout etc.), deterministic in
@@ -292,39 +214,6 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             base, lax.axis_index(axis_name).astype(jnp.int32))
         return {k: jax.random.fold_in(base, i)
                 for i, k in enumerate(rng_keys)}
-
-    def local_micro_grads(params, batch_stats, images, labels, world, step,
-                          scale):
-        """Sequential scan over micro-batches -> stacked grads (N, ...)."""
-        n = emulate_node
-        if images.shape[0] < n or images.shape[0] % n:
-            # a 0-sample micro-batch silently yields NaN losses (mean over
-            # an empty batch); fail at trace time with the actual geometry
-            raise ValueError(
-                f"per-device batch {images.shape[0]} must be a positive "
-                f"multiple of emulate_node={n} (global batch = "
-                f"devices * per-device batch; each device slice is split "
-                f"into emulate_node sequential micro-batches)")
-        mb = images.shape[0] // n
-        images = images.reshape(n, mb, *images.shape[1:])
-        labels = labels.reshape(n, mb, *labels.shape[1:])
-        loss_of = make_loss_of(world, scale)
-
-        def micro(carry, xy):
-            stats, micro_idx = carry
-            x, y = xy
-            rngs = micro_rngs(step, micro_idx)
-            (_, (logits, new_stats, loss)), grads = jax.value_and_grad(
-                loss_of, has_aux=True)(params, stats, x, y, rngs)
-            correct, counted = _count_hits(logits, y)
-            return (new_stats, micro_idx + 1), (grads, loss, correct, counted)
-
-        with jax.named_scope(scopes.LOSS_GRAD):
-            (final_stats, _), (stacked_grads, losses, corrects, counts) = \
-                lax.scan(micro, (batch_stats, jnp.zeros([], jnp.int32)),
-                         (images, labels))
-        return (stacked_grads, final_stats, losses.sum(), corrects.sum(),
-                counts.sum())
 
     def _count_hits(logits, y):
         hit = jnp.argmax(_main_logits(logits), -1) == y
@@ -356,181 +245,39 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                     "loss_scale is static; pass loss_scale='dynamic' to "
                     "make_train_step")
             scale = jnp.float32(loss_scale)
-        sr = grad_rounding == "stochastic"
-        sum_key = grad_sr_key(grad_seed, state.step, 1) if sr else None
-        # wire-fault table lookup, keyed by the optimizer-update index —
-        # the same clock as with_fault_injection's grad schedule
-        wf = None
-        if wire_fault_plan is not None and mode == "ring":
-            codes = jnp.asarray(wire_fault_plan[0], jnp.int32)
-            ranks = jnp.asarray(wire_fault_plan[1], jnp.int32)
-            idx = jnp.clip(state.step, 0, codes.shape[0] - 1)
-            in_range = state.step < codes.shape[0]
-            wf = (jnp.where(in_range, codes[idx], 0), ranks[idx])
-        sfac = None
-        if sat_fault_plan is not None:
-            # saturation-pressure attack (resilience/inject.py
-            # `sat_pressure`): scale this step's local grads by 2^k.  An
-            # exact power of two, rank-agnostic (every replica scales
-            # identically, so replication is preserved)
-            from ..resilience.inject import sat_pressure_factor
-            sfac = sat_pressure_factor(sat_fault_plan, state.step)
-        vreport = None
-        pre_sharded_vec = None
-        if overlap_reduce:
-            # Bucketed, dependency-scheduled transport: the reduction
-            # runs INSIDE the backward via per-bucket custom_vjp taps
-            # (parallel/overlap.py) — bitwise identical to the
-            # post-backward path below, but each bucket's collective is
-            # emitted the moment its last cotangent closes, so XLA may
-            # overlap ring hops with the remaining backward compute.
-            #
-            # emulate_node > 1 (ISSUE 12 leg 3): micro-batches 0..N-2
-            # run as a plain unrolled value_and_grad chain (same
-            # sequential BN-stat order as the monolith's scan); their
-            # stacked gradients ride into the LAST micro-batch's taps as
-            # extras, where each bucket's rank-local emulate-node reduce
-            # + cross-device collective fire the moment that bucket's
-            # final cotangent closes — the collectives overlap the last
-            # backward instead of waiting behind the whole scan.
-            #
-            # reduce_in_update + tap_reduce (ZeRO-2): the taps run the
-            # updater's per-bucket reduce-scatter (`make_tap_reduce`)
-            # and the update consumes the extracted bucket shards.
-            from ..parallel.overlap import (BucketPlan,
-                                            extract_bucket_shards,
-                                            overlapped_grads)
-            n = emulate_node
-            if images.shape[0] < n or images.shape[0] % n:
-                raise ValueError(
-                    f"per-device batch {images.shape[0]} must be a "
-                    f"positive multiple of emulate_node={n}")
-            if tap_reduce is not None:
-                plan, tap_chunks, tap_collective = tap_reduce(
-                    model_params,  axis_name,
-                    dict(use_aps=use_aps, grad_exp=grad_exp,
-                         grad_man=grad_man, use_kahan=use_kahan,
-                         mode=mode, rounding=grad_rounding,
-                         block_scale=block_scale, block_size=block_size))
-                if (bucket_elems is not None
-                        and plan.bucket_elems != bucket_elems):
-                    # the tap plan comes SOLELY from the updater's
-                    # layout (the update must consume the same shards
-                    # the taps produce) — a step-side cap that differs
-                    # would be a silently ignored tuning knob, the
-                    # exact hazard the old CLI fail-fast rejected
-                    raise ValueError(
-                        f"bucket_elems={bucket_elems} does not match "
-                        f"the ZeRO updater's bucket layout (cap "
-                        f"{plan.bucket_elems}): with reduce_in_update "
-                        f"the tap plan comes from the updater — pass "
-                        f"the same value to zero2_sgd(bucket_elems=)")
-            else:
-                plan = BucketPlan.for_tree(model_params, bucket_elems)
-                tap_chunks = tap_collective = None
-            loss_of = make_loss_of(world, scale)
-            stats_c = state.batch_stats
-            extras = emulate_fn = emu_key = None
-            micro_losses, micro_correct, micro_counted = [], [], []
-            if n > 1:
-                mb = images.shape[0] // n
-                imgs = images.reshape(n, mb, *images.shape[1:])
-                lbls = labels.reshape(n, mb, *labels.shape[1:])
-                prev = []
-                for mi in range(n - 1):
-                    rngs_mi = micro_rngs(state.step, jnp.int32(mi))
-                    with jax.named_scope(scopes.LOSS_GRAD):
-                        (_, (lg, stats_c, l_mi)), g_mi = jax.value_and_grad(
-                            loss_of, has_aux=True)(model_params, stats_c,
-                                                   imgs[mi], lbls[mi],
-                                                   rngs_mi)
-                    c_mi, n_mi = _count_hits(lg, lbls[mi])
-                    micro_losses.append(l_mi)
-                    micro_correct.append(c_mi)
-                    micro_counted.append(n_mi)
-                    prev.append(jax.tree_util.tree_leaves(g_mi))
-                extras = [jnp.stack([prev[mi][i] for mi in range(n - 1)])
-                          for i in range(len(plan.sizes))]
-                if sfac is not None:
-                    # the monolith scales the whole stacked-grad tensor;
-                    # the taps scale the last micro's cotangent (aux[0])
-                    # — scale the prior micros here so every micro sees
-                    # the same 2^k pressure
-                    extras = [e * sfac for e in extras]
-                if sr:
-                    emu_key = jax.random.fold_in(
-                        grad_sr_key(grad_seed, state.step, 0),
-                        lax.axis_index(axis_name).astype(jnp.int32))
-                from ..parallel.emulate import make_overlap_emulate_fn
-                emulate_fn = make_overlap_emulate_fn(
-                    n, use_aps, grad_exp, grad_man, sr)
-                x_last, y_last = imgs[n - 1], lbls[n - 1]
-                rngs = micro_rngs(state.step, jnp.int32(n - 1))
-            else:
-                x_last, y_last = images, labels
-                rngs = micro_rngs(state.step, jnp.zeros([], jnp.int32))
-            base_stats = stats_c
 
-            def loss_closure(p):
-                return loss_of(p, base_stats, x_last, y_last, rngs)
-
-            ((_, (logits, new_stats, loss_last)), reduced,
-             vreport) = overlapped_grads(
-                loss_closure, model_params, axis_name=axis_name,
-                plan=plan,
-                reduce_kw=dict(use_aps=use_aps, grad_exp=grad_exp,
-                               grad_man=grad_man, use_kahan=use_kahan,
-                               mode=mode, rounding=grad_rounding,
-                               bucket_elems=bucket_elems,
-                               block_scale=block_scale,
-                               block_size=block_size),
-                key=sum_key, sat_factor=sfac, wire_fault=wf,
-                verify=verify_reduce, stats=quant_stats,
-                collective=tap_collective, extras=extras,
-                emulate_reduce=emulate_fn, emulate_key=emu_key)
-            c_last, n_last = _count_hits(logits, y_last)
-            # same associativity as the monolith's stacked-sum metrics
-            loss = jnp.stack(micro_losses + [loss_last]).sum()
-            correct = jnp.stack(micro_correct + [c_last]).sum()
-            counted = jnp.stack(micro_counted + [n_last]).sum()
-            if tap_collective is not None:
-                pre_sharded_vec = extract_bucket_shards(reduced, plan,
-                                                        tap_chunks)
-        else:
-            stacked, new_stats, loss, correct, counted = local_micro_grads(
-                model_params, state.batch_stats, images, labels, world,
-                state.step, scale)
-            if sfac is not None:
-                stacked = jax.tree.map(lambda g: g * sfac, stacked)
-
-            # Local emulated-node reduction (mix.py:251-282), then the
-            # cross-device low-precision all-reduce (mix.py:286-291).
-            # grad_rounding='stochastic': fresh unbiased SR bits per step
-            # via the shared derivation (parallel/dist.py grad_sr_key —
-            # rank-free by contract, so replicated reduction outputs stay
-            # consistent).  The emulate-node reduce is rank-LOCAL, so its
-            # key also folds in the rank index (same decorrelation the
-            # dropout rngs get; sum_gradients folds the rank into its own
-            # pre-quantize key).
-            local = emulate_node_reduce(
-                stacked, emulate_node, use_aps, grad_exp, grad_man,
-                rounding=grad_rounding,
-                key=jax.random.fold_in(
-                    grad_sr_key(grad_seed, state.step, 0),
-                    lax.axis_index(axis_name).astype(jnp.int32)) if sr
-                else None)
-            if reduce_in_update:
-                reduced = local       # update_fn owns the collective
+        def loss_of(p, stats, xy, micro_idx):
+            """One micro-batch's loss: the carry is the batch statistics,
+            the aux its unscaled loss and hit counts."""
+            x, y = xy
+            rngs = micro_rngs(state.step, micro_idx)
+            variables = {"params": p}
+            kwargs = {"rngs": rngs} if rngs else {}
+            if jax.tree.leaves(stats):
+                variables["batch_stats"] = stats
+                logits, mut = model.apply(variables, x, train=True,
+                                          mutable=["batch_stats"], **kwargs)
+                stats = mut["batch_stats"]
             else:
-                reduced = sum_gradients(
-                    local, axis_name, use_aps=use_aps,
-                    grad_exp=grad_exp, grad_man=grad_man,
-                    use_kahan=use_kahan, mode=mode, rounding=grad_rounding,
-                    key=sum_key, verify=verify_reduce, wire_fault=wf,
-                    stats=quant_stats, bucket_elems=bucket_elems,
-                    block_scale=block_scale, block_size=block_size)
-                if verify_reduce or quant_stats:
-                    reduced, vreport = reduced
+                logits = model.apply(variables, x, train=True, **kwargs)
+            loss = loss_fn(logits, y) / (world * emulate_node)  # mix.py:239
+            return loss * scale, (stats, (loss, *_count_hits(logits, y)))
+
+        n = emulate_node
+        if images.shape[0] < n or images.shape[0] % n:
+            # a 0-sample micro-batch silently yields NaN losses (mean over
+            # an empty batch); fail at trace time with the actual geometry
+            raise ValueError(
+                f"per-device batch {images.shape[0]} must be a positive "
+                f"multiple of emulate_node={n} (global batch = "
+                f"devices * per-device batch; each device slice is split "
+                f"into emulate_node sequential micro-batches)")
+        out = reduced(
+            loss_of, model_params, (images, labels), n=n,
+            carry=state.batch_stats, step=state.step, axis_dp=axis_name,
+            opts=opts, tap_reduce=tap_reduce, reduce=not reduce_in_update)
+        new_stats = out.carry
+        loss, correct, counted = (a.sum() for a in out.aux)
 
         with jax.named_scope(scopes.OPTIMIZER):
             if update_fn is not None:
@@ -539,29 +286,15 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                 # (full replicated by default; the rank's shard when
                 # params_spec/unpack_params are in play) and the (possibly
                 # sharded) new opt state.
-                # With reduce_in_update the step's precision settings ride
-                # along so the updater's collective cannot drift from the
-                # emulate-node quantization above.  The SR key is the SAME
-                # fold the replicated path hands sum_gradients, so a ZeRO
-                # reduce-scatter draws exactly the bits the replicated
-                # faithful reduction would (parallel/zero.py).
-                if pre_sharded_vec is not None:
-                    # ZeRO-2 overlap: the taps already ran the per-bucket
-                    # reduce-scatter — the update just consumes the shards
-                    new_params, new_opt = update_fn(pre_sharded_vec, state,
-                                                    axis_name,
-                                                    pre_sharded=True)
-                else:
-                    quant_kw = dict(use_aps=use_aps, grad_exp=grad_exp,
-                                    grad_man=grad_man, use_kahan=use_kahan,
-                                    mode=mode, rounding=grad_rounding,
-                                    key=sum_key, block_scale=block_scale,
-                                    block_size=block_size) \
-                        if reduce_in_update else {}
-                    new_params, new_opt = update_fn(reduced, state, axis_name,
-                                                    **quant_kw)
+                # With reduce_in_update the stage's `update_kw` carries the
+                # step's precision settings and the collective's SR key, so
+                # the updater's collective cannot drift from the
+                # emulate-node quantization (parallel/zero.py); after
+                # ZeRO-2's taps it says the shards are reduced already.
+                new_params, new_opt = update_fn(out.grads, state, axis_name,
+                                                **out.update_kw)
             else:
-                updates, new_opt = tx.update(reduced, state.opt_state,
+                updates, new_opt = tx.update(out.grads, state.opt_state,
                                              state.params)
                 new_params = optax.apply_updates(state.params, updates)
         with jax.named_scope(scopes.METRICS):
@@ -593,25 +326,7 @@ def make_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                                          axis_name),
                                 1.0),
             }
-        if vreport is not None:
-            # replicated scalars: the wire-integrity verdict / numeric-
-            # health telemetry of THIS step's reduce, consumed by the
-            # transport / precision supervisors in the loop
-            f32 = jnp.float32
-            if verify_reduce:
-                metrics.update(
-                    reduce_ok=vreport["ok"].astype(f32),
-                    reduce_hop_bad=vreport["hop_bad"].astype(f32),
-                    reduce_gather_bad=vreport["gather_bad"].astype(f32),
-                    reduce_agree=vreport["agree"].astype(f32))
-            if quant_stats:
-                metrics.update(
-                    prec_wire_sat=vreport["wire_sat"].astype(f32),
-                    prec_wire_underflow=vreport["wire_underflow"]
-                    .astype(f32),
-                    prec_wire_nan=vreport["wire_nan"].astype(f32),
-                    prec_wire_total=vreport["wire_total"].astype(f32),
-                    prec_aps_bad=vreport["aps_bad"].astype(f32))
+        metrics.update(report_metrics(out.report, opts))
         return new_state, metrics
 
     if opt_state_spec is None and params_spec is None:
@@ -767,7 +482,8 @@ def ir_programs(reg):
     from .state import create_train_state
 
     W, BUCKET = 8, 100
-    deps = ("cpd_tpu.train.step", "cpd_tpu.parallel.dist",
+    deps = ("cpd_tpu.train.step", "cpd_tpu.train.grads",
+            "cpd_tpu.parallel.dist",
             "cpd_tpu.parallel.ring", "cpd_tpu.parallel.overlap",
             "cpd_tpu.parallel.aps", "cpd_tpu.parallel.emulate",
             "cpd_tpu.parallel.zero", "cpd_tpu.quant.numerics",

@@ -394,11 +394,9 @@ def _tiny_setup():
     return mesh, model, tx, state, x, y
 
 
-def test_train_step_overlap_bitwise_and_interleaved():
-    """The whole jitted step: overlapped params == monolith params
-    bitwise (ring + SR, the maximal pipeline), metrics equal, and the
-    overlap structurally happened — transport collectives interleave
-    with backward compute in the tapped program only."""
+def _overlap_twins():
+    """The whole jitted step, monolith and overlapped, at ring + SR (the
+    maximal pipeline)."""
     from cpd_tpu.train import make_train_step
     mesh, model, tx, state, x, y = _tiny_setup()
     kw = dict(use_aps=True, grad_exp=5, grad_man=2, mode="ring",
@@ -406,12 +404,24 @@ def test_train_step_overlap_bitwise_and_interleaved():
               donate=False)
     mono = make_train_step(model, tx, mesh, **kw)
     over = make_train_step(model, tx, mesh, overlap_reduce=True, **kw)
+    return mono, over, state, x, y
+
+
+def test_train_step_overlap_bitwise():
+    """Overlapped params == monolith params bitwise, metrics equal."""
+    mono, over, state, x, y = _overlap_twins()
     sa, ma = mono(state, x, y)
     sb, mb = over(state, x, y)
     for pa, pb in zip(jax.tree.leaves(sa.params),
                       jax.tree.leaves(sb.params)):
         _bitwise(pa, pb)
     assert float(ma["loss"]) == float(mb["loss"])
+
+
+def test_train_step_overlap_interleaved():
+    """The overlap structurally happened — transport collectives
+    interleave with backward compute in the tapped program only."""
+    mono, over, state, x, y = _overlap_twins()
     ev_o = overlap_evidence(over, state, x, y)
     ev_m = overlap_evidence(mono, state, x, y)
     assert ev_o["interleaved"] and ev_o[
