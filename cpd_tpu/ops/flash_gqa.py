@@ -21,13 +21,22 @@ Design notes:
     the final K step.
   * the q block is (rep, bq, D): logits are ONE (rep·bq, D)x(D, bk) MXU
     contraction via dot_general — no per-head loop, no reshape.
+  * a step takes up to 1,024 keys (`_fwd_blocks`, from the group's size
+    and the sequence lengths, as the backward's `_bwd_blocks`): the
+    accumulator's and the running maximum's and sum's read-modify-write,
+    `alpha`'s exponential and the two cross-lane reductions a row are
+    paid once a step, so once per 8 vregs of a row's scores at 1,024
+    keys where a 128-key step paid them per vreg (v5e, one call with its
+    layout passes: Moonlight's 27.1 -> 9.5 ms, StarCoder2's 4.08 -> 2.56;
+    the sweep is in the comment on `_FWD_SCORES`).
   * masking zeroes p directly (p = where(valid, exp(s - m), 0)), so pad
     keys and fully-masked rows contribute 0 to l — a fully-masked row
     yields o = 0 rather than a pad-key average (the degenerate-row edge
     the ADVICE round-4 note flags for `_chunked_attention`).
   * causal K blocks strictly above the diagonal skip their compute via
-    `pl.when` (their DMA still runs — Pallas fetches per the BlockSpec —
-    but the MXU work, the dominant cost, is elided).
+    `pl.when`, and their copies too: such a step names the block its
+    neighbour on the diagonal holds (`_dq_k_block`: the forward's grid
+    is the dq kernel's), which Pallas does not fetch again.
   * fp32 logits/softmax; p is cast to the V dtype for the PV matmul —
     the same precision recipe as `_fold_segment` (attention.py).
 
@@ -35,8 +44,9 @@ Backward: `jax.custom_vjp`, the flash-backward recipe on the MXU.  The
 forward also emits the per-row LSE, and two kernels — dq (K innermost)
 and fused dk/dv (Q innermost, the GQA group-sums folded into (rep, bq)
 contractions) — re-exponentiate p = exp(s − lse) per block, at v's own
-width and with block lengths of their own (`_bwd_blocks`); causal steps
-above the diagonal neither compute nor copy.  What it costs on the
+width and with block lengths of their own (`_bwd_blocks`: the forward's
+rule under a cap half as large); causal steps above the diagonal
+neither compute nor copy.  What it costs on the
 chip beside the XLA gradient of `_chunked_attention` (which
 `attn_impl="chunked"` still runs, and the tests hold this one to):
 PERF.md section 6, PR 31.
@@ -58,13 +68,12 @@ from .backend import interpret_mode
 __all__ = ["flash_gqa"]
 
 _BQ = 128   # query rows per program and head of the group (pre-rep);
-            # MXU/sublane aligned; `_dims` lengthens it for rep < 8
-_BK = 128   # K/V block; == the lane width so (.., bk) masks are one tile
+            # MXU/sublane aligned; `_step_blocks` lengthens it for rep < 8
 
 
 def _flash_gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
                       m_ref, l_ref, *,
-                      causal: bool, scale: float, tq: int, tk: int,
+                      causal: bool, scale: float, tk: int,
                       bq: int, bk: int, n_k: int):
     i = pl.program_id(2)          # q block index
     j = pl.program_id(3)          # k block index
@@ -83,63 +92,49 @@ def _flash_gqa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     def _():
         q = q_ref[0, 0]           # (rep, bq, D)
         k = k_ref[0, 0]           # (bk, D)
-        v = v_ref[0, 0]           # (bk, D)
+        v = v_ref[0, 0]           # (bk, Dv)
         s = lax.dot_general(
             q, k, (((2,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (rep, bq, bk)
 
-        qpos = i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        kpos = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        valid = kpos < tk                                  # pad keys out
-        if causal:
-            valid = valid & (qpos >= kpos)
-        valid = valid[None]                                # (1, bq, bk)
+        valid = None              # no pad key and no diagonal: no mask
+        if causal or n_k * bk != tk:
+            qpos = i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            kpos = j * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            valid = kpos < tk                              # pad keys out
+            if causal:
+                valid = valid & (qpos >= kpos)
+            valid = valid[None]                            # (1, bq, bk)
+            s = jnp.where(valid, s, _NEG_INF)
 
-        m_prev = m_ref[...]                                # (rep, bq, 128)
-        l_prev = l_ref[...]
-        s = jnp.where(valid, s, _NEG_INF)
+        m_prev = m_ref[...]                                # (rep, bq, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        # p is zeroed by the mask, not by exp(-inf): when every key so far
-        # is masked m_new is still _NEG_INF and exp(s - m_new) would be 1
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)      # (rep, bq, bk)
-        alpha = jnp.exp(m_prev - m_new)                    # (rep, bq, 128)
-        l_ref[...] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+        p = jnp.exp(s - m_new)                             # (rep, bq, bk)
+        if valid is not None:
+            # p is zeroed by the mask, not by exp(-inf): when every key
+            # so far is masked m_new is still _NEG_INF and exp(s - m_new)
+            # would be 1
+            p = jnp.where(valid, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)                    # (rep, bq, 1)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
         pv = lax.dot_general(
             p.astype(v.dtype), v, (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (rep, bq, D)
-        acc_ref[...] = acc_ref[...] * alpha[..., :1] + pv
+            preferred_element_type=jnp.float32)            # (rep, bq, Dv)
+        acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
     @pl.when(j == n_k - 1)
     def _():
-        l = l_ref[..., :1]                                 # (rep, bq, 1)
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)                 # (rep, bq, 1)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
         # log-sum-exp per row, consumed by the Pallas backward (a
         # fully-masked row keeps lse ~ -1e30; its p re-exponentiates
         # to 0 there via the same validity mask)
-        lse_ref[0, 0] = (m_ref[..., :1]
-                         + jnp.log(jnp.maximum(l, 1e-30)))[..., 0]
+        lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[..., 0]
 
 
 def _pad128(d: int) -> int:
     return max(128, -(-d // 128) * 128)
-
-
-def _dims(q, k):
-    b, tq, h, d = q.shape
-    tk, hkv = k.shape[1], k.shape[2]
-    rep = h // hkv
-    # a program works on rep x bq rows: a group of few query heads takes a
-    # longer block, so that the grid's per-step cost is spread over about
-    # as many rows as a wide group's (v5e, 2 x 8,192 tokens, 16 heads of
-    # 192/128 at rep 1: 61.7 ms a call at 128 rows, 27.2 ms at 1,024;
-    # PERF.md section 6, PR 30).  rep >= 8 keeps _BQ
-    bq = min(_BQ * max(1, 8 // rep), max(8, -(-tq // 8) * 8))
-    bk = _BK
-    tq_p = -(-tq // bq) * bq
-    tk_p = -(-tk // bk) * bk
-    return b, tq, h, d, tk, hkv, rep, bq, bk, tq_p, tk_p, _pad128(d)
 
 
 def _q_layout(x, hkv, rep, tq_p, d_p):
@@ -157,13 +152,118 @@ def _kv_layout(x, tk_p, d_p):
                     (0, d_p - x.shape[-1])))
 
 
+# Block lengths, timed on a v5e for one layer's call in bf16, causal
+# (`tools/bench_flash_gqa.py`: the kernels with the layout passes around
+# them).
+#
+# The forward (PERF.md section 6, PR 33).  Moonlight's call (2 x 8,192
+# tokens, 16 heads of 192/128 at rep 1; 27.1 ms at PR 32's fixed 128 keys
+# a step): (bq, bk) = (1,024, 1,024) 9.51 ms, (2,048, 1,024) 10.24, (512,
+# 1,024) 10.25, (1,024, 2,048) 10.49, (512, 512) 12.30, (1,024, 512) 13.07,
+# (1,024, 256) 22.17, (1,024, 128) 29.05.  StarCoder2's (2 x 4,096, 24
+# heads of 128 at rep 12; 4.08 before): (256, 1,024) 2.53, (128, 1,024)
+# 2.56, (128, 2,048) 2.99, (256, 512) 3.06, (128, 512) 3.50, (256, 256)
+# 4.77, (128, 256) 5.10, (128, 128) 8.31.  What a step pays once (module
+# docstring) makes long steps pay up to 1,024 keys; past that the work a
+# crossed block does above the diagonal costs more than the step saves.
+# The forward ranks a group of twelve otherwise than the backward pair
+# does ((128, 1,024) before (128, 512), the backward's choice), so it has
+# a cap of its own, two million scores: the rule takes (1,024, 1,024), the
+# best, and (128, 1,024), 1% behind a pair that wants three million.
+# Tried in a scratch copy of the kernel and left out (my chip run, PR 33):
+# a second body without the mask for the blocks that neither the diagonal
+# nor the pad crosses, 8.62 against 8.84 ms at the Moonlight call and
+# 2.54 against 2.55 at StarCoder2's, 0.3% of a step; a loop inside the
+# step over slices of the keys (12.6 ms at 512 keys, 19.7 at 256) or of
+# the rows (9.7 at 256 rows) with the state carried as values; a per-row
+# guard on the maximum in place of the second `where` (9.26 against 9.08).
+#
+# The backward pair (PERF.md section 6, PR 31).  Moonlight's (the chunked
+# XLA gradient: 168.1 ms): (1,024, 1,024) 23.8 ms, (1,024, 512) 24.5, (512,
+# 512) 25.4, (2,048, 512) 25.6, (512, 256) 31.3, (256, 256) 39.7, (1,024,
+# 128) 43.4.  StarCoder2's (chunked 60.7): (256, 512) 5.54, (128, 512) 5.76,
+# (256, 256) 6.01, (128, 1,024) 6.21, (128, 256) 6.53, (128, 128) 10.48.  An
+# accumulator is read and written once a step, so long steps pay; past a
+# million scores a block little or nothing is won.  The rule takes (1,024,
+# 1,024), the best, and (128, 512), 4% behind a pair that wants 1.5 million
+# scores.
+_FWD_SCORES = 2 ** 21          # most scores, rep x bq x bk, a step holds:
+_BWD_SCORES = 2 ** 20          # the forward's, and the two backward kernels'
+# Mosaic's own limit of 16 MiB a kernel holds the benchmark's two bf16
+# backward calls and refuses a million float32 scores (8 heads over 2 of
+# 256, a group of 32 at 192/128; `tests/test_reduce_bytes_v5e.py` compiles
+# all three kernels at them).  48 MiB is a number for the v5e, whose core
+# has 128 MiB of VMEM: a part with less wants a smaller one, and the two
+# caps with it
+_VMEM_LIMIT = 48 * 2 ** 20
+
+
+def _fit(block: int, t: int) -> int:
+    """`block` halved while half of it still holds all t rows, never
+    under the 128 lanes of a score tile."""
+    while block > 128 and block // 2 >= t:
+        block //= 2
+    return block
+
+
+def _step_blocks(rep, tq, tk, scores):
+    """(bq, bk) of a grid step: 1,024 rows of scores over the group's
+    heads (a program works on rep x bq rows: a group of few query heads
+    takes a longer block, so that a step's cost is spread over about as
+    many rows as a wide group's; rep >= 8 keeps `_BQ`) and as many keys,
+    1,024 at most, as keep the block within `scores`."""
+    bq = _fit(_BQ * max(1, 8 // rep), tq)
+    if tq < 128:      # one block of all the rows
+        bq = -(-tq // 8) * 8
+    bk = _fit(1024, tk)
+    while bk > 128 and rep * bq * bk > scores:
+        bk //= 2
+    return bq, bk
+
+
+def _fwd_blocks(rep, tq, tk):
+    """(bq, bk) of the forward kernel."""
+    return _step_blocks(rep, tq, tk, _FWD_SCORES)
+
+
+def _bwd_blocks(rep, tq, tk):
+    """(bq, bk) of both backward kernels."""
+    return _step_blocks(rep, tq, tk, _BWD_SCORES)
+
+
+# Pallas copies whatever block a spec names, for a step whose compute
+# `pl.when` skips too.  A causal step above the diagonal names the block
+# its neighbour on the diagonal needs, which is then in VMEM already and
+# is not copied again (the backward pair: 23.8 against 25.4 ms at the
+# Moonlight shape, 5.76 against 6.17 at StarCoder2's; the forward: 9.26
+# against 10.23 and, at (128, 512), 4.04 against 4.01: nothing at twelve
+# heads a key head, whose steps are long beside a 256 KiB copy)
+
+def _dq_k_block(i, j, bq, bk):
+    """The k block that step (q block i, k block j) of the causal forward
+    and dq kernels names: j up to the last block q block i's rows reach."""
+    return jnp.minimum(j, (i * bq + bq - 1) // bk)
+
+
+def _dkv_q_block(j, i, bq, bk, n_q):
+    """The q block that step (k block j, q block i) of the causal dk/dv
+    kernel names: i from the first block whose rows reach k block j (the
+    last q block where the keys go on past every query)."""
+    return jnp.maximum(i, jnp.minimum(j * bk // bq, n_q - 1))
+
+
 @functools.partial(jax.jit, static_argnums=(3, 4))
 def _flash_gqa_fwd_call(q, k, v, causal: bool, interpret: bool):
     """Returns ((B, Tq, H, Dv) out, (B, H_kv, rep, Tq_p) lse)."""
-    (b, tq, h, d, tk, hkv, rep, bq, bk, tq_p, tk_p, d_p) = _dims(q, k)
-    dv = v.shape[-1]            # v's own width (latent attention: Dv < D)
-    dv_p = _pad128(dv)
+    b, tq, h, d = q.shape
+    tk, hkv, dv = k.shape[1], k.shape[2], v.shape[-1]
+    rep = h // hkv
+    d_p, dv_p = _pad128(d), _pad128(dv)     # v's own width (latent
+                                            # attention: Dv < D)
     scale = 1.0 / float(d) ** 0.5
+    bq, bk = _fwd_blocks(rep, tq, tk)
+    tq_p, tk_p = -(-tq // bq) * bq, -(-tk // bk) * bk
+    n_q, n_k = tq_p // bq, tk_p // bk
     # layouts: q -> (B, H_kv, rep, Tq, D); k/v -> (B, H_kv, Tk, D).
     # D zero-pad changes no logit (q·k unaffected) and only adds zero
     # output columns, sliced off below; pad keys are masked by position.
@@ -171,10 +271,16 @@ def _flash_gqa_fwd_call(q, k, v, causal: bool, interpret: bool):
     kt = _kv_layout(k, tk_p, d_p)
     vt = _kv_layout(v, tk_p, dv_p)
 
-    n_q, n_k = tq_p // bq, tk_p // bk
+    def kv(width):
+        return pl.BlockSpec(
+            (1, 1, bk, width),
+            (lambda bi, g, i, j: (bi, g, _dq_k_block(i, j, bq, bk), 0))
+            if causal else (lambda bi, g, i, j: (bi, g, j, 0)),
+            memory_space=pltpu.VMEM)
+
     call = pl.pallas_call(
         functools.partial(_flash_gqa_kernel, causal=causal, scale=scale,
-                          tq=tq, tk=tk, bq=bq, bk=bk, n_k=n_k),
+                          tk=tk, bq=bq, bk=bk, n_k=n_k),
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, rep, tq_p, dv_p), q.dtype),
             jax.ShapeDtypeStruct((b, hkv, rep, tq_p), jnp.float32),
@@ -184,12 +290,7 @@ def _flash_gqa_fwd_call(q, k, v, causal: bool, interpret: bool):
             pl.BlockSpec((1, 1, rep, bq, d_p),
                          lambda bi, g, i, j: (bi, g, 0, i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bk, d_p),
-                         lambda bi, g, i, j: (bi, g, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bk, dv_p),
-                         lambda bi, g, i, j: (bi, g, j, 0),
-                         memory_space=pltpu.VMEM),
+            kv(d_p), kv(dv_p),
         ],
         out_specs=(
             pl.BlockSpec((1, 1, rep, bq, dv_p),
@@ -199,11 +300,14 @@ def _flash_gqa_fwd_call(q, k, v, causal: bool, interpret: bool):
                          lambda bi, g, i, j: (bi, g, 0, i),
                          memory_space=pltpu.VMEM),
         ),
+        # the running maximum and sum are a column: with 128 lanes of
+        # copies a step reads and writes 128 times what it needs
         scratch_shapes=[
             pltpu.VMEM((rep, bq, dv_p), jnp.float32),
-            pltpu.VMEM((rep, bq, 128), jnp.float32),
-            pltpu.VMEM((rep, bq, 128), jnp.float32),
+            pltpu.VMEM((rep, bq, 1), jnp.float32),
+            pltpu.VMEM((rep, bq, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name=scopes.kernel_name(scopes.KERNEL_FLASH_GQA_FWD),
     )
@@ -313,67 +417,6 @@ def _flash_gqa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-# The backward kernels' block lengths (`_bwd_blocks`), timed on a v5e for
-# one layer's call, both kernels and the layout passes around them
-# (`tools/bench_flash_gqa.py`; PERF.md section 6, PR 31).  Moonlight's (2 x
-# 8,192 tokens, 16 heads of 192/128 at rep 1; the chunked XLA gradient:
-# 168.1 ms): (bq, bk) = (1,024, 1,024) 23.8 ms, (1,024, 512) 24.5, (512,
-# 512) 25.4, (2,048, 512) 25.6, (512, 256) 31.3, (256, 256) 39.7, (1,024,
-# 128) 43.4.  StarCoder2's (2 x 4,096, 24 heads of 128 at rep 12; chunked
-# 60.7): (256, 512) 5.54, (128, 512) 5.76, (256, 256) 6.01, (128, 1,024)
-# 6.21, (128, 256) 6.53, (128, 128) 10.48.  An accumulator is read and
-# written once a step, so long steps pay; past a million scores a block
-# little or nothing is won.  The rule takes (1,024, 1,024), the best, and
-# (128, 512), 4% behind a pair that wants 1.5 million scores.
-_BWD_SCORES = 2 ** 20          # most scores, rep x bq x bk, a step holds
-# Mosaic's own limit of 16 MiB a kernel holds the benchmark's two bf16
-# calls and refuses a million float32 scores (8 heads over 2 of 256, a
-# group of 32 at 192/128; `tests/test_reduce_bytes_v5e.py` compiles them).
-# 48 MiB is a number for the v5e, whose core has 128 MiB of VMEM: a part
-# with less wants a smaller one, and `_BWD_SCORES` with it
-_BWD_VMEM_LIMIT = 48 * 2 ** 20
-
-
-def _fit(block: int, t: int) -> int:
-    """`block` halved while half of it still holds all t rows, never
-    under the 128 lanes of a score tile."""
-    while block > 128 and block // 2 >= t:
-        block //= 2
-    return block
-
-
-def _bwd_blocks(rep, tq, tk):
-    """(bq, bk) of both backward kernels: the forward's rows a program
-    (`_dims`) and as many keys, 1,024 at most, as keep the block of
-    scores within `_BWD_SCORES`."""
-    bq = _fit(_BQ * max(1, 8 // rep), tq)
-    if tq < 128:      # one block of all the rows, as the forward's
-        bq = -(-tq // 8) * 8
-    bk = _fit(1024, tk)
-    while bk > 128 and rep * bq * bk > _BWD_SCORES:
-        bk //= 2
-    return bq, bk
-
-
-# Pallas copies whatever block a spec names, for a step whose compute
-# `pl.when` skips too.  A causal step above the diagonal names the block
-# its neighbour on the diagonal needs, which is then in VMEM already and
-# is not copied again (23.8 against 25.4 ms at the Moonlight shape, 5.76
-# against 6.17 at StarCoder2's)
-
-def _dq_k_block(i, j, bq, bk):
-    """The k block that step (q block i, k block j) of the causal dq
-    kernel names: j up to the last block q block i's rows reach."""
-    return jnp.minimum(j, (i * bq + bq - 1) // bk)
-
-
-def _dkv_q_block(j, i, bq, bk, n_q):
-    """The q block that step (k block j, q block i) of the causal dk/dv
-    kernel names: i from the first block whose rows reach k block j (the
-    last q block where the keys go on past every query)."""
-    return jnp.maximum(i, jnp.minimum(j * bk // bq, n_q - 1))
-
-
 def _row_layout(x, hkv, rep, tq_p):
     """(B, Tq, H) -> padded (B, H_kv, rep, Tq_p)."""
     b, tq, _ = x.shape
@@ -399,8 +442,8 @@ def _flash_gqa_bwd_call(q, k, v, out, lse, do, causal: bool,
     vt = _kv_layout(v, tk_p, dv_p)
     dot = _q_layout(do, hkv, rep, tq_p, dv_p)
     # delta_i = Σ_d dO_id · O_id (the flash-backward row constant).  Pad
-    # rows (the forward's block length is not this one's: lse is cut to
-    # Tq and padded anew) have q = dO = 0 and lse = delta = 0: p = 1
+    # rows (the forward's block length need not be this one's: lse is cut
+    # to Tq and padded anew) have q = dO = 0 and lse = delta = 0: p = 1
     # there, ds = 0, and they add nothing to dk or dv
     delta = _row_layout(
         (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1),
@@ -427,7 +470,7 @@ def _flash_gqa_bwd_call(q, k, v, out, lse, do, causal: bool,
                            memory_space=pltpu.VMEM)
         return wide(d_p), wide(dv_p), kv(d_p), kv(dv_p), row
 
-    params = pltpu.CompilerParams(vmem_limit_bytes=_BWD_VMEM_LIMIT)
+    params = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
     qd, qdv, kd, kdv, row = specs(
         lambda i, j: i,
         (lambda i, j: _dq_k_block(i, j, bq, bk)) if causal

@@ -157,6 +157,7 @@ def _qkv(tq, tk, h, hkv, d, dv, dtype=jnp.float32, seed=9):
 
 _WIDTHS = {"rep12": (12, 1, 16, 16), "v_narrower": (2, 2, 24, 16),
            "v_wider": (4, 2, 16, 24)}
+_FWD_WIDTHS = {**_WIDTHS, "rep1": (2, 2, 16, 16)}
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -262,6 +263,122 @@ def test_flash_gqa_backward_causal_steps_name_blocks_in_range(tq, tk, bq,
     # a skipped step names the block of the nearest step that computes
     assert (np.diff(kj, axis=1) >= 0).all()
     assert (np.diff(qi, axis=0) >= 0).all()
+
+
+def _exact_lse(q, k, causal):
+    """Log-sum-exp of each query row's scaled scores, per head:
+    (B, H, Tq)."""
+    rep = q.shape[2] // k.shape[2]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, rep, 2),
+                   precision="highest") / q.shape[-1] ** 0.5
+    if causal:
+        s = jnp.where(jnp.arange(q.shape[1])[:, None]
+                      >= jnp.arange(k.shape[1])[None], s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1)
+
+
+@pytest.mark.parametrize("tq,tk,bq,bk,causal,widths", [
+    (976, 976, 128, 128, True, "rep1"),       # square, the diagonal at
+    (1104, 1104, 128, 256, True, "rep12"),    # every offset in a block
+    (1232, 1232, 256, 128, True, "v_narrower"),
+    (136, 528, 128, 128, True, "v_wider"),    # keys past every query row
+    (208, 912, 128, 256, True, "rep1"),
+    (528, 136, 128, 128, True, "rep12"),      # rows past every key
+    (296, 208, 128, 128, False, "v_narrower"),
+    (48, 296, 48, 128, False, "rep1"),        # one block of all the rows
+    (392, 392, 128, 256, False, "rep12"),
+])
+def test_flash_gqa_forward_over_many_blocks(monkeypatch, tq, tk, bq, bk,
+                                            causal, widths):
+    """At the lengths `_fwd_blocks` picks, a CPU-sized sequence is one
+    step; with short ones handed in, the steps that compute, the causal
+    steps that are skipped and those whose copies are skipped too all
+    run: the output against the exact attention, `lse` against the exact
+    log-sum-exp."""
+    import sys
+    import cpd_tpu.ops.flash_gqa  # noqa: F401
+    fg = sys.modules["cpd_tpu.ops.flash_gqa"]
+
+    q, k, v = _qkv(tq, tk, *_FWD_WIDTHS[widths], seed=5)
+    monkeypatch.setattr(fg, "_fwd_blocks", lambda *a: (bq, bk))
+    # (the jitted call would serve another test's trace of these shapes,
+    # made at other lengths)
+    out, lse = fg._flash_gqa_fwd_call.__wrapped__(q, k, v, causal, True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_exact_attention(q, k, v, causal)),
+        rtol=2e-6, atol=2e-6)
+    assert lse.shape[-1] == -(-tq // bq) * bq
+    h = q.shape[2]
+    np.testing.assert_allclose(
+        np.asarray(lse[..., :tq]).reshape(1, h, tq),
+        np.asarray(_exact_lse(q, k, causal)), rtol=2e-6, atol=2e-6)
+
+
+def test_flash_gqa_forward_fully_masked_row(monkeypatch):
+    """A row none of whose keys counts (every score -inf) reads o = 0 and
+    an lse of -1e30, in a causal step where most of its block is masked
+    besides: p is zeroed by the mask, not by exp(-1e30 - m), which is 1
+    while the row's maximum is still -1e30."""
+    import sys
+    import cpd_tpu.ops.flash_gqa  # noqa: F401
+    fg = sys.modules["cpd_tpu.ops.flash_gqa"]
+
+    q, k, v = _qkv(264, 264, 2, 1, 16, 8, seed=6)
+    k = jnp.abs(k) + 0.5
+    dead = 5
+    q = q.at[0, dead].set(-3e38)
+    monkeypatch.setattr(fg, "_fwd_blocks", lambda *a: (128, 128))
+    out, lse = fg._flash_gqa_fwd_call.__wrapped__(q, k, v, True, True)
+    out, lse = np.asarray(out), np.asarray(lse)[0, 0, :, :264]
+    assert (out[0, dead] == 0).all() and (lse[:, dead] < -9e29).all()
+    live = np.arange(264) != dead
+    np.testing.assert_allclose(
+        out[0, live], np.asarray(_exact_attention(q, k, v, True))[0, live],
+        rtol=2e-6, atol=2e-6)
+    np.testing.assert_allclose(
+        lse[:, live], np.asarray(_exact_lse(q, k, True))[0][:, live],
+        rtol=2e-6, atol=2e-6)
+
+
+def test_flash_gqa_forward_block_lengths():
+    """The forward rule's choices at the benchmark's two calls (timed on
+    the chip, PERF.md section 6, PR 33), at short and ragged sequences,
+    and at large groups: the backward's rows, and keys within a cap
+    twice the backward's."""
+    import sys
+    import cpd_tpu.ops.flash_gqa  # noqa: F401
+    blocks = sys.modules["cpd_tpu.ops.flash_gqa"]._fwd_blocks
+
+    assert blocks(1, 8192, 8192) == (1024, 1024)
+    assert blocks(12, 4096, 4096) == (128, 1024)
+    assert blocks(2, 300, 300) == (512, 512)
+    assert blocks(2, 130, 100) == (256, 128)
+    assert blocks(2, 40, 100) == (40, 128)
+    assert blocks(4, 4096, 4096) == (256, 1024)
+    assert blocks(16, 4096, 4096) == (128, 1024)
+    assert blocks(32, 4096, 4096) == (128, 512)
+
+
+@pytest.mark.parametrize("rep,tq,tk", [
+    (1, 8192, 8192), (12, 4096, 4096), (2, 1500, 4000), (4, 2500, 1300)])
+def test_flash_gqa_forward_causal_steps_name_blocks_in_range(rep, tq, tk):
+    """The forward's K/V map at the rule's own lengths (its grid is the
+    dq kernel's): every step names a block that exists, a step that
+    computes names its own, and a skipped step the last one that did."""
+    import sys
+    import cpd_tpu.ops.flash_gqa  # noqa: F401
+    fg = sys.modules["cpd_tpu.ops.flash_gqa"]
+
+    bq, bk = fg._fwd_blocks(rep, tq, tk)
+    n_q, n_k = -(-tq // bq), -(-tk // bk)
+    i, j = np.meshgrid(np.arange(n_q), np.arange(n_k), indexing="ij")
+    computes = j * bk <= i * bq + (bq - 1)       # the kernel's own test
+    kj = np.asarray(fg._dq_k_block(i, j, bq, bk))
+    assert kj.min() >= 0 and kj.max() < n_k
+    np.testing.assert_array_equal(kj[computes], j[computes])
+    last = np.where(computes, j, -1).max(axis=1, keepdims=True)
+    np.testing.assert_array_equal(kj[~computes],
+                                  np.broadcast_to(last, kj.shape)[~computes])
 
 
 def test_flash_gqa_routing_and_validation():

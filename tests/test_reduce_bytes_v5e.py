@@ -84,7 +84,7 @@ def test_one_rank_pipeline_moves_little_more_than_the_control(
     assert 0 < gap < GAP_LIMIT_GB
 
 
-# ---- the flash-backward kernels' block lengths (ops/flash_gqa.py) --------
+# ---- the flash kernels' block lengths (ops/flash_gqa.py) ------------------
 # here because this is the file of tests/ that loads the TPU's library
 
 @pytest.mark.parametrize("b,t,h,hkv,d,dv,dtype", [
@@ -92,15 +92,16 @@ def test_one_rank_pipeline_moves_little_more_than_the_control(
     (2, 4096, 24, 2, 128, 128, "bfloat16"),     # the StarCoder2 cells'
     (1, 4096, 8, 2, 256, 256, "float32"),       # wide float32 heads and
     (1, 4096, 32, 1, 192, 128, "float32"),      # a group of 32: these two
-                                                # need `_BWD_VMEM_LIMIT`
+                                                # need `_VMEM_LIMIT`
     (1, 4096, 16, 1, 512, 512, "bfloat16"),     # the widest a step gets
     (1, 300, 2, 2, 24, 16, "float32"),          # a multiple of no block
 ])
-def test_flash_backward_compiles_for_v5e_at_its_own_block_lengths(
+def test_flash_kernels_compile_for_v5e_at_their_own_block_lengths(
         topo, b, t, h, hkv, d, dv, dtype):
-    """`_bwd_blocks` caps a step's scores and counts no bytes; Mosaic
-    refuses here, as on the chip, a step that does not fit the kernels'
-    VMEM limit (at Mosaic's own 16 MiB the two float32 shapes do not)."""
+    """`_fwd_blocks` and `_bwd_blocks` cap a step's scores and count no
+    bytes; Mosaic refuses here, as on the chip, a step that does not fit
+    the kernels' VMEM limit (at Mosaic's own 16 MiB the two float32
+    shapes do not)."""
     import jax
     from jax.sharding import SingleDeviceSharding
 
@@ -109,9 +110,11 @@ def test_flash_backward_compiles_for_v5e_at_its_own_block_lengths(
     one = SingleDeviceSharding(topo.devices[0])
     shaped = lambda *shape: jax.ShapeDtypeStruct(shape, dtype, sharding=one)
     q, k, v = shaped(b, t, h, d), shaped(b, t, hkv, d), shaped(b, t, hkv, dv)
-    out = shaped(b, t, h, dv)
-    lse = jax.eval_shape(lambda *a: fg._flash_gqa_fwd_call(
-        *a, True, False), q, k, v)[1]
+    fwd = jax.jit(lambda *a: fg._flash_gqa_fwd_call(*a, True, False))
+    out, lse = jax.eval_shape(fwd, q, k, v)
+    assert out.shape == (b, t, h, dv)
+    fwd.lower(q, k, v).compile()
+    out = shaped(*out.shape)
     lse = jax.ShapeDtypeStruct(lse.shape, lse.dtype, sharding=one)
     jax.jit(lambda *a: fg._flash_gqa_bwd_call(*a, True, False)).lower(
         q, k, v, out, lse, out).compile()

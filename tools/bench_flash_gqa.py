@@ -1,15 +1,17 @@
 """Time one layer's `flash_gqa` call on the chip at the two LM cells' shapes.
 
-Forward kernel, the chunked XLA gradient (`jax.vjp` of
-`_chunked_attention`, what `flash_gqa`'s backward was before PR 31; a
-sequence at a time at the Moonlight shape, as the model took it) and the
-Pallas backward (`_flash_gqa_bwd_call`: both kernels and the layout
-passes around them) at the rule's own block lengths and at a sweep of
-others, with each kernel's output alone besides.  These are the numbers in
-`ops/flash_gqa.py`'s comment on `_bwd_blocks` and in PERF.md section 6,
-PR 31:
+The forward (`_flash_gqa_fwd_call`: the kernel and the layout passes
+around it) and the Pallas backward (`_flash_gqa_bwd_call`: both kernels
+and theirs, with each kernel's output alone besides), each at its rule's
+own block lengths and at a sweep of others, and the chunked XLA gradient
+(`jax.vjp` of `_chunked_attention`, what `flash_gqa`'s backward was
+before PR 31; a sequence at a time at the Moonlight shape, as the model
+took it).  These are the numbers in `ops/flash_gqa.py`'s comment on the
+block lengths and in PERF.md section 6, PR 31 and PR 33:
 
-    python tools/bench_flash_gqa.py [chunked] [sweep]
+    python tools/bench_flash_gqa.py [chunked] [sweep] [fwd | bwd]
+
+(`fwd` or `bwd`: that pass's timings only; default both.)
 
 Refuses any backend but a TPU (`ops.require_tpu`, exit 2).  bf16, causal.
 One JSON object on the last line, and in chiprun_out/bench_flash_gqa.json.
@@ -24,14 +26,19 @@ import sys
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
-# (batch, tokens, heads, kv heads, D, Dv) and the (bq, bk) pairs to time;
-# the rule's own pair is timed first whatever this lists
+# (batch, tokens, heads, kv heads, D, Dv) and the (bq, bk) pairs to time,
+# the forward's and the backward's; a rule's own pair is timed first
+# whatever these list
 SHAPES = {
     "moonlight": ((2, 8192, 16, 16, 192, 128),
+                  [(1024, 2048), (2048, 1024), (512, 1024), (1024, 512),
+                   (512, 512), (1024, 256), (1024, 128)],
                   [(1024, 512), (512, 1024), (512, 512), (2048, 512),
                    (2048, 1024), (256, 1024), (256, 512), (512, 256),
                    (256, 256), (1024, 128)]),
     "starcoder2": ((2, 4096, 24, 2, 128, 128),
+                   [(256, 1024), (128, 2048), (256, 512), (128, 512),
+                    (256, 256), (128, 256), (128, 128)],
                    [(256, 512), (256, 256), (256, 1024), (128, 1024),
                     (128, 256), (256, 128), (128, 128)]),
 }
@@ -78,7 +85,9 @@ def main() -> int:
     dev = require_tpu("bench_flash_gqa")[0]
     enable_compile_cache()
     out = {"device": dev.device_kind}
-    rule = fg._bwd_blocks
+    fwd_rule, bwd_rule = fg._fwd_blocks, fg._bwd_blocks
+    sweep = "sweep" in phases
+    passes = [x for x in ("fwd", "bwd") if x in phases] or ["fwd", "bwd"]
 
     def rec(name, fn):
         try:
@@ -88,15 +97,27 @@ def main() -> int:
             out[name] = "ERR " + " ".join(str(e).split())[:200]
         print(name, out[name], flush=True)
 
-    for name, ((b, t, h, hkv, d, dv), pairs) in SHAPES.items():
+    for name, ((b, t, h, hkv, d, dv), fwd_pairs, pairs) in SHAPES.items():
         ks = jax.random.split(jax.random.PRNGKey(0), 4)
         q = jax.random.normal(ks[0], (b, t, h, d), jnp.bfloat16)
         k = jax.random.normal(ks[1], (b, t, hkv, d), jnp.bfloat16)
         v = jax.random.normal(ks[2], (b, t, hkv, dv), jnp.bfloat16)
         g = jax.random.normal(ks[3], (b, t, h, dv), jnp.bfloat16)
-        fwd = _jit_fwd(fg)
-        rec(f"{name}_fwd_ms", lambda: _ms(fwd, q, k, v))
-        o, lse = fwd(q, k, v)
+        own = fwd_rule(h // hkv, t, t)
+        for pair in ([own] + (fwd_pairs if sweep else [])
+                     if "fwd" in passes else []):
+            # the lengths are read when the call is traced
+            fg._fwd_blocks = lambda *a, pair=pair: pair
+            jax.clear_caches()
+            fwd = _jit_fwd(fg)
+            rec(f"{name}_fwd_{pair[0]}x{pair[1]}"
+                + ("_rule" if pair == own else "") + "_ms",
+                lambda: _ms(fwd, q, k, v, n=20))
+        fg._fwd_blocks = fwd_rule
+        if "bwd" not in passes:
+            continue
+        jax.clear_caches()
+        o, lse = _jit_fwd(fg)(q, k, v)
 
         if "chunked" in phases:
             one = lambda q, k, v: _chunked_attention(q, k, v, True, 0, 0)
@@ -113,9 +134,8 @@ def main() -> int:
             rec(f"{name}_bwd_chunked_ms",
                 lambda: _ms(jax.jit(chunked_bwd), q, k, v, g, n=3))
 
-        own = rule(h // hkv, t, t)
-        for pair in [own] + (pairs if "sweep" in phases else []):
-            # the lengths are read when the call is traced
+        own = bwd_rule(h // hkv, t, t)
+        for pair in [own] + (pairs if sweep else []):
             fg._bwd_blocks = lambda *a, pair=pair: pair
             jax.clear_caches()
             tag = (f"{name}_bwd_pallas_{pair[0]}x{pair[1]}"
@@ -125,7 +145,7 @@ def main() -> int:
                 call = _jit_bwd(fg, pick)
                 rec(tag + part + "_ms",
                     lambda: _ms(call, q, k, v, o, lse, g))
-        fg._bwd_blocks = rule
+        fg._bwd_blocks = bwd_rule
 
     out_dir = os.path.join(_REPO, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)

@@ -26,8 +26,10 @@ Checks (bitwise vs the XLA composition unless noted):
      vs the reference implementation (allclose)
   4. a transformer Block with attn_impl="flash" vs "xla" (allclose)
   5. chunked attention — pure XLA; cross-checked against 3 on the chip
-  6. flash_gqa — forward (incl. a short-Tq case, bq < 128) and its Pallas
-     backward, at small shapes and at the benchmark cells' own (allclose)
+  6. flash_gqa — forward (incl. a short-Tq case, bq < 128; output and
+     lse at the benchmark cells' own shapes and block lengths and at two
+     ragged ones) and its Pallas backward, at small shapes and at the
+     cells' own (allclose)
   7. the ring's wire kernels — quantize_add, quantize_pack, hop_pack
      (plain / digest / blocked / multi-tile) and digest_rows: the three
      kernels `--mode ring` selects by default on TPU
@@ -198,6 +200,64 @@ def _flash_gqa_fwd(shapes):
                            f"causal={causal} {diff}")
         return bad
     return check
+
+
+# (batch, Tq, Tk, heads, kv heads, D, Dv)
+_FWD_CELL_SHAPES = [(2, 8192, 8192, 16, 16, 192, 128),
+                    (2, 4096, 4096, 24, 2, 128, 128),
+                    (1, 2500, 3300, 4, 2, 192, 128),
+                    (1, 3300, 2500, 24, 2, 128, 128)]
+
+
+def check_flash_gqa_fwd_cells(rng, shapes=_FWD_CELL_SHAPES):
+    """The forward at the benchmark cells' own shapes in bf16, so at the
+    block lengths `_fwd_blocks` gives them (the diagonal inside a block
+    of 1,024 keys, the steps above it whose copies are skipped), and a
+    ragged causal case of several key blocks, with pad rows and pad keys
+    that differ in number; output and `lse` against the chunked XLA scan
+    and the exact log-sum-exp of the bf16 inputs."""
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from cpd_tpu.ops.attention import _chunked_attention
+    import cpd_tpu.ops.flash_gqa  # noqa: F401  (the attribute is a function)
+    fg = sys.modules["cpd_tpu.ops.flash_gqa"]
+
+    def lse_of(q, k):
+        """(B, H, Tq) log-sum-exp of the causal scaled scores, a head at
+        a time (a (Tq, Tk) float32 block each)."""
+        rep = q.shape[2] // k.shape[2]
+        row = jnp.arange(q.shape[1])[:, None] >= jnp.arange(k.shape[1])
+
+        def head(qh, kh):
+            s = jnp.dot(qh.astype(jnp.float32), kh.astype(jnp.float32).T,
+                        precision="highest") / q.shape[-1] ** 0.5
+            return jax.nn.logsumexp(jnp.where(row, s, -jnp.inf), axis=-1)
+        return jax.jit(lambda q, k: jax.lax.map(
+            lambda x: jax.lax.map(lambda y: head(*y), x),
+            (q.transpose(0, 2, 1, 3),
+             jnp.repeat(k, rep, 2).transpose(0, 2, 1, 3))))(q, k)
+
+    bad = []
+    for (bsz, tq, tk, h, hkv, d, dv) in shapes:
+        q, k, v = (x.astype(jnp.bfloat16)
+                   for x in _qkv(rng, bsz, tq, tk, h, hkv, d))
+        v = v[..., :dv]
+        out, lse = fg._flash_gqa_fwd_call(q, k, v, True,
+                                           fg.interpret_mode())
+        want = _chunked_attention(q, k, v, True, 0, 0)
+        tag = f"tq={tq} tk={tk} h={h}/{hkv} {d}/{dv} bf16"
+        diff = _close(np.asarray(out.astype(jnp.float32)),
+                      np.asarray(want.astype(jnp.float32)), 2e-2)
+        if diff:
+            bad.append(f"{tag} out {diff}")
+        got = np.asarray(lse[..., :tq]).reshape(bsz, h, tq)
+        diff = _close(got, np.asarray(lse_of(q, k)), 2e-3)
+        if diff:
+            bad.append(f"{tag} lse {diff}")
+    return bad
 
 
 def _flash_gqa_bwd():
@@ -423,6 +483,7 @@ def checks() -> list:
         # lane dimension is bq
         ("flash_gqa fwd short-Tq", _flash_gqa_fwd(
             [(8, 128, 4, 2, 64, True), (40, 256, 8, 2, 64, False)])),
+        ("flash_gqa fwd cells' shapes", check_flash_gqa_fwd_cells),
         ("flash_gqa bwd", _flash_gqa_bwd()),
         ("quantize_add_pallas[_bits]", check_quantize_add),
     ]
