@@ -14,6 +14,7 @@ from .transformer import TransformerLM, lm_param_specs, transformer_lm
 from .pipeline_lm import PipelinedLM, pipelined_lm, pp_param_specs
 from .moe import MoETransformerLM, moe_lm, moe_param_specs
 from .mla_moe import MLAMoELM, mla_moe_lm
+from .looped import LoopedLM, looped_lm
 from .davidnet_graph import graph_davidnet
 from .generate import generate
 from .vit import ViT, vit
@@ -32,6 +33,7 @@ _REGISTRY = {
     "pipelined_lm": pipelined_lm,
     "moe_lm": moe_lm,
     "mla_moe_lm": mla_moe_lm,         # latent attention + routed experts
+    "looped_lm": looped_lm,           # one stack run n_loops times, exit gate
     "davidnet_graph": graph_davidnet,  # dict-graph definition (TorchGraph)
     "vit": vit,                       # RoPE-ViT encoder (models/vit.py)
 }
@@ -50,5 +52,5 @@ __all__ = ["ResNetCIFAR", "resnet18_cifar", "DavidNet", "davidnet",
            "TransformerLM", "transformer_lm", "lm_param_specs",
            "PipelinedLM", "pipelined_lm", "pp_param_specs",
            "MoETransformerLM", "moe_lm", "moe_param_specs",
-           "MLAMoELM", "mla_moe_lm",
+           "MLAMoELM", "mla_moe_lm", "LoopedLM", "looped_lm",
            "graph_davidnet", "generate", "get_model"]

@@ -46,8 +46,8 @@ from ..obs import scopes
 from ..ops.attention import _chunked_attention, local_attention
 from ..ops.grouped import grouped_matmul
 
-__all__ = ["RMSNorm", "GatedMLP", "LatentAttention", "RoutedExperts",
-           "MLAMoEBlock", "MLAMoELM", "mla_moe_lm", "COUNTERS"]
+__all__ = ["RMSNorm", "GatedMLP", "causal_attention", "LatentAttention",
+           "RoutedExperts", "MLAMoEBlock", "MLAMoELM", "mla_moe_lm", "COUNTERS"]
 
 COUNTERS = "counters"   # the flax collection the counters are sown into
 
@@ -73,6 +73,27 @@ def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float):
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return out.astype(x.dtype)
+
+
+def causal_attention(q, k, v, impl: str):
+    """Causal softmax attention on (B, T, H, D) by the path `impl` names:
+    "flash" (the Pallas kernels of ops/flash_gqa.py), "chunked" (the
+    online-softmax scan) or "xla"."""
+    if impl == "flash":
+        from ..ops.flash_gqa import flash_gqa
+        return flash_gqa(q, k, v, True)
+    if impl == "chunked":
+        one = lambda q, k, v: _chunked_attention(q, k, v, True, 0, 0)
+        # a sequence at a time: the scan's backward keeps an output-
+        # sized float32 carry for every block of keys, and the
+        # sequences' carries need not live together (2 x 8,192 tokens
+        # compiled for a v5e: 8.7 GiB of temporaries at once, 4.7 so)
+        return one(q, k, v) if q.shape[0] == 1 else lax.map(
+            lambda x: one(*(y[None] for y in x))[0], (q, k, v))
+    if impl == "xla":
+        return local_attention(q, k, v, causal=True)
+    raise ValueError(f"unknown attn_impl {impl!r}; "
+                     "expected 'xla', 'flash' or 'chunked'")
 
 
 class RMSNorm(nn.Module):
@@ -133,22 +154,7 @@ class LatentAttention(nn.Module):
         k_rope = _rope(k_rope[:, :, None, :], positions, self.rope_theta)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_rope, (b, t, nh, rope))], -1)
-        if self.attn_impl == "flash":
-            from ..ops.flash_gqa import flash_gqa
-            a = flash_gqa(q, k, v, True)
-        elif self.attn_impl == "chunked":
-            one = lambda q, k, v: _chunked_attention(q, k, v, True, 0, 0)
-            # a sequence at a time: the scan's backward keeps an output-
-            # sized float32 carry for every block of keys, and the
-            # sequences' carries need not live together (2 x 8,192 tokens
-            # compiled for a v5e: 8.7 GiB of temporaries at once, 4.7 so)
-            a = one(q, k, v) if b == 1 else lax.map(
-                lambda x: one(*(y[None] for y in x))[0], (q, k, v))
-        elif self.attn_impl == "xla":
-            a = local_attention(q, k, v, causal=True)
-        else:
-            raise ValueError(f"unknown attn_impl {self.attn_impl!r}; "
-                             "expected 'xla', 'flash' or 'chunked'")
+        a = causal_attention(q, k, v, self.attn_impl)
         return dense(d, "out_proj")(a.reshape(b, t, nh * self.v_head_dim))
 
 

@@ -28,6 +28,8 @@ Stdlib-only, like the rest of `obs`.
 | `cpd.mla` | `models/mla_moe.py` | latent attention with its projections (the kernel's scope nests under it) |
 | `cpd.moe_router` / `cpd.moe_dispatch` / `cpd.moe_experts` / `cpd.moe_combine` | `models/mla_moe.py:RoutedExperts` | scores, selection and gates / sort, group sizes and the gather of rows / the three grouped products and the gating product / the sum back into tokens |
 | `cpd.moe_shared` / `cpd.dense_mlp` | `models/mla_moe.py` | the shared expert / a leading layer's dense gated MLP |
+| `cpd.loop_attn` / `cpd.loop_mlp` | `models/looped.py:LoopBlock` | a looped block's attention (norms N1 and N2, projections, rotary; the kernels' scopes nest under it) / its gated MLP with norms N3 and N4; every pass of the loop |
+| `cpd.loop_exit` | `models/looped.py:LoopedLM` | a pass's exit: final norm, gate, head and cross-entropy, and the exit weighting of the loss |
 | `kernel.<name>` | `ops/*.py`, around each `pl.pallas_call` | one Pallas kernel; the call's `name=` is the same `<name>` |
 
 Ownership, as the reader applies it: an operation belongs to the LAST
@@ -41,7 +43,7 @@ from __future__ import annotations
 
 __all__ = ["LOSS_GRAD", "EMULATE_NODE", "REDUCE", "OPTIMIZER", "METRICS",
            "MLA", "MOE_ROUTER", "MOE_DISPATCH", "MOE_EXPERTS", "MOE_COMBINE",
-           "MOE_SHARED", "DENSE_MLP",
+           "MOE_SHARED", "DENSE_MLP", "LOOP_ATTN", "LOOP_MLP", "LOOP_EXIT",
            "APS_MAX_EXP", "APS_SCALE", "APS_UNSCALE", "WIRE_CAST",
            "WIRE_PACK", "WIRE_UNPACK", "WIRE_COLLECTIVE", "REDUCE_SCAN",
            "REDUCE_LOCAL",
@@ -61,6 +63,11 @@ MOE_EXPERTS = "cpd.moe_experts"
 MOE_COMBINE = "cpd.moe_combine"
 MOE_SHARED = "cpd.moe_shared"
 DENSE_MLP = "cpd.dense_mlp"
+
+# model layers (models/looped.py); all nest under LOSS_GRAD
+LOOP_ATTN = "cpd.loop_attn"
+LOOP_MLP = "cpd.loop_mlp"
+LOOP_EXIT = "cpd.loop_exit"
 
 APS_MAX_EXP = "aps.max_exp"
 APS_SCALE = "aps.scale"

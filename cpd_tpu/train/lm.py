@@ -42,6 +42,10 @@ from .state import (TrainState, make_sharded_stepper, reject_norm_based,
 
 __all__ = ["make_lm_train_step", "make_lm_eval_step", "lm_state_specs"]
 
+# the method of a model that owns its loss: (tokens, targets, train=True)
+# -> ((B, T) float32 loss terms, hits); the step takes the mean
+OWN_LOSS = "token_losses"
+
 
 def lm_state_specs(state: TrainState, tp_axis: str = "tp") -> TrainState:
     """PartitionSpec pytree shaped like `state`: params (and their optimizer
@@ -68,14 +72,19 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     """Build jitted ``(state, tokens, targets) -> (state, metrics)``.
 
     ``metrics`` holds ``loss`` and ``accuracy`` and, for a model that
-    declares ``step_counters`` ({name: "sum" | "max"}; its layers ``sow``
-    them into the ``"counters"`` collection), each counter merged over
-    layers, micro-batches and the dp/sp ranks (tp ranks repeat them).
+    declares ``step_counters`` ({name: "sum" | "max" | "mean"}; its layers
+    ``sow`` them into the ``"counters"`` collection), each counter merged
+    over layers, micro-batches and the dp/sp ranks (tp ranks repeat them).
 
     tokens/targets: (global_batch * emulate_node, T_global) int32, sharded
     (dp, sp).  Loss is next-token CE averaged over all target positions;
     ``label_smoothing`` in [0, 1) mixes the one-hot targets with uniform
-    mass (training loss only — eval stays plain CE).
+    mass (training loss only — eval stays plain CE).  A model whose loss
+    is not the cross-entropy of one logits tensor owns it: it has a
+    method ``token_losses(tokens, targets, train=True)`` that returns the
+    (B, T) float32 loss of every token and how many of them it predicts
+    right (models/looped.py), and is asked for those instead of its
+    logits; ``label_smoothing`` with such a model raises.
 
     use_aps ... block_size, the fifteen keywords of the dp reduction, are
     `train.grads.ReduceOptions`' fields and are described there, once.
@@ -105,12 +114,19 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
     if mesh.shape.get(axis_tp, 1) > 1:
         reject_norm_based(tx, "tp-sharded LM step")
 
+    own_loss = hasattr(model, OWN_LOSS)
+    if own_loss and label_smoothing:
+        raise ValueError(
+            f"label_smoothing {label_smoothing} with a model that owns its "
+            f"loss ({type(model).__name__}.{OWN_LOSS}): the smoothing is of "
+            f"the step's own cross-entropy, which this model does not use")
     has_dropout = getattr(model, "dropout_rate", 0.0) > 0.0
-    # counters a model reports: {name: "sum" | "max"}, sown into the
-    # "counters" collection by its layers (models/mla_moe.py).  A model
+    # counters a model reports: {name: "sum" | "max" | "mean"}, sown into
+    # the "counters" collection by its layers (models/mla_moe.py).  A model
     # that declares none is applied as before, equation for equation
     counters = dict(getattr(model, "step_counters", None) or {})
-    merge = {"sum": (jnp.sum, lax.psum), "max": (jnp.max, lax.pmax)}
+    merge = {"sum": (jnp.sum, lax.psum), "max": (jnp.max, lax.pmax),
+             "mean": (jnp.mean, lax.pmean)}
     for name, how in counters.items():
         if how not in merge:
             raise ValueError(f"step counter {name!r}: unknown merge {how!r}")
@@ -143,7 +159,13 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
                     key, lax.axis_index(axis_sp).astype(jnp.int32))
                 rngs = {"dropout": key}
             counts = {}
-            if counters:
+            if own_loss:
+                (ce, hits), sown = model.apply(
+                    {"params": params}, toks, tgts, train=True, rngs=rngs,
+                    mutable=["counters"], method=OWN_LOSS)
+                if counters:
+                    counts = model_counts(sown["counters"])
+            elif counters:
                 logits, sown = model.apply(
                     {"params": params}, toks, train=True, rngs=rngs,
                     mutable=["counters"])
@@ -151,8 +173,9 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             else:
                 logits = model.apply({"params": params}, toks, train=True,
                                      rngs=rngs)
-            ce = optax.softmax_cross_entropy_with_integer_labels(
-                logits, tgts)                       # (B_local, T_local)
+            if not own_loss:
+                ce = optax.softmax_cross_entropy_with_integer_labels(
+                    logits, tgts)                   # (B_local, T_local)
             if label_smoothing:
                 # closed form of CE against one_hot*(1-a) + a/V targets:
                 # (1-a)*CE_int + a*(logsumexp - mean(logits)) — no dense
@@ -174,7 +197,8 @@ def make_lm_train_step(model, tx: optax.GradientTransformation, mesh: Mesh,
             # normalize by the emulated-cluster size too (mix.py:239's
             # divide-so-the-sum-is-the-mean, per micro-batch)
             loss = local_sum / global_n / emulate_node
-            hits = jnp.sum(jnp.argmax(logits, -1) == tgts)
+            if not own_loss:
+                hits = jnp.sum(jnp.argmax(logits, -1) == tgts)
             return loss, (None, (local_sum, local_n, hits, counts))
 
         # --- cross-axis gradient reduction (see module docstring) ---

@@ -397,7 +397,7 @@ def test_counter_plumbing_leaves_a_dense_lm_step_as_it_was():
 
 def test_unknown_counter_merge_is_refused():
     model = model_of()
-    object.__setattr__(model, "step_counters", {"moe_pairs_held": "mean"})
+    object.__setattr__(model, "step_counters", {"moe_pairs_held": "median"})
     with pytest.raises(ValueError, match="unknown merge"):
         make_lm_train_step(model, make_optimizer("sgd", lambda s: 0.01),
                            make_mesh(dp=1, devices=jax.devices()[:1]))

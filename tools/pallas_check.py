@@ -205,6 +205,7 @@ def _flash_gqa_fwd(shapes):
 # (batch, Tq, Tk, heads, kv heads, D, Dv)
 _FWD_CELL_SHAPES = [(2, 8192, 8192, 16, 16, 192, 128),
                     (2, 4096, 4096, 24, 2, 128, 128),
+                    (2, 4096, 4096, 16, 16, 128, 128),
                     (1, 2500, 3300, 4, 2, 192, 128),
                     (1, 3300, 2500, 24, 2, 128, 128)]
 
@@ -310,10 +311,13 @@ def _flash_gqa_bwd():
                     bad.append(f"tq={tq} tk={tk} {d}/{dv} d{name} {diff}")
         # (batch, Tq, Tk, heads, kv heads, D, Dv): Moonlight's attention
         # a sequence (the chunked gradient of two keeps 8.7 GiB of
-        # temporaries), StarCoder2's, and its group of twelve ragged
+        # temporaries), StarCoder2's, the looped LM's (a head of 128 for
+        # every key head: the kernels' native width, no group, no
+        # padding), and StarCoder2's group of twelve ragged
         for (bsz, t, tk, h, hkv, d, dv) in [
                 (1, 8192, 8192, 16, 16, 192, 128),
                 (2, 4096, 4096, 24, 2, 128, 128),
+                (2, 4096, 4096, 16, 16, 128, 128),
                 (1, 4000, 1500, 24, 2, 128, 128)]:
             q, k, v = (x.astype(jnp.bfloat16)
                        for x in _qkv(rng, bsz, t, tk, h, hkv, d))
