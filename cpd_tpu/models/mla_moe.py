@@ -17,17 +17,22 @@ have, each a module here:
   absent expert add nothing here (their owners' chips add them; on one
   chip there is no exchange and none is stood in for).  Static shapes:
   the T·k (token, slot) pairs are sorted so that the held experts' pairs
-  come first in expert order, the rows are gathered into a T·k-row
-  buffer, pass three grouped products whose group sizes are the held
-  experts' counts, are scaled by their gates and summed back into their
-  tokens.  A T·k-row buffer is dropless whatever the routing; the grouped
-  products (`ops/grouped.py`) cost what the live rows cost, and leave the
-  rows past them unwritten (PERF.md §6, PR 30);
+  come first in expert order (index work, T·k long); the first `C` sorted
+  rows are gathered into a buffer, pass three grouped products whose
+  group sizes are the held experts' counts, are scaled by their gates and
+  added back into their tokens.  `C` is twice the pairs the chip holds
+  under an even router (`_row_bound`), and a step that holds more takes
+  the same path over all T·k rows, the other branch of one `lax.cond` on
+  the device: dropless whatever the routing, at the cost of the rows held
+  when the routing is sane.  The grouped products (`ops/grouped.py`) cost
+  what the live rows cost, and leave the rows past them unwritten
+  (PERF.md §6, PR 30 and PR 35);
 * `MLAMoELM` — `first_dense` leading blocks with a dense `GatedMLP`, the
   rest with `RoutedExperts` plus a shared expert; untied float32 head.
 
-Counters: each `RoutedExperts` sows `moe_pairs_held` and
-`moe_load_max_over_mean` into the `"counters"` collection; the model
+Counters: each `RoutedExperts` sows `moe_pairs_held`,
+`moe_load_max_over_mean` and `moe_compact` (1 where the step ran over `C`
+rows) into the `"counters"` collection; the model
 declares how each is merged over layers (and ranks) in `step_counters`,
 which `train/lm.py` reads.  Parameter leaves are named so that
 `megatron_shard_kind` takes none of them for a tensor-parallel one.
@@ -35,6 +40,7 @@ which `train/lm.py` reads.  Parameter leaves are named so that
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -44,12 +50,32 @@ from jax import lax
 
 from ..obs import scopes
 from ..ops.attention import _chunked_attention, local_attention
-from ..ops.grouped import grouped_matmul
+from ..ops.grouped import _TILE as ROW_TILE, grouped_matmul
 
 __all__ = ["RMSNorm", "GatedMLP", "causal_attention", "LatentAttention",
            "RoutedExperts", "MLAMoEBlock", "MLAMoELM", "mla_moe_lm", "COUNTERS"]
 
 COUNTERS = "counters"   # the flax collection the counters are sown into
+
+# The `d`-wide rows of `RoutedExperts` are bounded by this many times the
+# pairs a chip holds when the router is even.  The source balances its
+# experts by the selection bias (the fullest expert a few percent over the
+# mean in training; 1.45 here at seeded weights with nothing balancing, and
+# that is one expert: the chip's 8 together read 12,293 a layer against
+# 12,288, ledger, PR 34), so a chip whose share doubles is a step gone wrong,
+# and that step is exact too: it runs over all T·k rows.  A constant with
+# its reason, not a setting.
+_HELD_ROWS_OVER_EXPECTED = 2
+
+
+def _row_bound(pairs: int, held: int, n_experts: int) -> int:
+    """`C`: rows of the `d`-wide buffer for `pairs` (token, slot) pairs on a
+    chip that holds `held` of `n_experts`, whole row tiles of the grouped
+    product; `pairs` itself (no bound below the dropless one) from a share
+    of a half."""
+    tiles = -(-_HELD_ROWS_OVER_EXPECTED * pairs * held
+              // (n_experts * ROW_TILE))
+    return min(pairs, tiles * ROW_TILE)
 
 
 def _init(std: float):
@@ -203,6 +229,7 @@ class RoutedExperts(nn.Module):
                 picked.sum(-1, keepdims=True) + 1e-20)
 
         with jax.named_scope(scopes.MOE_DISPATCH):
+            # index work, over every pair whatever the routing
             local = chosen.reshape(-1) - self.expert_first       # (n*k,)
             here = (local >= 0) & (local < held)
             # held experts' pairs first, in expert order; absent last
@@ -211,32 +238,65 @@ class RoutedExperts(nn.Module):
             sizes = jnp.sum(group[:, None] == jnp.arange(held)[None, :],
                             0, dtype=jnp.int32)
             pairs_held = sizes.sum()
-            live = (jnp.arange(n * k) < pairs_held)[:, None]    # sorted rows
-            # a grouped product leaves the rows past the held count as
-            # they were in memory, forward and backward (ops/grouped.py):
-            # `where` keeps them out of the tokens' gradient here and out
-            # of the output below
-            rows = jnp.where(live, x[order // k], 0)    # (n*k, d)
-            gate_of_row = gates.reshape(-1)[order]
 
-        with jax.named_scope(scopes.MOE_EXPERTS):
-            act = nn.silu(grouped_matmul(rows, w_gate, sizes)) * (
-                grouped_matmul(rows, w_up, sizes))
-            y = grouped_matmul(act, w_down, sizes)
-            # masked BEFORE the gating product: its transpose multiplies
-            # the gates' cotangent by y, and 0 x (not finite) is not 0
-            y = jnp.where(live, y, 0) * gate_of_row[:, None].astype(y.dtype)
+        def over_rows(c, x, gates, w_gate, w_up, w_down):
+            """The `d`-wide work on the first `c` sorted rows, (n, d)
+            float32 out: every held pair's term when `pairs_held <= c`."""
+            with jax.named_scope(scopes.MOE_DISPATCH):
+                live = (jnp.arange(c) < pairs_held)[:, None]
+                pair = order[:c]
+                token = pair // k
+                # a grouped product leaves the rows past the held count
+                # as they were in memory, forward and backward
+                # (ops/grouped.py): `where` keeps them out of the tokens'
+                # gradient here and out of the output below
+                rows = jnp.where(live, x[token], 0)             # (c, d)
+                gate_of_row = gates.reshape(-1)[pair]
+            with jax.named_scope(scopes.MOE_EXPERTS):
+                act = nn.silu(grouped_matmul(rows, w_gate, sizes)) * (
+                    grouped_matmul(rows, w_up, sizes))
+                y = grouped_matmul(act, w_down, sizes)
+                # masked BEFORE the gating product: its transpose
+                # multiplies the gates' cotangent by y, and
+                # 0 x (not finite) is not 0
+                y = jnp.where(live, y, 0) * (
+                    gate_of_row[:, None].astype(y.dtype))
+            with jax.named_scope(scopes.MOE_COMBINE):
+                if c < n * k:
+                    # a token's at most k terms added where they lie (a
+                    # dead row adds its zero): the cost follows c, and the
+                    # transpose is a gather of c rows (v5e, 24,576 rows:
+                    # 3.0 ms and 0.65; gathered back through the inverse
+                    # permutation, clamped and masked, 3.1 and 11.5)
+                    return jnp.zeros((n, d), jnp.float32).at[token].add(
+                        y.astype(jnp.float32))
+                back = jnp.zeros_like(order).at[order].set(
+                    jnp.arange(n * k, dtype=order.dtype))
+                return y[back].reshape(n, k, d).astype(jnp.float32).sum(1)
 
-        with jax.named_scope(scopes.MOE_COMBINE):
-            back = jnp.zeros_like(order).at[order].set(
-                jnp.arange(n * k, dtype=order.dtype))
-            out = y[back].reshape(n, k, d).astype(jnp.float32).sum(1)
+        c = _row_bound(n * k, held, self.n_experts)
+        operands = (x, gates, w_gate, w_up, w_down)
+        if c == n * k:
+            compact = jnp.zeros((), jnp.float32)
+            out = over_rows(c, *operands)
+        else:
+            # a `cond` hands its backward the residuals of BOTH branches,
+            # the branch not taken writing zeros for its own (T·k rows,
+            # `d` wide): under `jax.checkpoint` a branch saves its
+            # operands and nothing else
+            fits = pairs_held <= c
+            compact = fits.astype(jnp.float32)
+            out = lax.cond(
+                fits, jax.checkpoint(functools.partial(over_rows, c)),
+                jax.checkpoint(functools.partial(over_rows, n * k)),
+                *operands)
 
         mean = pairs_held.astype(jnp.float32) / held
         self.sow(COUNTERS, "moe_pairs_held", pairs_held.astype(jnp.float32))
         self.sow(COUNTERS, "moe_load_max_over_mean",
                  jnp.where(mean > 0, sizes.max() / jnp.maximum(mean, 1e-9),
                            0.0))
+        self.sow(COUNTERS, "moe_compact", compact)
         return out.astype(self.dtype).reshape(b, t, d)
 
 
@@ -318,7 +378,8 @@ class MLAMoELM(nn.Module):
     # name -> how `train/lm.py` merges the counter over layers, micro-
     # batches and data ranks before it reports it in the step's metrics
     step_counters = {"moe_pairs_held": "sum",
-                     "moe_load_max_over_mean": "max"}
+                     "moe_load_max_over_mean": "max",
+                     "moe_compact": "mean"}
 
     @nn.compact
     def __call__(self, tokens, train: bool = True):

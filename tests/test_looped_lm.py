@@ -433,7 +433,14 @@ def test_other_lm_cells_steps_lower_to_the_parents_text(cell):
     for character, what the commit before the looped LM lowered
     (`tests/fixtures/lm_step_texts.json`: `lowered_step_sha1` of
     `_tiny_cells()` on a checkout of that commit; a PR that means to
-    change those steps records them anew and says so)."""
+    change those steps records them anew and says so).  PR 35 recorded
+    the Moonlight entry anew: its stand-in holds 4 of 8 experts, so
+    `RoutedExperts` has no row bound below its T·k pairs there and no
+    `cond`, and its text differs from the parent's by the new counter
+    alone (`moe_compact`: 18 scalar operations of its mean over layers,
+    micro-batches and ranks, every other operation and type as it was);
+    the step that does hold the `cond` is `test_mla_moe.py`'s, at 2 of
+    8 experts held.  The two StarCoder2 entries are PR 34's."""
     golden = json.loads(GOLDEN.read_text())
     if golden["jax"] != jax.__version__:
         pytest.skip(f"texts recorded under jax {golden['jax']}")

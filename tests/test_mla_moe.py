@@ -186,21 +186,75 @@ def _bias_towards(experts, n=8):
     return jnp.zeros((n,)).at[jnp.asarray(experts)].set(10.0)
 
 
-@pytest.mark.parametrize("onto,pairs,load", [
-    ((2, 0, 1), 32.0, 2.0),     # one held expert takes every token
-    ((2, 3, 0), 64.0, 1.0),     # both held experts take every token
-    ((0, 1, 4), 0.0, 0.0),      # absent experts only
-], ids=["one_held", "both_held", "absent_only"])
-def test_experts_forced_imbalance_drops_nothing(onto, pairs, load):
+# 640 tokens x 3 slots on a chip that holds experts 2 and 3 of 16: 1,920
+# pairs, 240 held by expectation, so the `d`-wide work is bounded by
+# C = 512 rows (`_row_bound`: twice 240, in whole row tiles) and a step
+# that holds more runs over all 1,920
+WIDE = dict(held=2, first=2, n=16, k=3)
+WIDE_TOKENS, WIDE_C = (2, 320), 512
+
+
+def _wide_case(onto=None, two=0, one=0):
+    """(layer, params, h) with the routing forced: by the selection bias
+    `onto` those experts for every token, or, token by token, `two`
+    tokens onto both held experts, `one` onto expert 2 alone and the rest
+    onto absent ones (two input features the router reads with weights of
+    4 where its others are 0.02: scores of 0.98 and 0.02, so the gates
+    keep a gradient) -- 2 x two + one pairs held, to the pair."""
+    layer = _experts(**WIDE)
+    h = jax.random.normal(jax.random.PRNGKey(5), (*WIDE_TOKENS, 32))
+    params = layer.init(jax.random.PRNGKey(0), h)["params"]
+    if onto is not None:
+        return layer, {**params, "score_bias": _bias_towards(onto, 16)}, h
+    if not two and not one:
+        return layer, params, h                     # as seeded
+    tokens = h.shape[0] * h.shape[1]
+    kind = jax.random.permutation(jax.random.PRNGKey(6), jnp.asarray(
+        [2] * two + [1] * one + [0] * (tokens - two - one))
+    ).reshape(h.shape[:2])
+    h = h.at[..., 0].set(jnp.where(kind == 2, 1.0,
+                                   jnp.where(kind == 0, -1.0, 0.0)))
+    h = h.at[..., 1].set(jnp.where(kind == 1, 1.0, 0.0))
+    router = 0.02 * jax.random.normal(jax.random.PRNGKey(7), (32, 16))
+    router = router.at[:2].set(0.0).at[0, 2:4].set(4.0)
+    router = router.at[1, 2].set(4.0).at[1, 3].set(-4.0)
+    return layer, {**params, "router": router}, h
+
+
+def _apply(layer, params, h):
+    out, sown = jax.jit(lambda p, h: layer.apply(
+        {"params": p}, h, mutable=[mm.COUNTERS]))(params, h)
+    return out, {k: float(v[0]) for k, v in sown[mm.COUNTERS].items()}
+
+
+# compact: 1.0 where the step must run over C rows, 0.0 where it must run
+# over all T·k (the fallback, or a layer with no bound below T·k)
+@pytest.mark.parametrize("wide,onto,pairs,load,compact", [
+    (False, (2, 0, 1), 32.0, 2.0, 0.0),  # one held expert takes every token
+    (False, (2, 3, 0), 64.0, 1.0, 0.0),  # both held experts take every token
+    (False, (0, 1, 4), 0.0, 0.0, 0.0),   # absent experts only
+    (True, (2, 0, 1), 640.0, 2.0, 0.0),  # 640 > C: every row, on T·k rows
+    (True, (2, 3, 0), 1280.0, 1.0, 0.0),
+    (True, (0, 1, 4), 0.0, 0.0, 1.0),    # nothing held fits any bound
+], ids=["one_held", "both_held", "absent_only", "wide_one_held_fallback",
+        "wide_both_held_fallback", "wide_absent_only_compact"])
+def test_experts_forced_imbalance_drops_nothing(wide, onto, pairs, load,
+                                                compact):
     """Every token forced onto chosen experts through the selection bias:
     no row is dropped (the output is the reference's, whose dense passes
-    cannot drop), the counters are exact, and absent experts add zero."""
-    layer, params, h, _, _ = _expert_case()
-    params = {**params, "score_bias": _bias_towards(onto)}
-    out, sown = layer.apply({"params": params}, h, mutable=[mm.COUNTERS])
-    counts = {k: float(v[0]) for k, v in sown[mm.COUNTERS].items()}
+    cannot drop), the counters are exact, absent experts add zero, and
+    the step took the branch the case names: a layer of 2 held experts of
+    8 at 96 pairs has no bound below them; the wide layer (`WIDE`) bounds
+    its rows by C = 512 and holds 640 and 1,280 pairs here."""
+    if wide:
+        layer, params, h = _wide_case(onto=onto)
+    else:
+        layer, params, h, _, _ = _expert_case()
+        params = {**params, "score_bias": _bias_towards(onto)}
+    out, counts = _apply(layer, params, h)
     assert counts == {"moe_pairs_held": pairs,
-                      "moe_load_max_over_mean": load}
+                      "moe_load_max_over_mean": load,
+                      "moe_compact": compact}
     want = _ref_routed(h, params, 2, 2)
     if pairs:
         assert rel(out, want) < 1e-5
@@ -208,17 +262,113 @@ def test_experts_forced_imbalance_drops_nothing(onto, pairs, load):
     else:
         assert float(jnp.abs(out).max()) == 0.0 == float(jnp.abs(want).max())
     # the bias has no gradient, the router and the held experts do
-    g = jax.grad(lambda p: jnp.sum(layer.apply(
-        {"params": p}, h, mutable=[mm.COUNTERS])[0] ** 2))(params)
+    g = jax.jit(jax.grad(lambda p: jnp.sum(layer.apply(
+        {"params": p}, h, mutable=[mm.COUNTERS])[0] ** 2)))(params)
     assert float(jnp.abs(g["score_bias"]).max()) == 0.0
     assert (float(jnp.abs(g["experts_down"]).max()) > 0) == bool(pairs)
 
 
-def test_rows_past_the_held_count_never_reach_a_gradient():
+def _grads_match_reference(layer, params, h):
+    """Gradients with respect to parameters (router, all three expert
+    tensors) AND tokens are finite and the reference's."""
+    def loss(p, h):
+        return jnp.sum(layer.apply({"params": p}, h,
+                                   mutable=[mm.COUNTERS])[0] ** 2)
+
+    got = jax.jit(jax.grad(loss, (0, 1)))(params, h)
+    want = jax.jit(jax.grad(
+        lambda p, h: jnp.sum(_ref_routed(h, p, 2, 2) ** 2), (0, 1)))(
+            params, h)
+    for name in ("router", "experts_gate", "experts_up", "experts_down"):
+        assert float(jnp.abs(want[0][name]).max()) > 0, name
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                            jax.tree.leaves(want)):
+        assert bool(jnp.isfinite(a).all()), path
+        assert rel(a, b) < 1e-5 or float(jnp.abs(b).max()) == 0.0, path
+
+
+@pytest.mark.parametrize("two,one,compact", [
+    (255, 1, 1.0),      # C - 1 pairs held
+    (256, 0, 1.0),      # C: the last row of the buffer is live
+    (256, 1, 0.0),      # C + 1: one pair too many for it, so all T·k rows
+    (0, 0, 1.0),        # as seeded: about 240
+], ids=["bound_less_one", "at_bound", "bound_plus_one", "seeded"])
+def test_compact_and_fallback_match_reference_around_the_bound(two, one,
+                                                               compact):
+    """The branch is chosen by the step's own count, to the pair, and both
+    give the reference's output and gradients."""
+    layer, params, h = _wide_case(two=two, one=one)
+    out, counts = _apply(layer, params, h)
+    if two or one:
+        assert counts["moe_pairs_held"] == 2 * two + one
+    else:
+        assert 0 < counts["moe_pairs_held"] < WIDE_C
+    assert counts["moe_compact"] == compact
+    assert rel(out, _ref_routed(h, params, 2, 2)) < 1e-5
+    _grads_match_reference(layer, params, h)
+
+
+@pytest.mark.parametrize("pairs,held,n_experts,bound", [
+    (98304, 8, 64, 24576),      # the Moonlight cell: a quarter of T·k
+    (98304, 32, 64, 98304),     # a share of a half: no bound below T·k
+    (98304, 64, 64, 98304),
+    (96, 2, 8, 96),             # under one row tile
+    (768, 2, 8, 512), (1920, 2, 16, 512), (2304, 2, 8, 1536),
+    (98304, 1, 64, 3072), (100000, 8, 64, 25088),
+])
+def test_row_bound(pairs, held, n_experts, bound):
+    """Twice the pairs held by expectation, in whole row tiles of the
+    grouped product (512), and never over the dropless T·k."""
+    assert mm._row_bound(pairs, held, n_experts) == bound
+    assert bound == pairs or (bound % 512 == 0
+                              and bound >= 2 * pairs * held / n_experts)
+
+
+def _conds(jaxpr) -> int:
+    """`cond` equations of a jaxpr, nested ones too, a Pallas kernel's own
+    left out (the interpreter's `pl.when`)."""
+    from jax.extend import core as jex
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        count += eqn.primitive.name == "cond"
+        for sub in jax.tree.leaves(
+                list(eqn.params.values()),
+                is_leaf=lambda v: isinstance(v, (jex.Jaxpr, jex.ClosedJaxpr))):
+            if isinstance(sub, (jex.Jaxpr, jex.ClosedJaxpr)):
+                count += _conds(getattr(sub, "jaxpr", sub))
+    return count
+
+
+@pytest.mark.parametrize("held,n,tokens,branches", [
+    (8, 8, (2, 16), 0),     # every expert held: nothing to bound
+    (8, 16, WIDE_TOKENS, 0),    # a share of a half
+    (2, 8, (2, 16), 0),     # 96 pairs: under one row tile
+    (2, 16, WIDE_TOKENS, 1),
+])
+def test_a_layer_with_no_bound_below_the_pairs_traces_without_a_branch(
+        held, n, tokens, branches):
+    """Where C == T·k the module is the program it was (and the counter):
+    no conditional of its own; where C < T·k there is one.  (Read from the
+    jaxpr: the lowered text of a CPU run holds the kernels' interpreter,
+    whose `pl.when`s are conditionals too.)"""
+    layer = _experts(held=held, first=0, n=n)
+    h = jax.ShapeDtypeStruct((*tokens, 32), jnp.float32)
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros(h.shape)))["params"]
+    jaxpr = jax.make_jaxpr(lambda p, h: layer.apply(
+        {"params": p}, h, mutable=[mm.COUNTERS]))(params, h)
+    assert _conds(jaxpr.jaxpr) == branches
+
+
+@pytest.mark.parametrize("case", ["no_bound", "compact", "fallback"])
+def test_rows_past_the_held_count_never_reach_a_gradient(case):
     """A grouped product leaves the rows past its groups unwritten, in the
     product and in the backward's (`ops/grouped.py`; the interpreter
     leaves NaN there, a TPU what the memory held).  Gradients with
-    respect to parameters AND inputs are finite and the reference's."""
+    respect to parameters AND inputs are finite and the reference's, over
+    T·k rows and over C, in both branches."""
     from cpd_tpu.ops.grouped import grouped_matmul
     x = jax.random.normal(jax.random.PRNGKey(0), (96, 32))
     w = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 24))
@@ -228,18 +378,18 @@ def test_rows_past_the_held_count_never_reach_a_gradient():
                                atol=1e-5)
     assert not bool(jnp.isfinite(got[33:]).any())   # what a caller masks
 
-    layer, params, h, _, _ = _expert_case()
-
-    def loss(p, h):
-        return jnp.sum(layer.apply({"params": p}, h,
-                                   mutable=[mm.COUNTERS])[0] ** 2)
-
-    got = jax.grad(loss, (0, 1))(params, h)
-    want = jax.grad(lambda p, h: jnp.sum(_ref_routed(h, p, 2, 2) ** 2),
-                    (0, 1))(params, h)
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        assert bool(jnp.isfinite(a).all())
-        assert rel(a, b) < 1e-5 or float(jnp.abs(b).max()) == 0.0
+    if case == "no_bound":
+        layer, params, h, _, counts = _expert_case()
+        compact = 0.0
+    else:
+        # seeded routing leaves half of the C rows dead; 600 pairs held
+        # leave 1,320 of the T·k rows dead
+        layer, params, h = _wide_case(**({} if case == "compact"
+                                         else dict(two=300)))
+        compact = float(case == "compact")
+        counts = _apply(layer, params, h)[1]
+    assert counts["moe_compact"] == compact
+    _grads_match_reference(layer, params, h)
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -336,20 +486,30 @@ def _state(model, tx, a):
                       batch_stats={}, opt_state=tx.init(params))
 
 
-@pytest.mark.parametrize("dp", [1, 4])
-def test_step_with_e5m2_aps_reports_the_counters(dp):
+def _aps_step(model, dp, a):
+    mesh = make_mesh(dp=dp, devices=jax.devices()[:dp])
+    tx = make_optimizer("sgd", lambda step: 0.01, momentum=0.9,
+                        weight_decay=0.0)
+    state = _state(model, tx, a)
+    return state, make_lm_train_step(
+        model, tx, mesh, use_aps=True, grad_exp=5, grad_man=2,
+        mode="faithful", donate=False)
+
+
+# 16-token sequences: 2 of 8 experts held, 192 and 48 pairs a rank, under
+# one row tile, so the layer has no bound below them and reports 0; at 192
+# tokens a sequence a rank bounds its 2,304 pairs by 1,536 rows and its 576
+# by 512, holds a quarter of them by expectation and reports 1
+@pytest.mark.parametrize("dp,t,compact", [(1, 16, 0.0), (4, 16, 0.0),
+                                          (1, 192, 1.0), (4, 192, 1.0)])
+def test_step_with_e5m2_aps_reports_the_counters(dp, t, compact):
     """The same entry point as the dense LM cells, e5m2 APS, on 1 and on 4
     devices over `dp`: the loss is the reference's, the counters are in
     the metrics and are the whole batch's, the update is near the
     reference's SGD step (e5m2's rounding: 0.053 of an element)."""
     model = model_of(remat=True)
-    mesh = make_mesh(dp=dp, devices=jax.devices()[:dp])
-    tx = make_optimizer("sgd", lambda step: 0.01, momentum=0.9,
-                        weight_decay=0.0)
-    a, b = batch(b=4)
-    state = _state(model, tx, a)
-    step = make_lm_train_step(model, tx, mesh, use_aps=True, grad_exp=5,
-                              grad_man=2, mode="faithful", donate=False)
+    a, b = batch(b=4, t=t)
+    state, step = _aps_step(model, dp, a)
     new, metrics = step(state, a, b)
     want_loss, g = REF_GRAD(state.params, a, b)
     assert abs(float(metrics["loss"]) - float(want_loss)) < 1e-5
@@ -359,6 +519,7 @@ def test_step_with_e5m2_aps_reports_the_counters(dp):
                 for b in sown[mm.COUNTERS].values()]
     assert float(metrics["moe_pairs_held"]) == sum(by_layer) > 0
     assert float(metrics["moe_load_max_over_mean"]) >= 1.0
+    assert float(metrics["moe_compact"]) == compact
     moved = jax.tree.map(lambda n, o, gg: (n - o, -0.01 * gg), new.params,
                          state.params, g)
     num = sum(float(jnp.sum((d - w) ** 2)) for d, w in
@@ -366,6 +527,20 @@ def test_step_with_e5m2_aps_reports_the_counters(dp):
     den = sum(float(jnp.sum(w ** 2)) for _, w in
               jax.tree.leaves(moved, is_leaf=lambda x: isinstance(x, tuple)))
     assert (num / den) ** 0.5 < (0.08 if dp == 1 else 0.16)
+
+
+@pytest.mark.parametrize("t,branches", [(16, 0), (128, 3)])
+def test_a_tiny_step_at_2_of_8_experts_held_traces_the_branch(t, branches):
+    """768 pairs bounded by 512 rows: the whole step (loss, `nn.remat`,
+    gradient, APS, update) holds the layer's conditional three times: the
+    forward's, the one jax's partial evaluation leaves of `nn.remat`'s
+    recomputation (its branches hand their operands on and compute
+    nothing: each saves only those), and the backward's, which recomputes
+    and transposes inside the branch taken; at 96 pairs it holds none."""
+    model = model_of(remat=True)
+    a, b = batch(b=2, t=t)
+    state, step = _aps_step(model, 1, a)
+    assert _conds(jax.make_jaxpr(step)(state, a, b).jaxpr) == branches
 
 
 def test_counter_plumbing_leaves_a_dense_lm_step_as_it_was():

@@ -34,6 +34,9 @@ Checks (bitwise vs the XLA composition unless noted):
      (plain / digest / blocked / multi-tile) and digest_rows: the three
      kernels `--mode ring` selects by default on TPU
   8. fused gather -> unpack -> attention (ServeEngine fused_attn=True)
+  9. grouped_matmul (ops/grouped.py: megablox `gmm`/`tgmm`) at the row
+     bound of the Moonlight cell's expert layers, forward and both
+     gradients against `lax.ragged_dot` on the live rows (allclose)
 """
 
 from __future__ import annotations
@@ -471,6 +474,50 @@ def check_fused_gather_attention(rng):
     return bad
 
 
+def check_grouped_matmul(rng):
+    """24,576 rows (`models/mla_moe.py:_row_bound` in the Moonlight cell),
+    12,288 of them live in 8 uneven groups, bf16, both of an expert's
+    shapes; the rows past the groups are the caller's to mask and are
+    left out here."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from cpd_tpu.ops.grouped import grouped_matmul
+
+    rows, live = 24576, 12288
+    sizes = np.asarray([2200, 1536, 900, 1536, 1700, 1536, 1344, 1536])
+    assert sizes.sum() == live
+    sizes = jnp.asarray(sizes, jnp.int32)
+    bad = []
+    for k, n in [(2048, 1408), (1408, 2048)]:
+        x = jnp.asarray(rng.randn(rows, k), jnp.bfloat16)
+        w = jnp.asarray(rng.randn(8, k, n) * 0.02, jnp.bfloat16)
+        cot = jnp.asarray(rng.randn(live, n), jnp.float32)
+
+        def loss(f):
+            return lambda x, w: jnp.sum(
+                f(x, w)[:live].astype(jnp.float32) * cot)
+
+        ours = lambda x, w: grouped_matmul(x, w, sizes)
+        ref = lambda x, w: jax.lax.ragged_dot(x[:live], w, sizes)
+        f32 = lambda a: np.asarray(a.astype(jnp.float32))
+        diff = _close(f32(jax.jit(ours)(x, w)[:live]),
+                      f32(jax.jit(ref)(x, w)), 2e-2)
+        if diff:
+            bad.append(f"{k}->{n} forward {diff}")
+        grads = lambda f: jax.jit(jax.grad(loss(f), (0, 1)))(x, w)
+        for name, a, b in zip(("d lhs", "d rhs"), grads(ours), grads(ref)):
+            # a gradient is held to 5e-2 of the LARGEST element, as bf16
+            # sums of 1,536 terms in another order differ by an ulp (of
+            # d lhs the live rows: the others are the caller's to mask)
+            a, b = (f32(g[:live] if name == "d lhs" else g) for g in (a, b))
+            scale = float(np.abs(b).max())
+            diff = _close(a / scale, b / scale, 5e-2)
+            if diff:
+                bad.append(f"{k}->{n} {name} {diff} of the largest")
+    return bad
+
+
 def checks() -> list:
     """(status-line name, check function) in run order."""
     out = [
@@ -502,7 +549,8 @@ def checks() -> list:
                             + (" +digest" if dig else ""))
                     out.append((name, _wire(fmt, block, n, dig)))
     out += [("digest_rows_pallas", check_digest_rows),
-            ("fused_gather_attention", check_fused_gather_attention)]
+            ("fused_gather_attention", check_fused_gather_attention),
+            ("grouped_matmul 24,576 rows", check_grouped_matmul)]
     return out
 
 
