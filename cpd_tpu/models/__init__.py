@@ -15,6 +15,7 @@ from .pipeline_lm import PipelinedLM, pipelined_lm, pp_param_specs
 from .moe import MoETransformerLM, moe_lm, moe_param_specs
 from .mla_moe import MLAMoELM, mla_moe_lm
 from .looped import LoopedLM, looped_lm
+from .conv_moe import ConvMoELM, conv_moe_lm
 from .davidnet_graph import graph_davidnet
 from .generate import generate
 from .vit import ViT, vit
@@ -34,6 +35,7 @@ _REGISTRY = {
     "moe_lm": moe_lm,
     "mla_moe_lm": mla_moe_lm,         # latent attention + routed experts
     "looped_lm": looped_lm,           # one stack run n_loops times, exit gate
+    "conv_moe_lm": conv_moe_lm,       # short convolutions + QK-normed GQA
     "davidnet_graph": graph_davidnet,  # dict-graph definition (TorchGraph)
     "vit": vit,                       # RoPE-ViT encoder (models/vit.py)
 }
@@ -53,4 +55,5 @@ __all__ = ["ResNetCIFAR", "resnet18_cifar", "DavidNet", "davidnet",
            "PipelinedLM", "pipelined_lm", "pp_param_specs",
            "MoETransformerLM", "moe_lm", "moe_param_specs",
            "MLAMoELM", "mla_moe_lm", "LoopedLM", "looped_lm",
+           "ConvMoELM", "conv_moe_lm",
            "graph_davidnet", "generate", "get_model"]

@@ -49,7 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..obs import scopes
-from ..ops.attention import _chunked_attention, local_attention
+from ..ops.attention import _chunked_attention, grouped_query_attention
 from ..ops.grouped import _TILE as ROW_TILE, grouped_matmul
 
 __all__ = ["RMSNorm", "GatedMLP", "causal_attention", "LatentAttention",
@@ -102,9 +102,11 @@ def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float):
 
 
 def causal_attention(q, k, v, impl: str):
-    """Causal softmax attention on (B, T, H, D) by the path `impl` names:
-    "flash" (the Pallas kernels of ops/flash_gqa.py), "chunked" (the
-    online-softmax scan) or "xla"."""
+    """Causal softmax attention of (B, T, H, D) queries against (B, T,
+    H_kv, D) keys and (B, T, H_kv, Dv) values, H_kv dividing H (a group
+    of query heads shares a key head), by the path `impl` names: "flash"
+    (the Pallas kernels of ops/flash_gqa.py), "chunked" (the online-
+    softmax scan) or "xla"."""
     if impl == "flash":
         from ..ops.flash_gqa import flash_gqa
         return flash_gqa(q, k, v, True)
@@ -117,7 +119,7 @@ def causal_attention(q, k, v, impl: str):
         return one(q, k, v) if q.shape[0] == 1 else lax.map(
             lambda x: one(*(y[None] for y in x))[0], (q, k, v))
     if impl == "xla":
-        return local_attention(q, k, v, causal=True)
+        return grouped_query_attention(q, k, v, causal=True)
     raise ValueError(f"unknown attn_impl {impl!r}; "
                      "expected 'xla', 'flash' or 'chunked'")
 

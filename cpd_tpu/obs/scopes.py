@@ -30,6 +30,7 @@ Stdlib-only, like the rest of `obs`.
 | `cpd.moe_shared` / `cpd.dense_mlp` | `models/mla_moe.py` | the shared expert / a leading layer's dense gated MLP |
 | `cpd.loop_attn` / `cpd.loop_mlp` | `models/looped.py:LoopBlock` | a looped block's attention (norms N1 and N2, projections, rotary; the kernels' scopes nest under it) / its gated MLP with norms N3 and N4; every pass of the loop |
 | `cpd.loop_exit` | `models/looped.py:LoopedLM` | a pass's exit: final norm, gate, head and cross-entropy, and the exit weighting of the loss |
+| `cpd.conv_mixer` / `cpd.gqa_attn` | `models/conv_moe.py:ConvMoEBlock` | a conv layer's mixer: norm N1, `in_proj`, gating, the causal taps, `out_proj` and the residual add / an attention layer's mixer: N1, projections, the per-head q and k norms, rotary, `out_proj` and the residual add (the kernels' scopes nest under it); the feed-forward parts keep `cpd.dense_mlp` and `cpd.moe_*` |
 | `kernel.<name>` | `ops/*.py`, around each `pl.pallas_call` | one Pallas kernel; the call's `name=` is the same `<name>` |
 
 Ownership, as the reader applies it: an operation belongs to the LAST
@@ -44,6 +45,7 @@ from __future__ import annotations
 __all__ = ["LOSS_GRAD", "EMULATE_NODE", "REDUCE", "OPTIMIZER", "METRICS",
            "MLA", "MOE_ROUTER", "MOE_DISPATCH", "MOE_EXPERTS", "MOE_COMBINE",
            "MOE_SHARED", "DENSE_MLP", "LOOP_ATTN", "LOOP_MLP", "LOOP_EXIT",
+           "CONV_MIXER", "GQA_ATTN",
            "APS_MAX_EXP", "APS_SCALE", "APS_UNSCALE", "WIRE_CAST",
            "WIRE_PACK", "WIRE_UNPACK", "WIRE_COLLECTIVE", "REDUCE_SCAN",
            "REDUCE_LOCAL",
@@ -68,6 +70,10 @@ DENSE_MLP = "cpd.dense_mlp"
 LOOP_ATTN = "cpd.loop_attn"
 LOOP_MLP = "cpd.loop_mlp"
 LOOP_EXIT = "cpd.loop_exit"
+
+# model layers (models/conv_moe.py); all nest under LOSS_GRAD
+CONV_MIXER = "cpd.conv_mixer"
+GQA_ATTN = "cpd.gqa_attn"
 
 APS_MAX_EXP = "aps.max_exp"
 APS_SCALE = "aps.scale"

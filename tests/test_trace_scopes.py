@@ -242,6 +242,39 @@ def test_looped_model_layers_are_named_under_the_loss_grad_scope():
         assert (scopes.LOSS_GRAD, scopes.LOOP_ATTN, kernel) in paths, kernel
 
 
+def test_conv_attention_hybrid_layers_are_named_under_the_loss_grad_scope():
+    """`models/conv_moe.py` names a conv layer's mixer and an attention
+    layer's; through the LM step each is nested under `cpd.loss_grad`, the
+    flash kernels' scopes under `cpd.gqa_attn`, and the feed-forward parts
+    keep the scopes of `models/mla_moe.py`."""
+    from cpd_tpu.models import conv_moe_lm
+    from cpd_tpu.train import make_lm_train_step, make_optimizer
+    from cpd_tpu.train.state import TrainState
+    mesh = make_mesh(dp=1, devices=jax.devices()[:1])
+    kw = dict(vocab_size=64, d_model=32, n_layers=3, n_heads=4, n_kv_heads=2,
+              d_ff=48, layer_types=("conv", "full_attention", "conv"),
+              n_experts=8, experts_held=4, top_k=2, moe_d_ff=24, remat=True)
+    model = conv_moe_lm(**kw, attn_impl="flash")
+    tx = make_optimizer("sgd", lambda step: 0.01, momentum=0.9)
+    toks = jnp.zeros((2, 16), jnp.int32)
+    params = conv_moe_lm(**kw).init(jax.random.PRNGKey(0), toks)["params"]
+    state = TrainState(step=jnp.zeros([], jnp.int32), params=params,
+                       batch_stats={}, opt_state=tx.init(params))
+    step = jax.jit(make_lm_train_step(model, tx, mesh, use_aps=True,
+                                      grad_exp=5, grad_man=2, donate=False))
+    paths = scope_paths(step.lower(state, toks, toks).compile())
+    found = {c for p in paths for c in p}
+    SEEN.update(found)
+    layers = {scopes.CONV_MIXER, scopes.GQA_ATTN, scopes.DENSE_MLP,
+              scopes.MOE_ROUTER, scopes.MOE_EXPERTS}
+    assert found <= KNOWN and layers <= found
+    for layer in layers:
+        assert (scopes.LOSS_GRAD, layer) in {p[:2] for p in paths}, layer
+    for kernel in (scopes.KERNEL_FLASH_GQA_FWD, scopes.KERNEL_FLASH_GQA_BWD_DQ,
+                   scopes.KERNEL_FLASH_GQA_BWD_DKV):
+        assert (scopes.LOSS_GRAD, scopes.GQA_ATTN, kernel) in paths, kernel
+
+
 def test_every_scope_is_used_and_lives_in_one_place():
     """Runs after the parametrised cases (same file, same worker): every
     non-kernel constant showed up in some compiled program; every kernel

@@ -28,8 +28,8 @@ Checks (bitwise vs the XLA composition unless noted):
   5. chunked attention — pure XLA; cross-checked against 3 on the chip
   6. flash_gqa — forward (incl. a short-Tq case, bq < 128; output and
      lse at the benchmark cells' own shapes and block lengths and at two
-     ragged ones) and its Pallas backward, at small shapes and at the
-     cells' own (allclose)
+     ragged ones; the pad columns of a head of 64 read 0) and its Pallas
+     backward, at small shapes and at the cells' own (allclose)
   7. the ring's wire kernels — quantize_add, quantize_pack, hop_pack
      (plain / digest / blocked / multi-tile) and digest_rows: the three
      kernels `--mode ring` selects by default on TPU
@@ -209,6 +209,7 @@ def _flash_gqa_fwd(shapes):
 _FWD_CELL_SHAPES = [(2, 8192, 8192, 16, 16, 192, 128),
                     (2, 4096, 4096, 24, 2, 128, 128),
                     (2, 4096, 4096, 16, 16, 128, 128),
+                    (2, 8192, 8192, 32, 8, 64, 64),
                     (1, 2500, 3300, 4, 2, 192, 128),
                     (1, 3300, 2500, 24, 2, 128, 128)]
 
@@ -264,6 +265,41 @@ def check_flash_gqa_fwd_cells(rng, shapes=_FWD_CELL_SHAPES):
     return bad
 
 
+def check_flash_gqa_pad_columns(rng):
+    """A head narrower than 128 lanes reaches the forward kernel padded
+    with zero columns (`_q_layout`, `_kv_layout`), and the kernel's output
+    columns past the head's width are sliced off: on the chip they must
+    read 0, which the interpreter's zeros cannot show.  Inputs handed over
+    already 128 wide, zero past column 64, are the arrays the kernel gets
+    for heads of 64 (only the softmax scale differs), and their output is
+    the kernel's own, every column; LFM2's heads (32 on 8 key heads of
+    64), a causal sequence of 2,048 in bf16."""
+    import sys
+
+    import jax.numpy as jnp
+    import numpy as np
+    from cpd_tpu.ops.attention import _chunked_attention
+    import cpd_tpu.ops.flash_gqa  # noqa: F401  (the attribute is a function)
+    fg = sys.modules["cpd_tpu.ops.flash_gqa"]
+
+    pad = lambda x: jnp.pad(x.astype(jnp.bfloat16),
+                            ((0, 0), (0, 0), (0, 0), (0, 64)))
+    q, k, v = (pad(x) for x in _qkv(rng, 1, 2048, 2048, 32, 8, 64))
+    out, _ = fg._flash_gqa_fwd_call(q, k, v, True, fg.interpret_mode())
+    out = np.asarray(out.astype(jnp.float32))
+    bad = []
+    if np.any(out[..., 64:] != 0):
+        bad.append(f"pad columns: {np.count_nonzero(out[..., 64:])} of "
+                   f"{out[..., 64:].size} not 0, largest "
+                   f"{np.abs(out[..., 64:]).max()}")
+    want = np.asarray(_chunked_attention(q, k, v, True, 0, 0).astype(
+        jnp.float32))
+    diff = _close(out[..., :64], want[..., :64], 2e-2)
+    if diff:
+        bad.append(f"columns 0-63 {diff}")
+    return bad
+
+
 def _flash_gqa_bwd():
     """The Pallas gradient against the exact XLA one at small float32
     shapes, square and ragged, with a v of its own width, then against the
@@ -316,11 +352,13 @@ def _flash_gqa_bwd():
         # a sequence (the chunked gradient of two keeps 8.7 GiB of
         # temporaries), StarCoder2's, the looped LM's (a head of 128 for
         # every key head: the kernels' native width, no group, no
-        # padding), and StarCoder2's group of twelve ragged
+        # padding), LFM2's a sequence (heads of 64 padded to 128, groups
+        # of four), and StarCoder2's group of twelve ragged
         for (bsz, t, tk, h, hkv, d, dv) in [
                 (1, 8192, 8192, 16, 16, 192, 128),
                 (2, 4096, 4096, 24, 2, 128, 128),
                 (2, 4096, 4096, 16, 16, 128, 128),
+                (1, 8192, 8192, 32, 8, 64, 64),
                 (1, 4000, 1500, 24, 2, 128, 128)]:
             q, k, v = (x.astype(jnp.bfloat16)
                        for x in _qkv(rng, bsz, t, tk, h, hkv, d))
@@ -535,6 +573,8 @@ def checks() -> list:
         ("flash_gqa fwd short-Tq", _flash_gqa_fwd(
             [(8, 128, 4, 2, 64, True), (40, 256, 8, 2, 64, False)])),
         ("flash_gqa fwd cells' shapes", check_flash_gqa_fwd_cells),
+        ("flash_gqa fwd pad columns of heads of 64",
+         check_flash_gqa_pad_columns),
         ("flash_gqa bwd", _flash_gqa_bwd()),
         ("quantize_add_pallas[_bits]", check_quantize_add),
     ]
