@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import scopes
+from ..ops.flash_gqa import KEEP_FLASH_RESIDUALS
 from .mla_moe import (GatedMLP, RMSNorm, RoutedExperts, _dense, _init, _rope,
                       causal_attention)
 
@@ -177,7 +178,8 @@ class ConvMoELM(nn.Module):
     moe_d_ff: int = 256
     routed_scaling: float = 1.0
     init_std: float = 0.02
-    remat: bool = False             # jax.checkpoint each block
+    remat: bool = False             # jax.checkpoint each block but
+                                    # its flash kernel's results
     attn_impl: str = "xla"
     dtype: Any = jnp.float32
 
@@ -196,7 +198,8 @@ class ConvMoELM(nn.Module):
         embed = nn.Embed(self.vocab_size, self.d_model, dtype=jnp.float32,
                          embedding_init=_init(self.init_std), name="embed")
         x = embed(tokens).astype(self.dtype)
-        block_cls = nn.remat(ConvMoEBlock) if self.remat else ConvMoEBlock
+        block_cls = (nn.remat(ConvMoEBlock, policy=KEEP_FLASH_RESIDUALS)
+                     if self.remat else ConvMoEBlock)
         kw = dict(
             n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
             head_dim=self.d_model // self.n_heads,

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import optax
 
 from ..obs import scopes
+from ..ops.flash_gqa import KEEP_FLASH_RESIDUALS
 from .mla_moe import (COUNTERS, GatedMLP, RMSNorm, _dense, _init, _rope,
                       causal_attention)
 
@@ -114,7 +115,8 @@ class LoopedLM(nn.Module):
     rope_theta: float = 10000.0
     eps: float = 1e-6
     init_std: float = 0.02
-    remat: bool = False             # jax.checkpoint each block
+    remat: bool = False             # jax.checkpoint each block but
+                                    # its flash kernel's results
     attn_impl: str = "xla"
     dtype: Any = jnp.float32
 
@@ -127,7 +129,8 @@ class LoopedLM(nn.Module):
         self.embed = nn.Embed(self.vocab_size, self.d_model,
                               dtype=self.dtype,
                               embedding_init=_init(self.init_std))
-        block_cls = nn.remat(LoopBlock) if self.remat else LoopBlock
+        block_cls = (nn.remat(LoopBlock, policy=KEEP_FLASH_RESIDUALS)
+                     if self.remat else LoopBlock)
         for i in range(self.n_layers):
             setattr(self, f"block{i}", block_cls(
                 self.n_heads, self.d_ff, self.rope_theta, self.eps,

@@ -50,6 +50,7 @@ from jax import lax
 
 from ..obs import scopes
 from ..ops.attention import _chunked_attention, grouped_query_attention
+from ..ops.flash_gqa import KEEP_FLASH_RESIDUALS
 from ..ops.grouped import _TILE as ROW_TILE, grouped_matmul
 
 __all__ = ["RMSNorm", "GatedMLP", "causal_attention", "LatentAttention",
@@ -373,7 +374,8 @@ class MLAMoELM(nn.Module):
     n_shared_experts: int = 1
     routed_scaling: float = 1.0
     init_std: float = 0.02
-    remat: bool = False             # jax.checkpoint each block
+    remat: bool = False             # jax.checkpoint each block but
+                                    # its flash kernel's results
     attn_impl: str = "xla"
     dtype: Any = jnp.float32
 
@@ -390,7 +392,8 @@ class MLAMoELM(nn.Module):
         x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
                      embedding_init=_init(self.init_std),
                      name="embed")(tokens)
-        block_cls = nn.remat(MLAMoEBlock) if self.remat else MLAMoEBlock
+        block_cls = (nn.remat(MLAMoEBlock, policy=KEEP_FLASH_RESIDUALS)
+                     if self.remat else MLAMoEBlock)
         held = (self.n_experts if self.experts_held is None
                 else self.experts_held)
         kw = dict(

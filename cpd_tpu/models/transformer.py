@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import (grouped_query_attention, local_attention,
                              ring_attention, ulysses_attention)
+from ..ops.flash_gqa import KEEP_FLASH_RESIDUALS
 
 __all__ = ["TransformerLM", "transformer_lm", "lm_param_specs"]
 
@@ -279,7 +280,9 @@ class TransformerLM(nn.Module):
     remat: bool = False     # jax.checkpoint each block: activations are
                             # recomputed in backward instead of stored —
                             # O(sqrt) activation memory for deep stacks,
-                            # the standard TPU HBM<->FLOPs trade
+                            # the standard TPU HBM<->FLOPs trade; the
+                            # flash kernel's results are kept
+                            # (`ops/flash_gqa.py:KEEP_FLASH_RESIDUALS`)
     scan_layers: bool = False   # ONE nn.scan'd block instead of a Python
                                 # loop: layer body traced/compiled once
                                 # regardless of depth; params gain a
@@ -327,7 +330,8 @@ class TransformerLM(nn.Module):
         # inside scan) — keeping them would wedge optimization-barrier ops
         # into the one scanned layer body.
         if self.remat and not self.decode:
-            block_cls = nn.remat(Block, prevent_cse=not self.scan_layers)
+            block_cls = nn.remat(Block, prevent_cse=not self.scan_layers,
+                                 policy=KEEP_FLASH_RESIDUALS)
         else:
             block_cls = Block
         block_kw = dict(head_dim=head_dim, d_ff=self.d_ff,

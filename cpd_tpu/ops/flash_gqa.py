@@ -59,13 +59,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from ..compat import pallas as pl, pallas_tpu as pltpu
 from ..obs import scopes
 
 from .attention import _NEG_INF, _gqa_rep  # attention imports us lazily
 from .backend import interpret_mode
 
-__all__ = ["flash_gqa"]
+__all__ = ["flash_gqa", "KEEP_FLASH_RESIDUALS"]
 
 _BQ = 128   # query rows per program and head of the group (pre-rep);
             # MXU/sublane aligned; `_step_blocks` lengthens it for rep < 8
@@ -559,11 +560,32 @@ def flash_gqa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out
 
 
+# The names of the forward kernel's two results among the gradient's
+# residuals.  Outside a `jax.checkpoint` a name is an identity; inside
+# one, a policy that saves them keeps them from the forward pass
+FLASH_OUT = "flash_gqa.out"
+FLASH_LSE = "flash_gqa.lse"
+
+# The policy of a recomputed block that calls `flash_gqa`: keep the
+# kernel's output (bf16, the size of q) and its log-sum-exp (a float32 per
+# query row), recompute everything else.  Without it the backward pass
+# re-runs the forward kernel and its layout passes for every block, which
+# costs the kernel's time again (a Moonlight layer on a v5e: 7.5 ms for
+# 65 MiB kept at 2 x 8,192 tokens; PERF.md section 6).  q, k and v are
+# not named: they are the projections' outputs, three to four times what
+# is kept, and a block recomputes them with the rest.  A block without the names
+# saves nothing under it, as under no policy
+KEEP_FLASH_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    FLASH_OUT, FLASH_LSE)
+
+
 def _fwd(q, k, v, causal):
     # custom_vjp bypasses the primal under jax.grad: the head ratio is
     # checked here too
     _gqa_rep(q, k)
     out, lse = _flash_gqa_fwd_call(q, k, v, causal, interpret_mode())
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse)
 
 

@@ -27,6 +27,7 @@ from cpd_tpu.obs import scopes
 from cpd_tpu.parallel.mesh import make_mesh
 from cpd_tpu.train import make_lm_train_step, make_optimizer
 from cpd_tpu.train.state import TrainState
+from flash_remat import compare_with_bare_remat
 
 # a tiny cut of LFM2-24B-A2B's config.json: the reference's (published)
 # keys; 4 of 8 experts held from id 2, heads of 8 in groups of two
@@ -111,6 +112,17 @@ def test_loss_and_every_gradient_leaf_match_reference(impl, remat):
     for block in ("block1", "block2"):
         assert float(jnp.abs(g1[block]["moe"]["score_bias"]).max()) == 0.0
         assert float(jnp.abs(g2[block]["moe"]["score_bias"]).max()) == 0.0
+
+
+@pytest.mark.parametrize("impl,blocks", [("flash", 1), ("xla", 0)])
+def test_a_recomputed_block_runs_the_forward_kernel_once(impl, blocks,
+                                                         monkeypatch):
+    """One block of three has attention (`tests/flash_remat.py` says what
+    is held against the bare `nn.remat`)."""
+    a, b = batch()
+    compare_with_bare_remat(
+        monkeypatch, cm, mean_loss(model_of(attn_impl=impl, remat=True), a, b),
+        params_of(), blocks)
 
 
 def _routing_fixed(params):

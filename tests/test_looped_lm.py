@@ -12,6 +12,7 @@ from __future__ import annotations
 import hashlib
 import json
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ from cpd_tpu.models.mla_moe import COUNTERS, RMSNorm
 from cpd_tpu.parallel.mesh import make_mesh
 from cpd_tpu.train import make_lm_train_step, make_optimizer
 from cpd_tpu.train.state import TrainState
+from flash_remat import compare_with_bare_remat
 
 # a tiny cut of Ouro's config.json: the reference's (published) keys
 CFG = dict(hidden_size=32, num_attention_heads=4, intermediate_size=48,
@@ -103,6 +105,19 @@ def test_loss_and_every_gradient_leaf_match_reference(impl, remat):
     assert abs(float(l1) - float(l2)) < 1e-5 * float(l2)
     assert jax.tree.structure(g1) == jax.tree.structure(g2)
     assert max(jax.tree.leaves(jax.tree.map(rel, g1, g2))) < 5e-5
+
+
+@pytest.mark.parametrize("impl,blocks", [("flash", 2), ("xla", 0)])
+def test_a_recomputed_block_runs_the_forward_kernel_once(impl, blocks,
+                                                         monkeypatch):
+    """The pass's body holds both blocks once: the scan runs it four times
+    (`tests/flash_remat.py` says what is held against the bare
+    `nn.remat`)."""
+    a, b = batch()
+    compare_with_bare_remat(
+        monkeypatch, lm_module,
+        mean_loss(model_of(attn_impl=impl, remat=True), a, b), params_of(),
+        blocks)
 
 
 def test_model_in_bfloat16_on_the_flash_kernels_is_near_the_reference():
@@ -410,8 +425,35 @@ def _tiny_cells() -> dict:
             "moonlight_16b_ep8_aps_e5m2_1chip": (moe, tiny.LM_TRAFFIC)}
 
 
+def numbered_symbols(text: str) -> str:
+    """`text` with each `@symbol` named by its base and its rank among the
+    symbols of that base, in order of first appearance.  jax emits every
+    distinct equation's lowering as a private function named after its
+    primitive or function, and the module's symbol table gives a name
+    already taken the suffix `_<n>` of one counter over the whole module:
+    one more equation anywhere (a `checkpoint_name` is an identity that
+    still takes a name) renumbers every later symbol."""
+    ranks: dict = {}
+    seen: dict = {}
+
+    def rename(m):
+        name = m.group(1)
+        if name not in ranks:
+            base = re.sub(r"_\d+$", "", name)
+            ranks[name] = (base, seen.get(base, 0))
+            seen[base] = ranks[name][1] + 1
+        base, rank = ranks[name]
+        return f"@{base}_{rank}" if rank else f"@{base}"
+    return re.sub(r"@([\w.$-]+)", rename, text)
+
+
 def lowered_step_sha1(config: dict, traffic: dict) -> str:
     import importlib
+    # jax's tracing caches decide whether two calls of one function share
+    # a lowering: entries that earlier work left behind (or evicted) can
+    # split one private function in two, so the text is lowered from
+    # empty caches
+    jax.clear_caches()
     mesh = make_mesh(dp=1, devices=jax.devices()[:1])
     runner = importlib.import_module(
         f"benchmark.runners.{config['runner']}").build(config, traffic, mesh,
@@ -421,7 +463,7 @@ def lowered_step_sha1(config: dict, traffic: dict) -> str:
     a, b = jax.eval_shape(runner.make_batch, key)
     with jax.default_matmul_precision(None):    # the cells set none
         text = jax.jit(runner.step).lower(state, a, b).as_text()
-    return hashlib.sha1(text.encode()).hexdigest()
+    return hashlib.sha1(numbered_symbols(text).encode()).hexdigest()
 
 
 @pytest.mark.parametrize("cell", ["starcoder2_3b_aps_e5m2_1chip",
@@ -440,7 +482,14 @@ def test_other_lm_cells_steps_lower_to_the_parents_text(cell):
     alone (`moe_compact`: 18 scalar operations of its mean over layers,
     micro-batches and ranks, every other operation and type as it was);
     the step that does hold the `cond` is `test_mla_moe.py`'s, at 2 of
-    8 experts held.  The two StarCoder2 entries are PR 34's."""
+    8 experts held.  Character for character but for the numbers jax's
+    symbol table appends to private functions (`numbered_symbols`): all
+    three entries were recorded again with the symbols numbered, the
+    StarCoder2 ones on the commit before recomputed blocks kept the flash
+    kernel's results (the two names that keeps add to every program that
+    calls the kernel moved each later symbol's number by one and nothing
+    else), the Moonlight one on the commit after it, whose blocks
+    recompute everything but the forward kernel."""
     golden = json.loads(GOLDEN.read_text())
     if golden["jax"] != jax.__version__:
         pytest.skip(f"texts recorded under jax {golden['jax']}")

@@ -23,6 +23,7 @@ from cpd_tpu.ops.flash_gqa import flash_gqa
 from cpd_tpu.parallel.mesh import make_mesh
 from cpd_tpu.train import make_lm_train_step, make_optimizer
 from cpd_tpu.train.state import TrainState
+from flash_remat import compare_with_bare_remat
 
 # a tiny cut of the DeepSeek-V3 block: the reference's (published) keys
 CFG = dict(hidden_size=32, num_attention_heads=4, kv_lora_rank=16,
@@ -440,6 +441,18 @@ def test_model_loss_and_gradient_match_reference(impl, remat):
     # the selection bias: a leaf with no gradient, on both sides
     assert float(jnp.abs(g1["block1"]["moe"]["score_bias"]).max()) == 0.0
     assert float(jnp.abs(g2["block1"]["moe"]["score_bias"]).max()) == 0.0
+
+
+@pytest.mark.parametrize("impl,blocks", [("flash", 2), ("xla", 0)])
+def test_a_recomputed_block_runs_the_forward_kernel_once(impl, blocks,
+                                                         monkeypatch):
+    """Both blocks have attention (`tests/flash_remat.py` says what is
+    held against the bare `nn.remat`)."""
+    a, b = batch()
+    params = model_of().init(jax.random.PRNGKey(0), a)["params"]
+    compare_with_bare_remat(
+        monkeypatch, mm, _loss_of(model_of(attn_impl=impl, remat=True), a, b),
+        params, blocks)
 
 
 def test_model_in_bfloat16_is_near_the_reference():
